@@ -16,6 +16,12 @@ import time
 import jax
 import numpy as np
 
+from repro.runtime.compile_cache import use_compile_cache
+
+# every benchmark imports this module first: reuse compiled programs across
+# runs (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache)
+use_compile_cache()
+
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments",
                            "bench")
 # every BENCH_*.json is mirrored to the repo root so the perf trajectory is

@@ -133,10 +133,10 @@ def bench_sharded(n: int, s: int, iters: int, block: int = 1024) -> dict:
                                             make_sharded_planes_fn,
                                             pad_table, score_order_sharded,
                                             sharded_chain_step)
-    from repro.runtime.jax_compat import make_auto_mesh, mesh_context
+    from repro.launch.mesh import make_local_mesh
 
     tp = jax.device_count()
-    mesh = make_auto_mesh((1, tp), ("data", "model"))
+    mesh = make_local_mesh(1, tp)
     S = n_parent_sets(n - 1, s)
     pst_np, _ = build_pst(n - 1, s)
     rng = np.random.default_rng(0)
@@ -160,7 +160,7 @@ def bench_sharded(n: int, s: int, iters: int, block: int = 1024) -> dict:
         states, _ = jax.lax.scan(body, states, None, length=length)
         return states
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         states = jax.vmap(lambda k: init_chain(k, n, score_fn))(
             jax.random.split(jax.random.key(0), 1))
         sm = states._replace(mask_planes=planes_fn(states.pos))

@@ -42,7 +42,7 @@ _TRACING_WRAPPERS = {
     "jax.lax.while_loop", "lax.while_loop", "jax.lax.cond", "lax.cond",
     "jax.lax.switch", "lax.switch", "jax.lax.map", "lax.map",
     "jax.vmap", "vmap", "jax.pmap", "pmap", "shard_map",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
 }
 _PALLAS = {"pl.pallas_call", "pallas_call", "jax.experimental.pallas.pallas_call"}
 _SEGMENT_RUNNER = "make_traced_segment_runner"

@@ -95,7 +95,13 @@ def local_scores_chunk(data_ext: jnp.ndarray, node: jnp.ndarray,
     a_jk = alpha_jk[:, None, None]
     term_k = gammaln(a_k) - gammaln(a_k + Nk)                          # (C, Q)
     term_jk = (gammaln(counts + a_jk) - gammaln(a_jk)).sum(-1)         # (C, Q)
-    return k * log_gamma + jnp.sum(active * (term_k + term_jk), axis=-1)
+    terms = active * (term_k + term_jk)                                # (C, Q)
+    # bins summed strictly in order (XLA may reassociate a row reduction):
+    # the fused preprocessing sums them in the same order, bit for bit
+    acc = terms[:, 0]
+    for b in range(1, terms.shape[1]):
+        acc = acc + terms[:, b]
+    return k * log_gamma + acc
 
 
 class ScoreTable:

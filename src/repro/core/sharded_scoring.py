@@ -110,16 +110,14 @@ def score_order_sharded(table, pst, pos, mesh, *, axis: str = "model",
     Under jit with the table sharded P(None, axis) this is one shard_map
     region; the collective payload is 2 × (n,) per call.
     """
-    from jax.experimental.shard_map import shard_map
-
     n, S = table.shape
     tp = mesh.shape[axis]
     shard = S // tp
     in_specs = (P(None, axis), P(axis, None), P(None))
     out_specs = (P(), P(None), P(None))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     def go(table_l, pst_l, pos):
         my = jax.lax.axis_index(axis)
         ls_l, idx_l = _local_score(table_l, pst_l, pos, my * shard, block)
@@ -201,16 +199,14 @@ def make_sharded_planes_fn(pst, mesh, *, axis: str = "model",
     stacked=True: (C, n) chain-stacked positions -> (C, n, P, S/32) planes
     sharded (chains over the data axes, words over `axis`); stacked=False:
     one (n,) position -> (n, P, S/32) (init_chain's planes_fn contract)."""
-    from jax.experimental.shard_map import shard_map
-
     dax = tuple(a for a in mesh.axis_names if a != axis)
     pos_spec = P(dax, None) if stacked else P(None)
     out_spec = (P(dax, None, None, axis) if stacked
                 else P(None, None, axis))
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(pos_spec, P(axis, None)),
-                       out_specs=out_spec, check_rep=False)
+                       out_specs=out_spec, check_vma=False)
     def build(pos, pst_l):
         if stacked:
             return jax.vmap(lambda p: build_violation_planes(pst_l, p))(pos)
@@ -242,8 +238,6 @@ def sharded_chain_step(states, table, pst, mesh, cm=None, *,
     placeholder in states.mask_planes) the delta path recomputes window
     masks from per-shard position gathers.
     """
-    from jax.experimental.shard_map import shard_map
-
     from .mcmc import mcmc_step
 
     n, S = table.shape
@@ -262,8 +256,8 @@ def sharded_chain_step(states, table, pst, mesh, cm=None, *,
         operands += (cm,)
     out_specs = st_specs
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     def go(states_l, table_l, pst_l, *rest):
         my = jax.lax.axis_index(axis)
 
@@ -294,7 +288,11 @@ def sharded_chain_step(states, table, pst, mesh, cm=None, *,
 
         return jax.vmap(lambda s: mcmc_step(s, score_fn, delta_fn, w))(states_l)
 
-    return go(*operands)
+    # jit even when called eagerly: an eager shard_map reports the zero-size
+    # mask_planes placeholder as replicated against its P(data) out_spec and
+    # fails; under jit it is partitioned like every other chain leaf (an
+    # outer jit, as in the segment runner, inlines this one)
+    return jax.jit(go)(*operands)
 
 
 def make_sharded_score_fn(table, pst, mesh, *, axis: str = "model",
@@ -318,8 +316,6 @@ def make_sharded_delta_fn(table, pst, mesh, *, window: int,
     contract, or None when the crossover heuristic rejects the window. This
     is the mask-RECOMPUTE variant; :func:`make_sharded_bitmask_fns` is the
     cached-planes engine."""
-    from jax.experimental.shard_map import shard_map
-
     n = table.shape[0]
     w = delta_window(n, window)
     if not w:
@@ -331,8 +327,8 @@ def make_sharded_delta_fn(table, pst, mesh, *, window: int,
     in_specs = (P(None, axis), P(axis, None), P(None), P(), P(None), P(None))
     out_specs = (P(), P(None), P(None))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     def go(table_l, pst_l, pos, lo, prev_ls, prev_idx):
         my = jax.lax.axis_index(axis)
         win, ls_g, idx_g = _local_delta(table_l, pst_l, pos, lo, my * shard,
@@ -361,8 +357,6 @@ def make_sharded_bitmask_fns(table, pst, mesh, *, window: int,
       inside shard_map.
 
     Returns (None, None) when the crossover heuristic rejects the window."""
-    from jax.experimental.shard_map import shard_map
-
     n = table.shape[0]
     w = delta_window(n, window)
     if not w:
@@ -378,8 +372,8 @@ def make_sharded_bitmask_fns(table, pst, mesh, *, window: int,
                 P(None), P(None, None, axis))
     out_specs = (P(), P(None), P(None), P(None, None, axis))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     def go(table_l, cm_l, pos, lo, prev_ls, prev_idx, pos_old, planes_l):
         my = jax.lax.axis_index(axis)
         win, ls_g, idx_g, new_planes = _local_bitmask_delta(

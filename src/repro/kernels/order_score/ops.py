@@ -7,9 +7,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .kernel import (NEG_INF, order_score_pallas,
+from .kernel import (BLOCK_S, NEG_INF, order_score_pallas,
                      order_score_window_bitmask_fused_pallas,
-                     order_score_window_bitmask_pallas,
                      order_score_window_pallas)
 from .ref import order_score_ref
 
@@ -35,7 +34,7 @@ def pad_for_kernel(table: jnp.ndarray, pst: jnp.ndarray, block_s: int):
 @functools.partial(jax.jit,
                    static_argnames=("block_s", "use_pallas", "interpret"))
 def order_score(table: jnp.ndarray, pst: jnp.ndarray, pos: jnp.ndarray, *,
-                block_s: int = 2048, use_pallas: bool = True,
+                block_s: int = BLOCK_S, use_pallas: bool = True,
                 interpret: bool | None = None):
     """Score an order (paper Eq. 6). Returns (score, best_idx (n,), best_ls (n,))."""
     if interpret is None:
@@ -53,7 +52,8 @@ def order_score(table: jnp.ndarray, pst: jnp.ndarray, pos: jnp.ndarray, *,
                                              "interpret"))
 def order_score_delta(table: jnp.ndarray, pst: jnp.ndarray, pos: jnp.ndarray,
                       prev_ls: jnp.ndarray, prev_idx: jnp.ndarray,
-                      lo: jnp.ndarray, *, window: int, block_s: int = 2048,
+                      lo: jnp.ndarray, *, window: int,
+                      block_s: int = BLOCK_S,
                       use_pallas: bool = True, interpret: bool | None = None):
     """Kernel-path incremental rescore (core/order_scoring.py docstring):
     recomputes only the `window` nodes at positions [lo, lo+window-1] of the
@@ -87,7 +87,7 @@ def order_score_delta_bitmask(table: jnp.ndarray, cm: jnp.ndarray,
                               pos: jnp.ndarray, prev_ls: jnp.ndarray,
                               prev_idx: jnp.ndarray, lo: jnp.ndarray,
                               pos_old: jnp.ndarray, planes: jnp.ndarray, *,
-                              window: int, block_s: int = 2048,
+                              window: int, block_s: int = BLOCK_S,
                               use_pallas: bool = True,
                               interpret: bool | None = None):
     """Kernel-path bitmask-cached rescore, now ONE fused Pallas kernel

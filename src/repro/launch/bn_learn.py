@@ -233,11 +233,11 @@ def _delta_context(st, cfg: LearnConfig):
     if cfg.scorer == "sum":
         return "sum", (st.table, st.pst), None, None
     if cfg.use_kernel:
-        from ..kernels.order_score.ops import pad_for_kernel
+        from ..kernels.order_score import BLOCK_S, pad_for_kernel
 
         # pre-pad once so the per-iteration call's pad is a no-op (the
         # blocked path hoists its padding the same way via _padded)
-        ktable, kpst = pad_for_kernel(st.table, st.pst, 2048)
+        ktable, kpst = pad_for_kernel(st.table, st.pst, BLOCK_S)
         if cfg.mask_cache:
             return "kernel", (ktable, kpst), \
                 build_membership_planes(kpst, ktable.shape[0]), \
@@ -396,7 +396,7 @@ def _run_sharded(st, cfg: LearnConfig, key, n: int, collector=None):
     from ..core.sharded_scoring import (_shard_block, make_sharded_planes_fn,
                                         pad_table, score_order_sharded,
                                         sharded_chain_step)
-    from ..runtime.jax_compat import make_auto_mesh, mesh_context
+    from .mesh import make_local_mesh
 
     if isinstance(st, SparseScoreTable):
         raise ValueError(
@@ -418,7 +418,7 @@ def _run_sharded(st, cfg: LearnConfig, key, n: int, collector=None):
     if cfg.chains % dp:
         raise ValueError(f"--chains {cfg.chains} must be divisible by the "
                          f"data-axis extent {dp}")
-    mesh = make_auto_mesh((dp, tp), ("data", "model"))
+    mesh = make_local_mesh(dp, tp)
     block = _shard_block(st.table.shape[1], tp, cfg.block)
     table, pst = pad_table(st.table, st.pst, tp * block)
     w = delta_window(n, cfg.window)
@@ -452,7 +452,7 @@ def _run_sharded(st, cfg: LearnConfig, key, n: int, collector=None):
     seg = cfg.checkpoint_every if checkpointed else \
         (_auto_check_every(cfg) if telem or cfg.supervise or cfg.fault_plan
          else cfg.iters)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         keys = jax.random.split(key, cfg.chains)
         states = jax.vmap(lambda k: init_chain(k, n, score_fn))(keys)
         if mask_on:
@@ -778,6 +778,9 @@ def _network_data(name: str, m: int, q: int, seed: int, n_synth: int = 64):
 
 
 def main(argv=None) -> dict:
+    from ..runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--network", default="alarm",
                     choices=["alarm", "stn", "synth"])

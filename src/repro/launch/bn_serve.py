@@ -200,6 +200,9 @@ class _Handler(BaseHTTPRequestHandler):
 
 
 def main(argv=None) -> None:
+    from ..runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8787)

@@ -31,7 +31,6 @@ from ..configs import ARCH_IDS, cells, get_config, get_shape
 from ..models.layers import set_mesh
 from ..optim import AdamWConfig, adamw_init, opt_state_specs
 from .hlo_cost import analyze_hlo
-from ..runtime.jax_compat import mesh_context
 from .mesh import make_production_mesh
 from .roofline import model_flops_for, roofline_terms
 from .specs import build_step, input_specs
@@ -128,7 +127,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                          _sh(mesh, in_sp["tokens"]))
 
         t0 = time.time()
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step, in_shardings=shardings)
             lowered = jitted.lower(*args)
             t_lower = time.time() - t0
@@ -224,7 +223,7 @@ def run_bn_cell(multi_pod: bool, *, n: int = 60, s: int = 4,
                                   window=window)
 
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=(
             st_sh, sh(P(None, "model")), sh(P("model", None)),
             sh(P(None, "model")))) \

@@ -12,7 +12,8 @@ device state (required so smoke tests see 1 CPU device while the dry-run sees
 """
 from __future__ import annotations
 
-from ..runtime.jax_compat import make_auto_mesh
+import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "batch_axes_of"]
 
@@ -20,12 +21,14 @@ __all__ = ["make_production_mesh", "make_local_mesh", "batch_axes_of"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_auto_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many real devices exist (tests/examples)."""
-    return make_auto_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def batch_axes_of(mesh) -> tuple[str, ...]:

@@ -31,7 +31,8 @@ import urllib.request
 
 import numpy as np
 
-__all__ = ["main"]
+__all__ = ["main", "start_server", "submit_and_wait", "fetch_artifacts",
+           "shutdown"]
 
 _POLL_TIMEOUT = 300.0      # seconds until we declare the service hung
 
@@ -45,50 +46,46 @@ def _http(method: str, url: str, payload: dict | None = None) -> dict:
         return json.loads(resp.read())
 
 
-def main(argv=None) -> int:
-    from ..launch.bn_learn import learn_structure
-    from ..launch.bn_query import load_result
+def start_server(run_dir: str, slots: int = 16):
+    """BNServer on an ephemeral localhost port, serving from a daemon
+    thread. Returns (server, base_url, thread)."""
     from ..launch.bn_serve import BNServer
-    from ..service import load_dataset, service_config, validate_response
-    from ..service.jobs import DatasetSpec
 
-    run_dir = tempfile.mkdtemp(prefix="serve_smoke_")
-    srv = BNServer(("127.0.0.1", 0), slots=16, run_dir=run_dir)
+    srv = BNServer(("127.0.0.1", 0), slots=slots, run_dir=run_dir)
     host, port = srv.server_address[:2]
-    base = f"http://{host}:{port}"
     t = threading.Thread(target=srv.serve_forever, daemon=True)
     t.start()
-    print(f"serve_smoke: server up at {base}, run_dir={run_dir}")
+    return srv, f"http://{host}:{port}", t
 
-    config = {"iters": 400, "chains": 3, "check_every": 100,
-              "trace_every": 10, "seed": 11, "stop_on_converge": True,
-              "patience": 1}
-    specs = [
-        {"network": "synth", "n": 8, "m": 150, "seed": 3},
-        {"network": "synth", "n": 10, "m": 150, "seed": 4},
-    ]
+
+def submit_and_wait(base: str, specs: list[dict], config: dict,
+                    timeout: float = _POLL_TIMEOUT) -> dict[str, dict]:
+    """Submit every dataset spec plus an exact duplicate of the first, check
+    the duplicate dedups onto the first job, poll until every job settles,
+    and check each settled job is ``done`` — the scheduler catches job
+    exceptions by design, so a clean return proves nothing; the state does.
+    Every response is schema-validated. Returns {job_id: final status}."""
+    from ..service import validate_response
 
     health = _http("GET", f"{base}/v1/health")
     validate_response(health)
     assert health["state"] == "up", health
 
-    # --- submit both datasets, plus an exact duplicate of the first
     jobs = [_http("POST", f"{base}/v1/jobs",
                   {"dataset": s, "config": config}) for s in specs]
     dup = _http("POST", f"{base}/v1/jobs",
                 {"dataset": specs[0], "config": config})
     for j in jobs + [dup]:
         validate_response(j)
-    assert not jobs[0]["deduped"] and not jobs[1]["deduped"], jobs
+    assert not any(j["deduped"] for j in jobs), jobs
     assert dup["deduped"] and dup["job_id"] == jobs[0]["job_id"], \
         f"dedup broken: {dup['job_id']} vs {jobs[0]['job_id']}"
     assert dup["attached"] == 2, dup
-    assert jobs[0]["job_id"] != jobs[1]["job_id"]
+    ids = [j["job_id"] for j in jobs]
+    assert len(set(ids)) == len(ids), ids
     print(f"serve_smoke: dedup OK ({dup['job_id']} attached twice)")
 
-    # --- poll to completion
-    ids = [j["job_id"] for j in jobs]
-    deadline = time.time() + _POLL_TIMEOUT
+    deadline = time.time() + timeout
     states: dict[str, dict] = {}
     while time.time() < deadline:
         states = {i: _http("GET", f"{base}/v1/jobs/{i}") for i in ids}
@@ -98,9 +95,59 @@ def main(argv=None) -> int:
     for i, s in states.items():
         validate_response(s)
         assert s["state"] == "done", f"job {i}: {s}"
-    print("serve_smoke: both jobs done "
+    print(f"serve_smoke: {len(ids)} jobs done "
           f"(iters_done={[states[i]['iters_done'] for i in ids]}, "
           f"converged={[states[i]['converged'] for i in ids]})")
+    return states
+
+
+def fetch_artifacts(base: str, jid: str) -> dict[str, dict]:
+    """The posterior, MAP and consensus (default and 0.25 cut) responses of
+    one finished job, each schema-validated."""
+    from ..service import validate_response
+
+    out = {
+        "posterior": _http("GET", f"{base}/v1/jobs/{jid}/posterior"),
+        "map": _http("GET", f"{base}/v1/jobs/{jid}/map"),
+        "consensus": _http("GET", f"{base}/v1/jobs/{jid}/consensus"),
+        "consensus_lo": _http(
+            "GET", f"{base}/v1/jobs/{jid}/consensus?threshold=0.25"),
+    }
+    for r in out.values():
+        validate_response(r)
+    assert out["consensus_lo"]["threshold"] == 0.25
+    assert np.asarray(out["consensus_lo"]["adjacency"]).sum() >= \
+        np.asarray(out["consensus"]["adjacency"]).sum()
+    return out
+
+
+def shutdown(base: str, thread: threading.Thread) -> None:
+    """POST /v1/shutdown and wait for the serving thread to stop."""
+    from ..service import validate_response
+
+    validate_response(_http("POST", f"{base}/v1/shutdown"))
+    thread.join(timeout=60)
+    assert not thread.is_alive(), "server thread did not stop"
+
+
+def main(argv=None) -> int:
+    from ..launch.bn_learn import learn_structure
+    from ..launch.bn_query import load_result
+    from ..service import load_dataset, service_config
+    from ..service.jobs import DatasetSpec
+
+    run_dir = tempfile.mkdtemp(prefix="serve_smoke_")
+    _, base, t = start_server(run_dir)
+    print(f"serve_smoke: server up at {base}, run_dir={run_dir}")
+
+    config = {"iters": 400, "chains": 3, "check_every": 100,
+              "trace_every": 10, "seed": 11, "stop_on_converge": True,
+              "patience": 1}
+    specs = [
+        {"network": "synth", "n": 8, "m": 150, "seed": 3},
+        {"network": "synth", "n": 10, "m": 150, "seed": 4},
+    ]
+    ids = list(submit_and_wait(base, specs, config))
 
     # --- slots reclaimed once everything finished
     health = _http("GET", f"{base}/v1/health")
@@ -108,17 +155,8 @@ def main(argv=None) -> int:
 
     # --- artifacts: schema-valid AND bitwise-equal to standalone runs
     for spec, jid in zip(specs, ids):
-        post = _http("GET", f"{base}/v1/jobs/{jid}/posterior")
-        mapr = _http("GET", f"{base}/v1/jobs/{jid}/map")
-        cons = _http("GET", f"{base}/v1/jobs/{jid}/consensus")
-        cons_lo = _http("GET",
-                        f"{base}/v1/jobs/{jid}/consensus?threshold=0.25")
-        for r in (post, mapr, cons, cons_lo):
-            validate_response(r)
-        assert cons_lo["threshold"] == 0.25
-        assert np.asarray(cons_lo["adjacency"]).sum() >= \
-            np.asarray(cons["adjacency"]).sum()
-
+        art = fetch_artifacts(base, jid)
+        post, mapr, cons = art["posterior"], art["map"], art["consensus"]
         cfg = service_config(config)
         data = load_dataset(DatasetSpec(**spec), cfg.q)
         ref = learn_structure(data, cfg)
@@ -141,11 +179,7 @@ def main(argv=None) -> int:
         assert doc["job"]["state"] == "done"
     print("serve_smoke: bn_query round-trip OK")
 
-    # --- clean shutdown
-    bye = _http("POST", f"{base}/v1/shutdown")
-    validate_response(bye)
-    t.join(timeout=60)
-    assert not t.is_alive(), "server thread did not stop"
+    shutdown(base, t)
     print("serve_smoke: clean shutdown — PASS")
     return 0
 

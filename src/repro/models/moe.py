@@ -139,8 +139,6 @@ def moe_apply_gathered(params, x, *, cfg: ModelConfig, mesh,
     x: (B, T, d) with batch over `batch_axes`; T divisible by the seq_axis
     extent. Capacity is enforced per token shard (more balanced than global).
     """
-    from jax.experimental.shard_map import shard_map
-
     B, T, d = x.shape
     e_pad = cfg.padded_experts(mesh.shape[seq_axis])
     e_real, k = cfg.n_experts, cfg.experts_top_k
@@ -178,8 +176,8 @@ def moe_apply_gathered(params, x, *, cfg: ModelConfig, mesh,
     capacity = max(int(local_tok * k / max(e_real, 1) * cfg.capacity_factor),
                    8)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(w_specs, x_spec),
-                       out_specs=x_spec, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(w_specs, x_spec),
+                       out_specs=x_spec, check_vma=False)
     def run(p, x_l):
         full = {}
         for name, spec in w_specs.items():
@@ -245,8 +243,6 @@ def moe_apply_ep(params, x, *, cfg: ModelConfig, mesh,
     x: (B, T, d), batch over `batch_axes`, T divisible by the seq_axis
     extent. Router replicated (gathered once — it is (d, e), tiny).
     """
-    from jax.experimental.shard_map import shard_map
-
     B, T, d = x.shape
     tp = mesh.shape[seq_axis]
     e_pad = cfg.padded_experts(tp)
@@ -276,8 +272,8 @@ def moe_apply_ep(params, x, *, cfg: ModelConfig, mesh,
     cap_exp = max(int(local_tok * k * tp / max(e_real, 1)
                       * cfg.capacity_factor), 8)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(w_specs, x_spec),
-                       out_specs=x_spec, check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(w_specs, x_spec),
+                       out_specs=x_spec, check_vma=False)
     def run(p, x_l):
         # weights: experts resident (dim 0 already sharded over seq_axis);
         # only their FSDP ('data') dim is gathered — e_loc × that slice

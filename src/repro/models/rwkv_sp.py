@@ -24,7 +24,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..runtime.jax_compat import axis_size
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
@@ -54,7 +53,7 @@ def _shift_from_left(x_l, axis_name: str):
     """prev-token sequence for a T-sharded (B, T_l, d) block: within-shard
     shift + the previous rank's last token via ppermute (rank 0 gets zeros,
     which is the sequence-start convention)."""
-    tp = axis_size(axis_name)
+    tp = jax.lax.axis_size(axis_name)
     boundary = jax.lax.ppermute(x_l[:, -1:], axis_name,
                                 perm=[(i, i + 1) for i in range(tp - 1)])
     return jnp.concatenate([boundary, x_l[:, :-1]], axis=1)
@@ -65,7 +64,7 @@ def _state_prefix_scan(D, K, axis_name: str):
     D: (B, H, N) total decay of the shard; K: (B, H, N, N) state injected by
     the shard. Returns each rank's incoming state (zeros at rank 0).
     Hillis–Steele doubling: log2(tp) ppermute rounds."""
-    tp = axis_size(axis_name)
+    tp = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     step = 1
     while step < tp:
@@ -120,7 +119,7 @@ def _time_mix_sp(p, x_l, *, cfg: ModelConfig, chunk: int, axis_name: str):
     y = (y.reshape(B, Tl, d).astype(dt)) * g
     out = jnp.einsum("btd,de->bte", y, p["w_o"])
     # global final state (for the prefill cache): lives on the last rank
-    tp = axis_size(axis_name)
+    tp = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     s_fin = D_tot[..., None] * s_in + s_loc
     s_fin = jax.lax.psum(jnp.where(rank == tp - 1, s_fin, 0.0), axis_name)
@@ -148,8 +147,6 @@ def rwkv_stack_sp(params_stacked, specs_stacked, x, *, cfg: ModelConfig,
     the `seq_axis` extent. Fresh state only (train / first prefill).
     Returns (x_out, per-layer RWKVState stacked or None).
     """
-    from jax.experimental.shard_map import shard_map
-
     tp = mesh.shape[seq_axis]
     layer_specs = jax.tree.map(
         lambda s: P(*s), specs_stacked,
@@ -164,9 +161,9 @@ def rwkv_stack_sp(params_stacked, specs_stacked, x, *, cfg: ModelConfig,
         specs_stacked, is_leaf=lambda s: isinstance(s, P))]
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(layer_specs, x_spec),
+        jax.shard_map, mesh=mesh, in_specs=(layer_specs, x_spec),
         out_specs=(x_spec, out_state_spec) if want_cache else x_spec,
-        check_rep=False)
+        check_vma=False)
     def run(params_l, x_l):
         rank = jax.lax.axis_index(seq_axis)
 

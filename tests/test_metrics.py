@@ -130,7 +130,7 @@ def _map_score(table, s, adj):
         for i in range(n))
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(hst.integers(0, 10_000))
 def test_map_dag_matches_bruteforce_oracle(seed):
     rng = np.random.default_rng(seed)
@@ -148,7 +148,7 @@ def test_map_dag_matches_bruteforce_oracle(seed):
     assert np.isclose(_map_score(table, s, got), _map_score(table, s, want))
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(hst.integers(0, 10_000))
 def test_map_dag_pruned_matches_dense(seed):
     rng = np.random.default_rng(seed)

@@ -13,8 +13,9 @@ SCRIPT = textwrap.dedent("""
     from repro.models import Model
     from repro.models.layers import set_mesh
 
-    from repro.runtime.jax_compat import make_auto_mesh, mesh_context
-    mesh = make_auto_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
     for arch in ("granite-moe-3b-a800m", "arctic-480b"):
         cfg = get_config(arch).reduced()
@@ -31,7 +32,7 @@ SCRIPT = textwrap.dedent("""
         params = m0.init(jax.random.key(0))
         tok = jax.random.randint(jax.random.key(1), (B, T), 0, cfg.vocab)
         set_mesh(mesh)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             a, _ = jax.jit(m0.forward)(params, tok)
             b, _ = jax.jit(m1.forward)(params, tok)
             np.testing.assert_allclose(

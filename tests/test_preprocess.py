@@ -62,6 +62,25 @@ def test_fused_small_chunk_matches():
     np.testing.assert_array_equal(got, want)
 
 
+def test_lgamma_f32_is_bitwise_xla_lgamma():
+    """The fused kernel's lgamma (the TPU kernel lowering has none) repeats
+    XLA's own expansion op for op, so on CPU it is bitwise jax.lax.lgamma:
+    over the score's arguments (integer counts plus ess / q^k), a wide
+    range, and the pole, negative and infinite edges."""
+    from repro.preprocess.fused import lgamma_f32
+
+    rng = np.random.default_rng(0)
+    counts = np.arange(0, 2001, dtype=np.float64)
+    xs = np.concatenate(
+        [rng.uniform(1e-4, 2, 20_000), rng.uniform(0, 2000, 20_000)]
+        + [counts + 1.0 / 3 ** k for k in range(6)]
+        + [[0.0, -0.5, -3.0, -2.25, np.inf, -np.inf, np.nan]]
+    ).astype(np.float32)
+    want = np.asarray(jax.jit(jax.lax.lgamma)(xs))
+    got = np.asarray(jax.jit(lgamma_f32)(xs))
+    np.testing.assert_array_equal(got, want)
+
+
 def test_fused_pallas_kernel_matches_ref():
     """Pallas fused count+score == jnp fused chunk (interpret mode), with the
     padded sample rows deliberately CORRUPTED in the child one-hot — the

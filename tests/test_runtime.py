@@ -214,12 +214,12 @@ def test_remesh_then_reshard_roundtrips_chain_leaves():
     leaves placed with a chains-over-'data' spec survive bitwise (the
     restart path: topology-free checkpoint -> new mesh)."""
     from jax.sharding import PartitionSpec as P
-    from repro.runtime.jax_compat import make_auto_mesh
+    from jax.sharding import AxisType
 
     ndev = jax.device_count()
     shape, names = remesh_plan(ndev, model_parallel=1)
     assert shape == (ndev, 1) and names == ("data", "model")
-    mesh = make_auto_mesh(shape, names)
+    mesh = jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * 2)
     C = 2 * ndev
     tree = {"pos": np.arange(C * 6).reshape(C, 6),
             "score": np.linspace(0, 1, C)}

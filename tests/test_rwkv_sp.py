@@ -13,8 +13,9 @@ SCRIPT = textwrap.dedent("""
     from repro.models import Model
     from repro.models.layers import set_mesh
 
-    from repro.runtime.jax_compat import make_auto_mesh, mesh_context
-    mesh = make_auto_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = get_config("rwkv6-7b").reduced()
     B, T = 2, 32                      # T/tp = 8 per shard, chunk 4
 
@@ -24,7 +25,7 @@ SCRIPT = textwrap.dedent("""
     tok = jax.random.randint(jax.random.key(1), (B, T), 0, cfg.vocab)
 
     set_mesh(mesh)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         # train-mode forward (no cache)
         a, _ = jax.jit(m_seq.forward)(params, tok)
         b, _ = jax.jit(m_sp.forward)(params, tok)

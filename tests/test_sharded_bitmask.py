@@ -33,7 +33,7 @@ SCRIPT = textwrap.dedent("""
                                             make_sharded_score_fn, pad_table,
                                             sharded_chain_step)
     from repro.checkpoint import restore_checkpoint, save_checkpoint
-    from repro.runtime.jax_compat import make_auto_mesh, mesh_context
+    from jax.sharding import AxisType
 
     n, s, w, tp, block, SEQS, MOVES = 13, 3, 4, 4, 64, 200, 2
     S = n_parent_sets(n - 1, s)
@@ -46,7 +46,8 @@ SCRIPT = textwrap.dedent("""
     tpad, ppad = pad_table(table, pst, tp * blk)
     cm = build_membership_planes(ppad, n)
 
-    mesh = make_auto_mesh((1, tp), ("data", "model"))
+    mesh = jax.make_mesh((1, tp), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     fn = make_sharded_score_fn(table, pst, mesh, block=block)
     bfn, planes_fn = make_sharded_bitmask_fns(table, pst, mesh, window=w,
                                               block=block)
@@ -83,7 +84,7 @@ SCRIPT = textwrap.dedent("""
         planes_ok = jnp.all(planes_f == planes_fn(pos_f))
         return None, outs + (planes_ok,)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         # sharded per-shard planes build == single-device build, word for word
         np.testing.assert_array_equal(np.asarray(planes_fn(pos0)),
                                       np.asarray(pl0))

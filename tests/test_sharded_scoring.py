@@ -21,21 +21,22 @@ SCRIPT = textwrap.dedent("""
     table = jnp.asarray(rng.normal(-40, 8, (n, S)).astype(np.float32))
     pst = jnp.asarray(pst)
 
-    from repro.runtime.jax_compat import make_auto_mesh, mesh_context
-    mesh = make_auto_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     fn = make_sharded_score_fn(table, pst, mesh, block=64)
 
     for seed in range(5):
         pos = jnp.asarray(np.random.default_rng(seed).permutation(n)
                           .astype(np.int32))
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             sc, idx, ls = jax.jit(fn)(pos)
         sc_ref, idx_ref, ls_ref = score_order_ref(table, pst, pos)
         np.testing.assert_allclose(float(sc), float(sc_ref), rtol=1e-6)
         np.testing.assert_array_equal(np.asarray(idx), np.asarray(idx_ref))
 
     # the full MCMC sampler runs on the sharded scorer unchanged
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         state, _ = mcmc_run(jax.random.key(0), n, fn, 50)
     assert np.isfinite(float(state.best_score))
 
@@ -47,16 +48,16 @@ SCRIPT = textwrap.dedent("""
     for seed in range(5):
         pos = jnp.asarray(np.random.default_rng(100 + seed).permutation(n)
                           .astype(np.int32))
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             sc0, idx0, ls0 = jax.jit(fn)(pos)
         new_pos, lo = propose_move(jax.random.key(seed), pos, window=4)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             got = jax.jit(dfn)(new_pos, lo, ls0, idx0)
             want = jax.jit(fn)(new_pos)
         np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-6)
         np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         a, _ = mcmc_run(jax.random.key(1), n, fn, 40, window=4)
         b, _ = mcmc_run(jax.random.key(1), n, fn, 40, delta_fn=dfn, window=4)
     assert float(a.score) == float(b.score)
@@ -68,7 +69,7 @@ SCRIPT = textwrap.dedent("""
     from repro.core.sharded_scoring import sharded_chain_step
     tpad, ppad = pad_table(table, pst, 4 * 64)
     keys = jax.random.split(jax.random.key(2), 8)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         states = jax.vmap(lambda k: init_chain(k, n, fn))(keys)
         sd = sl = states
         for _ in range(3):
