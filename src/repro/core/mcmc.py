@@ -236,20 +236,22 @@ def _propose_and_score(state: ChainState, k_prop: jax.Array,
         new_pos, lo = _propose_move_impl(k_prop, state.pos, window=window)
     else:
         new_pos, lo = _propose_swap(k_prop, state.pos), jnp.int32(0)
-    if isinstance(delta_fn, BitmaskDelta):
-        new_score, new_idx, new_ls, new_planes = delta_fn.fn(
-            new_pos, lo, state.cur_ls, state.cur_idx, state.pos,
-            state.mask_planes)
-    elif delta_fn is not None:
-        new_score, new_idx, new_ls = delta_fn(new_pos, lo, state.cur_ls,
-                                              state.cur_idx)
-        new_planes = state.mask_planes
-    else:
-        new_score, new_idx, new_ls = score_fn(new_pos)
-        new_planes = state.mask_planes
+    with jax.named_scope("order_score"):
+        if isinstance(delta_fn, BitmaskDelta):
+            new_score, new_idx, new_ls, new_planes = delta_fn.fn(
+                new_pos, lo, state.cur_ls, state.cur_idx, state.pos,
+                state.mask_planes)
+        elif delta_fn is not None:
+            new_score, new_idx, new_ls = delta_fn(new_pos, lo, state.cur_ls,
+                                                  state.cur_idx)
+            new_planes = state.mask_planes
+        else:
+            new_score, new_idx, new_ls = score_fn(new_pos)
+            new_planes = state.mask_planes
     return new_pos, new_score, new_idx, new_ls, new_planes
 
 
+@jax.named_scope("accept")
 def _accept_update(state: ChainState, key, k_u, proposal) -> ChainState:
     """Shared MH accept/reject + cache/best bookkeeping."""
     new_pos, new_score, new_idx, new_ls, new_planes = proposal
@@ -533,11 +535,13 @@ def make_traced_segment_runner(step, *, tap=None, exchange=None,
             st = step(st) if stacked_step else jax.vmap(step)(st)
             it = start + i + 1
             if tap is not None:
-                tr = tap(tr, st, it)
+                with jax.named_scope("tap"):
+                    tr = tap(tr, st, it)
             if exchange_every > 0:
-                st, tr = jax.lax.cond(it % exchange_every == 0,
-                                      lambda c: exchange(*c), lambda c: c,
-                                      (st, tr))
+                with jax.named_scope("exchange"):
+                    st, tr = jax.lax.cond(it % exchange_every == 0,
+                                          lambda c: exchange(*c),
+                                          lambda c: c, (st, tr))
             return (st, tr), None
 
         (states, trace), _ = jax.lax.scan(body, (states, trace),

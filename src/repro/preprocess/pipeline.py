@@ -48,11 +48,14 @@ was planned), a {n_chunks, n_devices, imbalance} dict otherwise;
 (plan_s/score_s/assemble_s on the dense path, plan_s/stream_s/finalize_s
 streaming, cache_load_s/cache_store_s around the disk cache) — the
 telemetry collector (launch/bn_learn --telemetry) emits them as stage rows.
+Each is the ``.seconds`` of a telemetry span (``preprocess.build`` gives
+``preprocess_s``; ``preprocess.plan``/``score``/``assemble``, the last split
+into ``preprocess.rank_map`` and ``preprocess.gather``), so a profiler trace
+shows the same stretches on the device's clock.
 """
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +63,7 @@ import numpy as np
 
 from ..core.combinatorics import build_pst, n_parent_sets, rank_combinations_batch
 from ..core.scores import ScoreTable, validate_prior_matrix
+from ..telemetry.spans import span
 from .cache import (cache_key, load_cached_sparse, load_cached_table,
                     store_cached_sparse, store_cached_table)
 from .fused import (encode_subset_codes, fused_scores_pallas,
@@ -164,152 +168,168 @@ def build_score_table_fused(data: np.ndarray, *, q: int, s: int,
     ``use_pallas`` defaults to True on TPU, False elsewhere (the jnp fused
     path is the fast CPU path; the kernel is the fast TPU path).
     """
-    t0 = time.time()
-    data = np.asarray(data, dtype=np.int32)
-    m, n = data.shape
-    if np.any(data < 0) or np.any(data >= q):
-        raise ValueError(f"data states must lie in [0, {q})")
-    validate_prior_matrix(prior_matrix, n)
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if streaming is None:
-        streaming = prune_delta is not None
-    streaming = bool(streaming) and prune_delta is not None
+    with span("preprocess.build") as build:
+        data = np.asarray(data, dtype=np.int32)
+        m, n = data.shape
+        if np.any(data < 0) or np.any(data >= q):
+            raise ValueError(f"data states must lie in [0, {q})")
+        validate_prior_matrix(prior_matrix, n)
+        if use_pallas is None:
+            use_pallas = jax.default_backend() == "tpu"
+        if streaming is None:
+            streaming = prune_delta is not None
+        streaming = bool(streaming) and prune_delta is not None
 
-    S = n_parent_sets(n - 1, s)
-    # "stages" is the per-stage wall-clock breakdown of preprocess_s — the
-    # telemetry collector's stage rows (launch/bn_learn) read it verbatim
-    info: dict = {"cache_hit": False, "n": n, "S": S, "plan": None,
-                  "preprocess_s": None, "streaming": streaming,
-                  "peak_assembly_bytes": None, "stages": {}}
-    log_gamma = float(np.log(gamma))
-    expect = {"q": q, "s": s, "m": m, "n": n,
-              "gamma": float(gamma), "ess": float(ess)}
-    if devices is None:
-        devices = (list(np.asarray(mesh.devices).flat) if mesh is not None
-                   else [jax.devices()[0]])
+        S = n_parent_sets(n - 1, s)
+        # "stages" is the per-stage wall-clock breakdown of preprocess_s —
+        # the telemetry collector's stage rows (launch/bn_learn) read it
+        info: dict = {"cache_hit": False, "n": n, "S": S, "plan": None,
+                      "preprocess_s": None, "streaming": streaming,
+                      "peak_assembly_bytes": None, "stages": {}}
+        log_gamma = float(np.log(gamma))
+        expect = {"q": q, "s": s, "m": m, "n": n,
+                  "gamma": float(gamma), "ess": float(ess)}
+        if devices is None:
+            devices = (list(np.asarray(mesh.devices).flat) if mesh is not None
+                       else [jax.devices()[0]])
 
-    # ---- cache lookups: sparse (exact delta/max_keep) first, then dense
-    key = skey = None
-    if cache_dir:
-        key = cache_key(data, q=q, s=s, gamma=gamma, ess=ess,
-                        prior_matrix=prior_matrix)
-        if prune_delta is not None:
-            skey = cache_key(data, q=q, s=s, gamma=gamma, ess=ess,
-                             prior_matrix=prior_matrix,
-                             prune_delta=prune_delta, max_keep=max_keep)
-            hit = load_cached_sparse(cache_dir, skey, expect=expect)
-            if hit is not None:
-                kept_idx, kept_ls, kept_parents, _ = hit
-                sp = SparseScoreTable.from_kept(
-                    kept_idx, kept_ls, kept_parents,
-                    q=q, s=s, delta=prune_delta, S=S)
-                info.update(cache_hit=True, preprocess_s=time.time() - t0)
-                info["stages"]["cache_load_s"] = info["preprocess_s"]
-                return (sp, info) if return_info else sp
-        cached = load_cached_table(cache_dir, key, expect=expect)
-        if cached is not None:
-            table_np, pst_c, psz_c = cached
-            info.update(cache_hit=True, streaming=False,
-                        preprocess_s=time.time() - t0)
-            info["stages"]["cache_load_s"] = info["preprocess_s"]
-            st = ScoreTable(jnp.asarray(table_np), np.asarray(pst_c),
-                            np.asarray(psz_c), q, s)
-            if prune_delta is not None:
-                st = prune_table(st, prune_delta)
-            return (st, info) if return_info else st
-
-    # ---- streaming assembly: chunks -> pruned table, no dense intermediate
-    if streaming:
-        from .streaming import build_sparse_table_streaming
-        sp, sinfo = build_sparse_table_streaming(
-            data, q=q, s=s, gamma=gamma, ess=ess, chunk=chunk,
-            delta=prune_delta, prior_matrix=prior_matrix, max_keep=max_keep,
-            devices=devices, use_pallas=use_pallas, block_m=block_m,
-            interpret=interpret)
-        info["plan"] = {k: sinfo[k] for k in
-                        ("n_chunks", "n_devices", "imbalance")}
-        info["peak_assembly_bytes"] = sinfo["peak_assembly_bytes"]
-        info["stages"].update(sinfo.get("stages", {}))
-        info["preprocess_s"] = time.time() - t0
+        # ---- cache lookups: sparse (exact delta/max_keep) first, then dense
+        key = skey = None
+        sp = dense = None
         if cache_dir:
-            t_store = time.time()
-            store_cached_sparse(
-                cache_dir, skey or cache_key(
-                    data, q=q, s=s, gamma=gamma, ess=ess,
-                    prior_matrix=prior_matrix, prune_delta=prune_delta,
-                    max_keep=max_keep),
-                np.asarray(sp.kept_idx), np.asarray(sp.kept_ls),
-                np.asarray(sp.kept_parents),
-                metadata={**expect, "prune_delta": float(prune_delta),
-                          "max_keep": max_keep, "S": S})
-            info["stages"]["cache_store_s"] = time.time() - t_store
+            key = cache_key(data, q=q, s=s, gamma=gamma, ess=ess,
+                            prior_matrix=prior_matrix)
+            if prune_delta is not None:
+                skey = cache_key(data, q=q, s=s, gamma=gamma, ess=ess,
+                                 prior_matrix=prior_matrix,
+                                 prune_delta=prune_delta, max_keep=max_keep)
+                hit = load_cached_sparse(cache_dir, skey, expect=expect)
+                if hit is not None:
+                    kept_idx, kept_ls, kept_parents, _ = hit
+                    sp = SparseScoreTable.from_kept(
+                        kept_idx, kept_ls, kept_parents,
+                        q=q, s=s, delta=prune_delta, S=S)
+            if sp is None:
+                dense = load_cached_table(cache_dir, key, expect=expect)
+                if dense is not None:
+                    info["streaming"] = False
+            info["cache_hit"] = sp is not None or dense is not None
+
+        if info["cache_hit"]:
+            pass                               # the table came from disk
+        elif streaming:
+            # ---- streaming assembly: chunks -> pruned table, no dense
+            # intermediate
+            from .streaming import build_sparse_table_streaming
+            sp, sinfo = build_sparse_table_streaming(
+                data, q=q, s=s, gamma=gamma, ess=ess, chunk=chunk,
+                delta=prune_delta, prior_matrix=prior_matrix,
+                max_keep=max_keep, devices=devices, use_pallas=use_pallas,
+                block_m=block_m, interpret=interpret)
+            info["plan"] = {k: sinfo[k] for k in
+                            ("n_chunks", "n_devices", "imbalance")}
+            info["peak_assembly_bytes"] = sinfo["peak_assembly_bytes"]
+            info["stages"].update(sinfo.get("stages", {}))
+        else:
+            dense = _build_dense(data, q=q, s=s, ess=ess, chunk=chunk,
+                                 log_gamma=log_gamma,
+                                 prior_matrix=prior_matrix, devices=devices,
+                                 use_pallas=use_pallas, block_m=block_m,
+                                 interpret=interpret, info=info)
+    info["preprocess_s"] = build.seconds
+
+    if info["cache_hit"]:
+        info["stages"]["cache_load_s"] = build.seconds
+    elif cache_dir:
+        with span("preprocess.cache_store") as store:
+            if sp is not None:
+                store_cached_sparse(
+                    cache_dir, skey, np.asarray(sp.kept_idx),
+                    np.asarray(sp.kept_ls), np.asarray(sp.kept_parents),
+                    metadata={**expect, "prune_delta": float(prune_delta),
+                              "max_keep": max_keep, "S": S})
+            else:
+                table, pst, psizes = dense
+                store_cached_table(cache_dir, key, np.asarray(table), pst,
+                                   psizes, metadata={**expect,
+                                                     "kind": "dense"})
+        info["stages"]["cache_store_s"] = store.seconds
+    if sp is not None:
         return (sp, info) if return_info else sp
 
-    # ---- dense assembly -------------------------------------------------
-    t_plan = time.time()
-    pst, psizes = build_pst(n - 1, s)
-
-    # plan: column subsets, chunked + cost-sharded (paper §III-B)
-    sub, ssz = build_pst(n, s)                   # subsets of ALL n columns
-    Csub = sub.shape[0]
-    chunk = min(chunk, Csub)
-    pad = (-Csub) % chunk
-    sub_p = np.pad(sub, ((0, pad), (0, 0)), constant_values=-1)
-    ssz_p = np.pad(ssz, (0, pad))
-    nch = sub_p.shape[0] // chunk
-    plan = plan_preprocess(ssz_p, chunk, m, q, len(devices))
-    info["plan"] = {"n_chunks": plan.n_chunks, "n_devices": plan.n_devices,
-                    "imbalance": plan.imbalance}
-    info["stages"]["plan_s"] = time.time() - t_plan
-    t_score = time.time()
-
-    # execute: one jitted scan per device over its chunks
-    data_ext = np.concatenate([data, np.zeros((m, 1), np.int32)], axis=1)
-    subs3 = sub_p.reshape(nch, chunk, s)
-    sszs2 = ssz_p.reshape(nch, chunk)
-    lut_k, lut_j = score_luts(q, s, m, ess)
-    per_dev = []
-    for d, dev in enumerate(devices[:plan.n_devices]):
-        de = jax.device_put(jnp.asarray(data_ext), dev)
-        su = jax.device_put(jnp.asarray(subs3), dev)
-        sz = jax.device_put(jnp.asarray(sszs2), dev)
-        lk = jax.device_put(lut_k, dev)
-        lj = jax.device_put(lut_j, dev)
-        ids = jax.device_put(jnp.asarray(plan.padded_chunks[d]), dev)
-        out = _run_device(de, su, sz, lk, lj, ids, q=q, s=s, n=n, ess=ess,
-                          use_pallas=use_pallas, block_m=block_m,
-                          interpret=interpret)                # async dispatch
-        per_dev.append((plan.padded_chunks[d], out))
-
-    TI = np.zeros((nch * chunk, n), np.float32)
-    for ids, out in per_dev:
-        out = np.asarray(out)                              # (U, C, n) sync
-        for u, ci in enumerate(ids):                       # dupes: same data
-            TI[ci * chunk:(ci + 1) * chunk] = out[u]
-    TI = jnp.asarray(TI[:Csub])
-    info["stages"]["score_s"] = time.time() - t_score
-    t_asm = time.time()
-
-    # assemble: rank-gather + structure penalty (+ prior)
-    rmap = _rank_map(n, s, pst, psizes)
-    table = assemble_table(TI, rmap, psizes, log_gamma)
-    if prior_matrix is not None:
-        from ..core.priors import prior_table
-        table = table + prior_table(jnp.asarray(prior_matrix, jnp.float32),
-                                    jnp.asarray(pst), n)
-    info["stages"]["assemble_s"] = time.time() - t_asm
-    info["preprocess_s"] = time.time() - t0
-
-    if cache_dir:
-        t_store = time.time()
-        store_cached_table(cache_dir, key, np.asarray(table), pst, psizes,
-                           metadata={**expect, "kind": "dense"})
-        info["stages"]["cache_store_s"] = time.time() - t_store
-
-    st = ScoreTable(table, pst, psizes, q, s)
+    table, pst, psizes = dense
+    st = ScoreTable(jnp.asarray(table), np.asarray(pst), np.asarray(psizes),
+                    q, s)
     if prune_delta is not None:
-        t_prune = time.time()
-        st = prune_table(st, prune_delta)
-        info["stages"]["prune_s"] = time.time() - t_prune
+        with span("preprocess.prune") as prune:
+            st = prune_table(st, prune_delta)
+        if not info["cache_hit"]:
+            info["stages"]["prune_s"] = prune.seconds
     return (st, info) if return_info else st
+
+
+def _build_dense(data: np.ndarray, *, q: int, s: int, ess: float, chunk: int,
+                 log_gamma: float, prior_matrix, devices, use_pallas: bool,
+                 block_m: int, interpret, info: dict):
+    """(table, pst, psizes) by the dense assembly: plan the column-subset
+    chunks, score them on the devices, rank-gather the (n, S) table. Fills
+    ``info["plan"]`` and the plan_s/score_s/assemble_s stages."""
+    m, n = data.shape
+    with span("preprocess.plan") as plan_span:
+        pst, psizes = build_pst(n - 1, s)
+
+        # plan: column subsets, chunked + cost-sharded (paper §III-B)
+        sub, ssz = build_pst(n, s)               # subsets of ALL n columns
+        Csub = sub.shape[0]
+        chunk = min(chunk, Csub)
+        pad = (-Csub) % chunk
+        sub_p = np.pad(sub, ((0, pad), (0, 0)), constant_values=-1)
+        ssz_p = np.pad(ssz, (0, pad))
+        nch = sub_p.shape[0] // chunk
+        plan = plan_preprocess(ssz_p, chunk, m, q, len(devices))
+        info["plan"] = {"n_chunks": plan.n_chunks,
+                        "n_devices": plan.n_devices,
+                        "imbalance": plan.imbalance}
+
+    with span("preprocess.score") as score_span:
+        # execute: one jitted scan per device over its chunks
+        data_ext = np.concatenate([data, np.zeros((m, 1), np.int32)], axis=1)
+        subs3 = sub_p.reshape(nch, chunk, s)
+        sszs2 = ssz_p.reshape(nch, chunk)
+        lut_k, lut_j = score_luts(q, s, m, ess)
+        per_dev = []
+        for d, dev in enumerate(devices[:plan.n_devices]):
+            de = jax.device_put(jnp.asarray(data_ext), dev)
+            su = jax.device_put(jnp.asarray(subs3), dev)
+            sz = jax.device_put(jnp.asarray(sszs2), dev)
+            lk = jax.device_put(lut_k, dev)
+            lj = jax.device_put(lut_j, dev)
+            ids = jax.device_put(jnp.asarray(plan.padded_chunks[d]), dev)
+            out = _run_device(de, su, sz, lk, lj, ids, q=q, s=s, n=n,
+                              ess=ess, use_pallas=use_pallas,
+                              block_m=block_m,
+                              interpret=interpret)            # async dispatch
+            per_dev.append((plan.padded_chunks[d], out))
+
+        TI = np.zeros((nch * chunk, n), np.float32)
+        for ids, out in per_dev:
+            out = np.asarray(out)                          # (U, C, n) sync
+            for u, ci in enumerate(ids):                   # dupes: same data
+                TI[ci * chunk:(ci + 1) * chunk] = out[u]
+        TI = jnp.asarray(TI[:Csub])
+
+    with span("preprocess.assemble") as assemble_span:
+        # assemble: rank-gather + structure penalty (+ prior)
+        with span("preprocess.rank_map"):
+            rmap = _rank_map(n, s, pst, psizes)
+        with span("preprocess.gather"):
+            table = assemble_table(TI, rmap, psizes, log_gamma)
+            if prior_matrix is not None:
+                from ..core.priors import prior_table
+                table = table + prior_table(
+                    jnp.asarray(prior_matrix, jnp.float32),
+                    jnp.asarray(pst), n)
+    info["stages"].update(plan_s=plan_span.seconds,
+                          score_s=score_span.seconds,
+                          assemble_s=assemble_span.seconds)
+    return table, pst, psizes

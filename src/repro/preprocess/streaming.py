@@ -41,7 +41,6 @@ set exceeds ``max_keep``.
 """
 from __future__ import annotations
 
-import time
 from collections import deque
 
 import jax
@@ -51,6 +50,7 @@ import numpy as np
 from ..core.combinatorics import (build_pst, n_parent_sets,
                                   rank_combinations_batch)
 from ..core.order_scoring import NEG_INF
+from ..telemetry.spans import span
 from .planner import plan_preprocess
 from .sparse import SparseScoreTable
 
@@ -167,149 +167,149 @@ def build_sparse_table_streaming(
     from .fused import score_luts
     from .pipeline import _run_device
 
-    t_plan = time.time()
-    data = np.asarray(data, dtype=np.int32)
-    m, n = data.shape
-    S = n_parent_sets(n - 1, s)
-    log_gamma = float(np.log(gamma))
+    with span("preprocess.plan") as plan_span:
+        data = np.asarray(data, dtype=np.int32)
+        m, n = data.shape
+        S = n_parent_sets(n - 1, s)
+        log_gamma = float(np.log(gamma))
 
-    # ---- plan: identical chunking + LPT sharding to the dense pipeline
-    sub, ssz = build_pst(n, s)                  # subsets of ALL n columns
-    Csub = sub.shape[0]
-    chunk = min(chunk, Csub)
-    pad = (-Csub) % chunk
-    sub_p = np.pad(sub, ((0, pad), (0, 0)), constant_values=-1)
-    ssz_p = np.pad(ssz, (0, pad))
-    del sub, ssz                  # keep only the padded copy on the host
-    nch = sub_p.shape[0] // chunk
-    if devices is None:
-        devices = [jax.devices()[0]]
-    plan = plan_preprocess(ssz_p, chunk, m, q, len(devices))
+        # ---- plan: identical chunking + LPT sharding to the dense pipeline
+        sub, ssz = build_pst(n, s)                  # subsets of ALL n columns
+        Csub = sub.shape[0]
+        chunk = min(chunk, Csub)
+        pad = (-Csub) % chunk
+        sub_p = np.pad(sub, ((0, pad), (0, 0)), constant_values=-1)
+        ssz_p = np.pad(ssz, (0, pad))
+        del sub, ssz                  # keep only the padded copy on the host
+        nch = sub_p.shape[0] // chunk
+        if devices is None:
+            devices = [jax.devices()[0]]
+        plan = plan_preprocess(ssz_p, chunk, m, q, len(devices))
 
-    subs3 = sub_p.reshape(nch, chunk, s)
-    sszs2 = ssz_p.reshape(nch, chunk)
-    lut_k, lut_j = score_luts(q, s, m, ess)
-    data_ext = np.concatenate([data, np.zeros((m, 1), np.int32)], axis=1)
-    R = (jnp.asarray(prior_matrix, jnp.float32)
-         if prior_matrix is not None else None)
+        subs3 = sub_p.reshape(nch, chunk, s)
+        sszs2 = ssz_p.reshape(nch, chunk)
+        lut_k, lut_j = score_luts(q, s, m, ess)
+        data_ext = np.concatenate([data, np.zeros((m, 1), np.int32)], axis=1)
+        R = (jnp.asarray(prior_matrix, jnp.float32)
+             if prior_matrix is not None else None)
 
-    dev_in = []
-    for d, dev in enumerate(devices[:plan.n_devices]):
-        dev_in.append((jax.device_put(jnp.asarray(data_ext), dev),
-                       jax.device_put(jnp.asarray(subs3), dev),
-                       jax.device_put(jnp.asarray(sszs2), dev),
-                       jax.device_put(lut_k, dev),
-                       jax.device_put(lut_j, dev)))
+        dev_in = []
+        for d, dev in enumerate(devices[:plan.n_devices]):
+            dev_in.append((jax.device_put(jnp.asarray(data_ext), dev),
+                           jax.device_put(jnp.asarray(subs3), dev),
+                           jax.device_put(jnp.asarray(sszs2), dev),
+                           jax.device_put(lut_k, dev),
+                           jax.device_put(lut_j, dev)))
 
-    # ---- streaming merge state
-    best = np.full(n, np.float32(NEG_INF), np.float32)   # global running best
-    ls0 = np.full(n, np.float32(NEG_INF), np.float32)    # empty-set scores
-    partials = [_DevicePartial(s) for _ in range(plan.n_devices)]
-    peak = 0
+        # ---- streaming merge state
+        best = np.full(n, np.float32(NEG_INF), np.float32)  # global best
+        ls0 = np.full(n, np.float32(NEG_INF), np.float32)    # empty-set scores
+        partials = [_DevicePartial(s) for _ in range(plan.n_devices)]
+        peak = 0
 
-    def note_peak(tmp_bytes: int) -> None:
-        nonlocal peak
-        peak = max(peak, sum(p.nbytes for p in partials) + tmp_bytes)
+        def note_peak(tmp_bytes: int) -> None:
+            nonlocal peak
+            peak = max(peak, sum(p.nbytes for p in partials) + tmp_bytes)
 
-    arange_n = np.arange(n, dtype=np.int32)
+        arange_n = np.arange(n, dtype=np.int32)
 
-    def merge_chunk(d: int, ci: int, ti_c: np.ndarray) -> None:
-        nonlocal best
-        sub_c = sub_p[ci * chunk:(ci + 1) * chunk]       # (C, s) node ids
-        ssz_c = ssz_p[ci * chunk:(ci + 1) * chunk]
-        n_valid = int(np.clip(Csub - ci * chunk, 0, chunk))
-        # same f32 composition as assemble_table: |σ|·ln γ + TI (+ prior)
-        sc = ssz_c.astype(np.float32) * np.float32(log_gamma)
-        sc = sc[:, None] + ti_c                           # (C, n)
-        if R is not None:
-            sc = sc + np.asarray(_prior_all_jit(R, jnp.asarray(sub_c)))
-        member = (sub_c[:, :, None] == arange_n[None, None, :]).any(1)
-        valid = np.zeros((chunk, 1), bool)
-        valid[:n_valid] = True
-        dom = valid & ~member                             # (C, n) child ok
-        chunk_best = np.where(dom, sc, np.float32(NEG_INF)).max(0)
-        best = np.maximum(best, chunk_best)
-        if ci * chunk == 0:                               # σ = ∅ lives here
-            ls0[:] = sc[0]
-        keep = dom & (sc >= (best - float(delta))[None, :])
-        if ci * chunk == 0:
-            keep[0] = False          # rank 0 re-inserted at finalisation
-        cc, ii = np.nonzero(keep)
-        if len(cc):
-            rows = sub_c[cc]                              # (L, s) node ids
-            cand = rows - (rows > ii[:, None])
-            cand = np.where(rows < 0, -1, cand)
-            ranks = _rank_batched(n - 1, s, cand, ssz_c[cc])
-            partials[d].append(ii.astype(np.int32), ranks,
-                               sc[cc, ii], rows.astype(np.int32))
-        note_peak(ti_c.nbytes + sc.nbytes + member.nbytes + keep.nbytes
-                  + 2 * len(cc) * (4 + 8 + 4 + 4 * s))
-        if partials[d].since_compact >= _COMPACT_EVERY:
-            partials[d].compact(best, delta, max_keep)
+        def merge_chunk(d: int, ci: int, ti_c: np.ndarray) -> None:
+            nonlocal best
+            sub_c = sub_p[ci * chunk:(ci + 1) * chunk]       # (C, s) node ids
+            ssz_c = ssz_p[ci * chunk:(ci + 1) * chunk]
+            n_valid = int(np.clip(Csub - ci * chunk, 0, chunk))
+            # same f32 composition as assemble_table: |σ|·ln γ + TI (+ prior)
+            sc = ssz_c.astype(np.float32) * np.float32(log_gamma)
+            sc = sc[:, None] + ti_c                           # (C, n)
+            if R is not None:
+                sc = sc + np.asarray(_prior_all_jit(R, jnp.asarray(sub_c)))
+            member = (sub_c[:, :, None] == arange_n[None, None, :]).any(1)
+            valid = np.zeros((chunk, 1), bool)
+            valid[:n_valid] = True
+            dom = valid & ~member                             # (C, n) child ok
+            chunk_best = np.where(dom, sc, np.float32(NEG_INF)).max(0)
+            best = np.maximum(best, chunk_best)
+            if ci * chunk == 0:                           # σ = ∅ lives here
+                ls0[:] = sc[0]
+            keep = dom & (sc >= (best - float(delta))[None, :])
+            if ci * chunk == 0:
+                keep[0] = False          # rank 0 re-inserted at finalisation
+            cc, ii = np.nonzero(keep)
+            if len(cc):
+                rows = sub_c[cc]                              # (L, s) node ids
+                cand = rows - (rows > ii[:, None])
+                cand = np.where(rows < 0, -1, cand)
+                ranks = _rank_batched(n - 1, s, cand, ssz_c[cc])
+                partials[d].append(ii.astype(np.int32), ranks,
+                                   sc[cc, ii], rows.astype(np.int32))
+            note_peak(ti_c.nbytes + sc.nbytes + member.nbytes + keep.nbytes
+                      + 2 * len(cc) * (4 + 8 + 4 + 4 * s))
+            if partials[d].since_compact >= _COMPACT_EVERY:
+                partials[d].compact(best, delta, max_keep)
 
     # ---- dispatch: round-robin over the LPT buckets, bounded in-flight
-    t_stream = time.time()
-    plan_s = t_stream - t_plan
-    schedule = []
-    width = max(len(b) for b in plan.device_chunks)
-    for r in range(width):
-        for d, bucket in enumerate(plan.device_chunks):
-            if r < len(bucket):
-                schedule.append((d, bucket[r]))
-    pending: deque = deque()
-    for d, ci in schedule:
-        de, su, sz, lk, lj = dev_in[d]
-        ids = jax.device_put(jnp.asarray([ci], jnp.int32), devices[d])
-        out = _run_device(de, su, sz, lk, lj, ids, q=q, s=s, n=n, ess=ess,
-                          use_pallas=use_pallas, block_m=block_m,
-                          interpret=interpret)            # async dispatch
-        pending.append((d, ci, out))
-        if len(pending) >= _INFLIGHT_PER_DEV * plan.n_devices:
+    with span("preprocess.stream") as stream_span:
+        schedule = []
+        width = max(len(b) for b in plan.device_chunks)
+        for r in range(width):
+            for d, bucket in enumerate(plan.device_chunks):
+                if r < len(bucket):
+                    schedule.append((d, bucket[r]))
+        pending: deque = deque()
+        for d, ci in schedule:
+            de, su, sz, lk, lj = dev_in[d]
+            ids = jax.device_put(jnp.asarray([ci], jnp.int32), devices[d])
+            out = _run_device(de, su, sz, lk, lj, ids, q=q, s=s, n=n, ess=ess,
+                              use_pallas=use_pallas, block_m=block_m,
+                              interpret=interpret)            # async dispatch
+            pending.append((d, ci, out))
+            if len(pending) >= _INFLIGHT_PER_DEV * plan.n_devices:
+                dd, cc_, fut = pending.popleft()
+                merge_chunk(dd, cc_, np.asarray(fut)[0])
+        while pending:
             dd, cc_, fut = pending.popleft()
             merge_chunk(dd, cc_, np.asarray(fut)[0])
-    while pending:
-        dd, cc_, fut = pending.popleft()
-        merge_chunk(dd, cc_, np.asarray(fut)[0])
 
     # ---- one merge at the end: final threshold, pack, hash
-    t_final = time.time()
-    stream_s = t_final - t_stream
-    node = np.concatenate([np.concatenate(p.node) if p.node else
-                           np.empty(0, np.int32) for p in partials])
-    rank = np.concatenate([np.concatenate(p.rank) if p.rank else
-                           np.empty(0, np.int64) for p in partials])
-    ls = np.concatenate([np.concatenate(p.ls) if p.ls else
-                         np.empty(0, np.float32) for p in partials])
-    par = np.concatenate([np.concatenate(p.par) if p.par else
-                          np.empty((0, s), np.int32) for p in partials])
-    keep = ls >= (best - float(delta))[node]
-    node, rank, ls, par = node[keep], rank[keep], ls[keep], par[keep]
-    if max_keep is not None and len(node):
-        node, rank, ls, par = _cap_per_node(node, rank, ls, par, n, max_keep)
-    note_peak(node.nbytes + rank.nbytes + ls.nbytes + par.nbytes)
+    with span("preprocess.finalize") as finalize_span:
+        node = np.concatenate([np.concatenate(p.node) if p.node else
+                               np.empty(0, np.int32) for p in partials])
+        rank = np.concatenate([np.concatenate(p.rank) if p.rank else
+                               np.empty(0, np.int64) for p in partials])
+        ls = np.concatenate([np.concatenate(p.ls) if p.ls else
+                             np.empty(0, np.float32) for p in partials])
+        par = np.concatenate([np.concatenate(p.par) if p.par else
+                              np.empty((0, s), np.int32) for p in partials])
+        keep = ls >= (best - float(delta))[node]
+        node, rank, ls, par = node[keep], rank[keep], ls[keep], par[keep]
+        if max_keep is not None and len(node):
+            node, rank, ls, par = _cap_per_node(node, rank, ls, par, n,
+                                                max_keep)
+        note_peak(node.nbytes + rank.nbytes + ls.nbytes + par.nbytes)
 
-    order = np.lexsort((rank, node))          # per node, ascending rank
-    node, rank, ls, par = node[order], rank[order], ls[order], par[order]
-    counts = np.bincount(node, minlength=n)
-    K = int(counts.max()) + 1 if len(node) else 1        # +1: forced rank 0
-    kept_idx = np.full((n, K), -1, np.int32)
-    kept_ls = np.full((n, K), np.float32(NEG_INF), np.float32)
-    kept_parents = np.full((n, K, s), -1, np.int32)
-    kept_idx[:, 0] = 0                                   # empty set first
-    kept_ls[:, 0] = ls0
-    starts = np.zeros(n + 1, np.int64)
-    starts[1:] = np.cumsum(counts)
-    pos = np.arange(len(node)) - starts[node] + 1
-    kept_idx[node, pos] = rank.astype(np.int32)
-    kept_ls[node, pos] = ls
-    kept_parents[node, pos] = par
-    note_peak(kept_idx.nbytes + kept_ls.nbytes + kept_parents.nbytes)
+        order = np.lexsort((rank, node))          # per node, ascending rank
+        node, rank, ls, par = node[order], rank[order], ls[order], par[order]
+        counts = np.bincount(node, minlength=n)
+        K = int(counts.max()) + 1 if len(node) else 1    # +1: forced rank 0
+        kept_idx = np.full((n, K), -1, np.int32)
+        kept_ls = np.full((n, K), np.float32(NEG_INF), np.float32)
+        kept_parents = np.full((n, K, s), -1, np.int32)
+        kept_idx[:, 0] = 0                                   # empty set first
+        kept_ls[:, 0] = ls0
+        starts = np.zeros(n + 1, np.int64)
+        starts[1:] = np.cumsum(counts)
+        pos = np.arange(len(node)) - starts[node] + 1
+        kept_idx[node, pos] = rank.astype(np.int32)
+        kept_ls[node, pos] = ls
+        kept_parents[node, pos] = par
+        note_peak(kept_idx.nbytes + kept_ls.nbytes + kept_parents.nbytes)
 
-    sp = SparseScoreTable.from_kept(kept_idx, kept_ls, kept_parents,
-                                    q=q, s=s, delta=delta, S=S)
+        sp = SparseScoreTable.from_kept(kept_idx, kept_ls, kept_parents,
+                                        q=q, s=s, delta=delta, S=S)
     info = {"peak_assembly_bytes": int(peak), "n_chunks": plan.n_chunks,
             "n_devices": plan.n_devices, "imbalance": plan.imbalance,
             "kept_entries": int(counts.sum()) + n, "K": K,
-            "stages": {"plan_s": plan_s, "stream_s": stream_s,
-                       "finalize_s": time.time() - t_final}}
+            "stages": {"plan_s": plan_span.seconds,
+                       "stream_s": stream_span.seconds,
+                       "finalize_s": finalize_span.seconds}}
     return sp, info

@@ -48,6 +48,7 @@ import numpy as np
 from ..checkpoint import (latest_step, restore_latest_verified,
                           save_checkpoint)
 from ..core.mcmc import ChainState
+from ..telemetry.spans import span
 from .faults import FaultPlan
 from .straggler import StragglerPolicy, best_finite_chain, rebalance_chains
 
@@ -362,10 +363,15 @@ class RunSupervisor:
 
     def advance(self) -> bool:
         """Run ONE supervised segment (chaos injection, segment scan, stall
-        replay, collector check, healing, checkpoint). Returns True while
-        the run has more segments to go."""
+        replay, collector check, healing, checkpoint) under the ``segment``
+        span. Returns True while the run has more segments to go."""
         if self.finished:
             return False
+        with span("segment"):
+            self._segment()
+        return not self.finished
+
+    def _segment(self) -> None:
         states, trace, done = self._states, self._trace, self._done
         if self.faults:
             states = self._fire_pre_segment(states)
@@ -398,7 +404,6 @@ class RunSupervisor:
         self._states, self._trace, self._done = states, trace, done
         if self.stop_on_converge and rec is not None and rec["converged"]:
             self._stopped = True
-        return not self.finished
 
     def result(self) -> SupervisedResult:
         return SupervisedResult(states=self._states, trace=self._trace,

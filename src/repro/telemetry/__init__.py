@@ -17,6 +17,10 @@ work:
   flags stuck/diverged chains with rolling-median/MAD spike detection, and
   appends schema-versioned JSONL rows (:mod:`schema`) under
   ``experiments/runs/``.
+* **Spans** (:mod:`spans`, host): ``span(name)`` marks the host layers'
+  boundaries (preprocessing stages, each supervised segment, the drain's
+  wait) on the profiler's clock and times the preprocessing stages; a
+  compile counter files every backend compile under the innermost span.
 
 The R̂ stopping rule (``bn_learn --stop-on-converge``): both R̂ statistics
 below ``--rhat-threshold`` for ``--patience`` consecutive checks stops the
@@ -27,6 +31,7 @@ run early — convergence, not the iteration cap, decides run length.
 from .collector import Collector, host_meta
 from .rhat import edge_rhat, median_outliers, split_rhat
 from .schema import SCHEMA, read_rows, validate_row, write_rows
+from .spans import span
 from .taps import (DEFAULT_TRACE_CAP, TraceState, adjacency_bits_from_ranks,
                    drain, exchange_step_traced, init_trace, make_tap,
                    unrank_parent_sets_jax)
@@ -36,5 +41,5 @@ __all__ = [
     "SCHEMA", "read_rows", "validate_row", "write_rows", "DEFAULT_TRACE_CAP",
     "TraceState", "adjacency_bits_from_ranks", "drain",
     "exchange_step_traced", "init_trace", "make_tap",
-    "unrank_parent_sets_jax",
+    "unrank_parent_sets_jax", "span", "spans",
 ]

@@ -35,6 +35,7 @@ import numpy as np
 
 from ..core.combinatorics import binom_table, size_offsets
 from ..core.mcmc import ChainState, exchange_step
+from .spans import span
 
 __all__ = ["TraceState", "init_trace", "make_tap", "exchange_step_traced",
            "unrank_parent_sets_jax", "adjacency_bits_from_ranks", "drain",
@@ -194,7 +195,13 @@ def exchange_step_traced(states: ChainState,
 def drain(trace: TraceState) -> dict:
     """Host-side snapshot: fetch every leaf as numpy, and linearise the
     score/accept rings oldest-first (valid entries only) so the collector
-    sees plain (C, L) time series."""
+    sees plain (C, L) time series.
+
+    The fetch waits for the segment that produced ``trace``; that wait is
+    made explicit under the ``segment.wait`` span, so the host's own
+    fetch-and-check work is told apart from waiting for the device."""
+    with span("segment.wait"):
+        jax.block_until_ready(trace)
     tr = jax.tree.map(np.asarray, trace)
     cap = tr.scores.shape[1]
     T = int(tr.taps)
