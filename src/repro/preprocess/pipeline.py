@@ -13,8 +13,10 @@ S/chunk chunks, one device round-trip each) with:
      jitted scan per device, then a gather
      ls(i, pi) = |pi|*ln(gamma) + TI[rank(columns(pi, i)), i] using the
      vectorized combination ranking (core/combinatorics) — the rank IS the
-     hash (paper §III-A). Materialises the (n, S) table (plus an (n, S)
-     host-side rank map), which is the memory wall at n >= 100;
+     hash (paper §III-A). The (n, S) rank map is built on the device from
+     the (S, s) PST by one jitted int32 computation, so only the PST and
+     its sizes cross from the host. Materialises the (n, S) table (plus
+     the (n, S) device rank map), which is the memory wall at n >= 100;
    * **streaming** (``prune_delta`` set — the default engine for pruned
      tables, streaming.py): per-chunk dispatch whose (chunk, n) output is
      rank-gathered chunk-locally and merged into per-node within-delta
@@ -56,12 +58,13 @@ shows the same stretches on the device's clock.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.combinatorics import build_pst, n_parent_sets, rank_combinations_batch
+from ..core.combinatorics import build_pst, n_parent_sets, size_offsets
 from ..core.scores import ScoreTable, validate_prior_matrix
 from ..telemetry.spans import span
 from .cache import (cache_key, load_cached_sparse, load_cached_table,
@@ -74,27 +77,61 @@ from .sparse import SparseScoreTable, prune_table
 __all__ = ["build_score_table_fused", "assemble_table"]
 
 
-def _rank_map(n: int, s: int, pst: np.ndarray, psizes: np.ndarray) -> np.ndarray:
-    """(n, S) int32: rank_map[i, t] = rank (in the size-ascending subset
-    enumeration over the n columns) of the column set of PST row t for node i.
-    Candidate->column mapping is monotone, so digit order is preserved and
-    the subset's config bins line up with the PST entry's.
-
-    Built one node at a time: the batch ranking's int64 temporaries are
-    (S, s)-sized, so peak host memory stays ~S*s*8 bytes regardless of n
-    (an (n, S, s) broadcast would peak at ~12 GB for n=64, s=4).
-
-    Dense-assembly only — the streaming path computes the INVERSE map chunk
-    by chunk (streaming.py) and never materialises this array."""
-    out = np.empty((n, pst.shape[0]), np.int32)
-    for i in range(n):
-        cols = pst + (pst >= i)
-        cols = np.where(pst < 0, -1, cols)
-        out[i] = rank_combinations_batch(n, s, cols, psizes)
+def _binom(x: jnp.ndarray, r: jnp.ndarray, s: int) -> jnp.ndarray:
+    """C(x, r) elementwise for int32 x >= 0 and 1 <= r <= s, in closed form:
+    C(x, k + 1) = C(x, k) (x - k) / (k + 1) divides exactly, and a factor
+    x - k < 0 only meets C(x, k) = 0. No table read, so XLA fuses it into
+    plain vector arithmetic (gathers from a binomial table do not fuse on
+    the TPU and keep (n, S) temporaries)."""
+    c = out = x
+    for k in range(1, s):
+        c = c * (x - k) // (k + 1)
+        out = jnp.where(r == k + 1, c, out)
     return out
 
 
-def assemble_table(TI: jnp.ndarray, rank_map: np.ndarray, psizes: np.ndarray,
+@functools.partial(jax.jit, static_argnames=("n", "s"))
+def _rank_map(n: int, s: int, pst, psizes) -> jax.Array:
+    """(n, S) int32 on the device: rank_map[i, t] = rank (in the
+    size-ascending subset enumeration over the n columns) of the column set
+    of PST row t for node i. Candidate->column mapping is monotone, so digit
+    order is preserved and the subset's config bins line up with the PST
+    entry's.
+
+    The same hockey-stick formula as
+    core/combinatorics.rank_combinations_batch, which stays the host oracle
+    (tests/test_rank_map.py pins the two bitwise): one (n, S) int32 term per
+    PST position, so no intermediate is wider than the output. int32 is
+    exact while every value formed stays below 2**31, which n and s alone
+    decide; past that this raises at trace time.
+
+    Dense-assembly only — the streaming path computes the INVERSE map chunk
+    by chunk (streaming.py) and never materialises this array."""
+    largest = max([math.comb(n + 1, s + 1) + n_parent_sets(n, s)]
+                  + [k * math.comb(n, k) for k in range(2, s + 1)])
+    if largest >= 2 ** 31:
+        raise ValueError(
+            f"the int32 rank map is exact only below 2**31: at n={n}, s={s}, "
+            f"C(n+1, s+1) + the size offsets, or a binomial's product "
+            f"k*C(n, k), reaches {largest}")
+    off = size_offsets(n, s).tolist()
+    rank = jnp.zeros((n,) + psizes.shape, jnp.int32)
+    for k in range(1, s + 1):
+        rank = jnp.where(psizes == k, off[k], rank)
+    node = jnp.arange(n, dtype=jnp.int32)[:, None]
+    prev = jnp.int32(-1)
+    for j in range(s):
+        valid = j < psizes
+        r = jnp.where(valid, psizes - j, 1)
+        col = pst[:, j] + (pst[:, j] >= node).astype(jnp.int32)
+        term = (_binom(n - 1 - jnp.where(valid, prev, 0), r, s)
+                - _binom(n - jnp.where(valid, col, 0), r, s))
+        rank = rank + jnp.where(valid, term, 0)
+        prev = col
+    return rank
+
+
+def assemble_table(TI: jnp.ndarray, rank_map: jax.Array, psizes: np.ndarray,
                    log_gamma: float) -> jnp.ndarray:
     """(n, S) table from the fused per-subset output: a pure gather."""
     n = TI.shape[1]

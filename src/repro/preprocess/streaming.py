@@ -2,7 +2,7 @@
 with NO dense (n, S) intermediate (paper §III-A taken at its word).
 
 The dense assembly (pipeline.assemble_table) materialises the full (n, S)
-score table plus an (n, S) host-side rank map before pruning — at n = 100,
+score table plus an (n, S) rank map before pruning — at n = 100,
 s = 4 (S ≈ 3.9M) that is ~1.6 GB apiece, the memory wall that blocked the
 "n >= 100 in bounded memory" gate. This module inverts the dataflow: as each
 device finishes a column-subset chunk, its (chunk, n) fused scores are

@@ -1,6 +1,9 @@
 """Compile every main-path Pallas kernel for a described TPU v5e chip at the
 paper's real widths (n = 60, s = 4, w = 8, q = 3, m = 1000), without a chip.
 
+The dense assembly's device rank map is compiled here too: written as an
+(n, S, s) broadcast it needs 28 GB of a 16 GB chip at this size.
+
 Interpret mode checks numerics but not the TPU compiler's rules (block
 tiling, VMEM, unsupported primitives); these compiles do. Each test asserts
 the compiled HLO holds a Mosaic kernel (`tpu_custom_call`), so a kernel that
@@ -136,3 +139,15 @@ def test_chain_vmapped_kernel_compiles_for_v5e(one_chip, build):
     """vmap over chains adds a squeezed leading block dim to every operand;
     the blocks must stay legal with it."""
     assert "tpu_custom_call" in build(one_chip, chains=CHAINS)
+
+
+def test_rank_map_compiles_for_v5e_in_one_pass(one_chip):
+    """The (n, S) int32 rank map fuses into passes that keep no intermediate
+    as large as its output (an (n, S, s) layout would pad s = 4 to 128 lanes
+    and run out of HBM)."""
+    from repro.preprocess.pipeline import _rank_map
+    args = [jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+            for shape in ((S_SETS, S_MAX), (S_SETS,))]
+    mem = _rank_map.lower(N, S_MAX, *args).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < mem.output_size_in_bytes, (
+        mem.temp_size_in_bytes, mem.output_size_in_bytes)
