@@ -128,12 +128,15 @@ def preprocess(cell: Cell, data, cache: str, require_mosaic: bool = True):
     if require_mosaic:
         block_m, chunk = 512, 1024       # build_score_table_fused defaults
         m_pad = cell.m + (-cell.m) % block_m
+        R = cell.n * cell.q
         _require(_mosaic(
             fused_scores_pallas,
             jax.ShapeDtypeStruct((chunk, m_pad), jnp.int32),
-            jax.ShapeDtypeStruct((m_pad, cell.n * cell.q), jnp.float32),
+            jax.ShapeDtypeStruct((m_pad, R), jnp.float32),
             jax.ShapeDtypeStruct((chunk,), jnp.int32),
-            q=cell.q, s=cell.s, n=cell.n), "count+score kernel not compiled")
+            jax.ShapeDtypeStruct((1, R), jnp.float32),
+            jax.ShapeDtypeStruct((R, cell.n), jnp.float32),
+            Q=cell.q ** cell.s), "count+score kernel not compiled")
     return st
 
 

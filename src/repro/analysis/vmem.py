@@ -29,8 +29,10 @@ from .rules.pallas import PallasSite, iter_pallas_sites
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024     # ~16 MiB/core (Pallas TPU guide)
 
 # run-time dims with no static default anywhere: the documented estimate
-# basis (n/s/q match the repo's n=64 gate configs; w the default window)
-ASSUMED_DIMS = {"n": 64, "s": 4, "q": 3, "w": 8, "Q": 81, "n_planes": 3,
+# basis (n/s/q match the repo's n=64 gate configs, R = n*q the child one-hot
+# width there; w the default window)
+ASSUMED_DIMS = {"n": 64, "s": 4, "q": 3, "R": 192, "w": 8, "Q": 81,
+                "n_planes": 3,
                 "D": 128, "P": 3, "C": 256, "W": 8192, "S": 262144,
                 "m": 4096, "BH": 8, "Tq": 2048, "Tk": 2048}
 

@@ -4,6 +4,7 @@ from __future__ import annotations
 import numpy as np
 
 from .combinatorics import candidates_to_nodes, unrank_parent_set
+from .scores import arity_vector
 
 __all__ = ["random_dag", "random_cpts", "adjacency_from_best",
            "adjacency_from_ranks", "parents_list_from_adjacency",
@@ -26,16 +27,16 @@ def random_dag(rng: np.random.Generator, n: int, max_parents: int,
     return adj
 
 
-def random_cpts(rng: np.random.Generator, adj: np.ndarray, q: int,
+def random_cpts(rng: np.random.Generator, adj: np.ndarray, q,
                 concentration: float = 0.5) -> list[np.ndarray]:
-    """Dirichlet CPTs: cpts[i] has shape (q^{|parents|}, q). Low concentration
-    gives sharp (informative) conditionals."""
-    n = adj.shape[0]
-    cpts = []
-    for i in range(n):
-        r = q ** int(adj[:, i].sum())
-        cpts.append(rng.dirichlet(np.full(q, concentration), size=r))
-    return cpts
+    """Dirichlet CPTs: cpts[i] has shape (prod_{p in parents} r_p, r_i),
+    (q^{|parents|}, q) at one arity q for every variable; ``q`` is that int
+    or one arity per variable. Low concentration gives sharp (informative)
+    conditionals."""
+    r = arity_vector(q, adj.shape[0])
+    return [rng.dirichlet(np.full(r[i], concentration),
+                          size=int(np.prod(r[adj[:, i] != 0], dtype=np.int64)))
+            for i in range(len(r))]
 
 
 def topological_order(adj: np.ndarray) -> np.ndarray:

@@ -4,10 +4,18 @@ precomputed score table (the paper's "hash table", §III-A).
 ``ls(i, π) = |π|·ln γ + Σ_k [ lnΓ(α_k) − lnΓ(α_k + N_k)
                               + Σ_j ( lnΓ(N_jk + α_jk) − lnΓ(α_jk) ) ]``
 
-with BDeu hyperparameters ``α_jk = ess / (r_i · q)``, ``α_k = ess / r_i``,
-``r_i = q^{|π|}``.  Natural log internally (the paper's log10 is a constant
-factor that cancels in Metropolis–Hastings ratios; priors are rescaled to
-match — see priors.py).
+with BDeu hyperparameters ``α_jk = ess / (q_π · r_i)``, ``α_k = ess / q_π``,
+where variable ``i`` has ``r_i`` states and ``q_π = Π_{p∈π} r_p`` is the
+number of parent configurations (``q^{|π|}`` when every variable has ``q``
+states).  Natural log internally (the paper's log10 is a constant factor
+that cancels in Metropolis–Hastings ratios; priors are rescaled to match —
+see priors.py).
+
+Arities: every entry point takes ``q``, one int (every variable has ``q``
+states) or one int per variable; :func:`arity_vector` turns either into the
+one int32 arity vector ``r`` the code works with.  A parent configuration is
+a mixed-radix code with per-column strides (:func:`mixed_radix`), so the
+codes of a parent set fill exactly the first ``q_π`` bins.
 
 Counting N_jk is formulated as one-hot × one-hot matmuls so the hot loop is
 MXU work on TPU (see kernels/count for the Pallas version; this module is the
@@ -25,70 +33,116 @@ from jax.scipy.special import gammaln
 
 from .combinatorics import build_pst, n_parent_sets
 
-__all__ = ["count_parent_child", "local_scores_chunk", "build_score_table",
+__all__ = ["arity_vector", "check_states", "mixed_radix", "max_bins",
+           "count_parent_child", "local_scores_chunk", "build_score_table",
            "ScoreTable", "validate_prior_matrix"]
 
 
+def arity_vector(q, n: int) -> np.ndarray:
+    """(n,) int32 states per variable from ``q``: one int for every variable,
+    or a sequence of n ints."""
+    r = np.asarray(q)
+    if r.dtype.kind not in "iu":
+        raise ValueError(f"arities must be integers, got {q!r}")
+    if r.ndim == 0:
+        r = np.full(n, int(r))
+    if r.shape != (n,):
+        raise ValueError(f"{r.size} arities given for {n} variables")
+    if r.min() < 1:
+        raise ValueError(f"every variable needs at least one state: {q!r}")
+    return r.astype(np.int32)
+
+
+def check_states(data: np.ndarray, r: np.ndarray) -> None:
+    """Raise unless every state of column i lies in [0, r_i)."""
+    bad = (data < 0) | (data >= r[None, :])
+    if bad.any():
+        i = int(np.nonzero(bad.any(0))[0][0])
+        raise ValueError(f"data states must lie in [0, r_i): column {i} "
+                         f"has a state outside [0, {int(r[i])})")
+
+
+def mixed_radix(arity_ext: jnp.ndarray, cols: jnp.ndarray):
+    """(strides (..., s), q_σ (...)) int32 of column sets ``cols`` (..., s),
+    padding mapped to the appended zeros column whose arity is 1:
+    stride_j = Π_{l<j} r[cols_l], so a set's configuration codes fill
+    exactly [0, q_σ) and its active bins are the first q_σ (q**j and q**|σ|
+    at a uniform q)."""
+    r = arity_ext[cols]
+    strides = jnp.concatenate(
+        [jnp.ones_like(r[..., :1]), jnp.cumprod(r[..., :-1], axis=-1)], -1)
+    return strides, strides[..., -1] * r[..., -1]
+
+
+def max_bins(r, s: int) -> int:
+    """Largest q_σ over sets of at most s columns: the product of the s
+    largest arities (q**s at a uniform q)."""
+    return math.prod(sorted(r, reverse=True)[:s])
+
+
 def count_parent_child(data_ext: jnp.ndarray, node: int | jnp.ndarray,
-                       parent_cols: jnp.ndarray, q: int, s: int) -> jnp.ndarray:
+                       parent_cols: jnp.ndarray, q, s: int) -> jnp.ndarray:
     """Contingency counts N[c, parent_config, child_state] for a chunk of parent sets.
 
     data_ext: (m, n+1) int32 — data with an appended all-zeros column so padded
       parents (mapped to column n) contribute digit 0.
     parent_cols: (C, s) int32 column indices into data_ext (already node-mapped,
       padding -> n).
-    Returns (C, q**s, q) float32 counts.
+    q: one arity for every variable (an int) or a tuple of one per variable.
+    Returns (C, Q, r_max) float32 counts, Q = :func:`max_bins` and r_max the
+    largest arity (q**s and q at a uniform q); states past the child's own
+    arity count 0.
     """
-    m = data_ext.shape[0]
+    n = data_ext.shape[1] - 1
+    r = q if isinstance(q, tuple) else (q,) * n
+    arity_ext = jnp.asarray(r + (1,), jnp.int32)
+    strides, _ = mixed_radix(arity_ext, parent_cols)     # (C, s)
     cols = data_ext[:, parent_cols]                      # (m, C, s)
-    pw = (q ** jnp.arange(s, dtype=jnp.int32))           # (s,)
-    code = jnp.sum(cols * pw, axis=-1)                   # (m, C)
-    Q = q ** s
-    oh_code = jax.nn.one_hot(code, Q, dtype=jnp.float32)         # (m, C, Q)
-    oh_child = jax.nn.one_hot(data_ext[:, node], q, dtype=jnp.float32)  # (m, q)
+    code = jnp.sum(cols * strides, axis=-1)              # (m, C)
+    oh_code = jax.nn.one_hot(code, max_bins(r, s),
+                             dtype=jnp.float32)          # (m, C, Q)
+    oh_child = jax.nn.one_hot(data_ext[:, node], max(r),
+                              dtype=jnp.float32)         # (m, r_max)
     # MXU-shaped contraction over samples
     return jnp.einsum("mcQ,mj->cQj", oh_code, oh_child)
-
-
-def _bin_digits(q: int, s: int) -> np.ndarray:
-    """(q**s, s) digit decomposition of each parent-config bin index, base q."""
-    Q = q ** s
-    b = np.arange(Q, dtype=np.int64)
-    return np.stack([(b // q ** j) % q for j in range(s)], axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("q", "s", "use_pallas"))
 def local_scores_chunk(data_ext: jnp.ndarray, node: jnp.ndarray,
                        pst_chunk: jnp.ndarray, psize_chunk: jnp.ndarray,
-                       *, q: int, s: int,
+                       *, q, s: int,
                        log_gamma: float, ess: float,
                        use_pallas: bool = False) -> jnp.ndarray:
     """ls(node, π) for a chunk of parent sets. pst_chunk: (C, s) candidate idx, -1 pad.
+    q: one arity for every variable (an int) or a tuple of one per variable.
 
     use_pallas=True routes the counting matmul through kernels/count
     (count_contingency, interpret mode off-TPU) instead of the pure-jnp
-    einsum — same (C, Q, q) contract, MXU-tiled on real hardware."""
+    einsum — same (C, Q, q) contract, MXU-tiled on real hardware; that
+    kernel counts one arity for every variable."""
     n = data_ext.shape[1] - 1
+    q = q if isinstance(q, tuple) else (q,) * n
+    arity_ext = jnp.asarray(q + (1,), jnp.int32)
     # candidate -> node column; padding -> the zeros column n
     pcols = pst_chunk + (pst_chunk >= node)
     pcols = jnp.where(pst_chunk < 0, n, pcols)
     if use_pallas:
+        if len(set(q)) > 1:
+            raise ValueError("kernels/count counts one arity for every "
+                             "variable; use the einsum oracle")
         from ..kernels.count import count_contingency  # late: kernels layer
         counts = count_contingency(data_ext, data_ext[:, node], pcols,
-                                   q=q, s=s)                      # (C, Q, q)
+                                   q=q[0], s=s)                   # (C, Q, q)
     else:
-        counts = count_parent_child(data_ext, node, pcols, q, s)  # (C, Q, q)
+        counts = count_parent_child(data_ext, node, pcols, q, s)  # (C, Q, r_max)
 
     k = psize_chunk.astype(jnp.float32)                                # (C,)
-    r = jnp.power(float(q), k)                                         # q^{|π|}
-    alpha_jk = ess / (r * q)                                           # (C,)
-    alpha_k = ess / r
-
-    digits = jnp.asarray(_bin_digits(q, s))                            # (Q, s)
-    pad_pos = jnp.arange(s)[None, :] >= psize_chunk[:, None]           # (C, s)
-    # bin active iff every padded position has digit 0
-    active = jnp.all(jnp.where(pad_pos[:, None, :], digits[None] == 0, True),
-                     axis=-1)                                          # (C, Q)
+    _, qsig = mixed_radix(arity_ext, pcols)                            # q_π
+    r_pa = qsig.astype(jnp.float32)                                    # exact
+    alpha_jk = ess / (r_pa * arity_ext[node].astype(jnp.float32))      # (C,)
+    alpha_k = ess / r_pa
+    # the codes of a parent set fill its first q_π bins
+    active = jnp.arange(counts.shape[1])[None, :] < qsig[:, None]      # (C, Q)
 
     Nk = counts.sum(-1)                                                # (C, Q)
     a_k = alpha_k[:, None]
@@ -108,11 +162,11 @@ class ScoreTable:
     """Dense (n, S) local-score table + its PST. The TPU-native 'hash table'."""
 
     def __init__(self, table: jnp.ndarray, pst: np.ndarray, psizes: np.ndarray,
-                 q: int, s: int):
+                 q, s: int):
         self.table = table          # (n, S) float32
         self.pst = jnp.asarray(pst)        # (S, s) int32, -1 padded
         self.psizes = jnp.asarray(psizes)  # (S,) int32
-        self.q = q
+        self.q = q                  # int, or one arity per variable
         self.s = s
 
     @property
@@ -144,7 +198,7 @@ def validate_prior_matrix(prior_matrix, n: int) -> None:
 
 @functools.partial(jax.jit, static_argnames=("q", "s", "use_pallas"))
 def _node_scores_batched(data_ext, node, pst_chunks, psz_chunks, R, *,
-                         q: int, s: int, log_gamma: float, ess: float,
+                         q: tuple, s: int, log_gamma: float, ess: float,
                          use_pallas: bool):
     """All chunks of one node in a single device program (a lax.map over the
     stacked (nc, chunk, s) PST) — one launch per node instead of one per
@@ -163,14 +217,15 @@ def _node_scores_batched(data_ext, node, pst_chunks, psz_chunks, R, *,
     return jax.lax.map(body, (pst_chunks, psz_chunks)).reshape(-1)
 
 
-def build_score_table(data: np.ndarray, *, q: int, s: int,
+def build_score_table(data: np.ndarray, *, q, s: int,
                       gamma: float = 0.1, ess: float = 1.0,
                       chunk: int = 1024,
                       prior_matrix: np.ndarray | None = None,
                       use_pallas: bool = False) -> ScoreTable:
     """Preprocessing (paper §III-A): all local scores for |π| <= s.
 
-    data: (m, n) integer states in [0, q). Optionally folds the pairwise prior
+    data: (m, n) integer states, column i in [0, r_i) for the arities ``q``
+    (one int, or one per variable). Optionally folds the pairwise prior
     (paper §IV) into the table — priors are per-(node, parent-set) additive
     constants, so baking them in preserves Eq. 9 exactly.
 
@@ -182,8 +237,8 @@ def build_score_table(data: np.ndarray, *, q: int, s: int,
     """
     data = np.asarray(data, dtype=np.int32)
     m, n = data.shape
-    if np.any(data < 0) or np.any(data >= q):
-        raise ValueError(f"data states must lie in [0, {q})")
+    r = arity_vector(q, n)
+    check_states(data, r)
     validate_prior_matrix(prior_matrix, n)
     S = n_parent_sets(n - 1, s)
     pst, psizes = build_pst(n - 1, s)
@@ -201,7 +256,7 @@ def build_score_table(data: np.ndarray, *, q: int, s: int,
         np.pad(psizes, (0, pad)).reshape(-1, chunk))
     R = None if prior_matrix is None else jnp.asarray(prior_matrix, jnp.float32)
     rows = [_node_scores_batched(data_ext, jnp.int32(i), pst_chunks,
-                                 psz_chunks, R, q=q, s=s,
+                                 psz_chunks, R, q=tuple(r.tolist()), s=s,
                                  log_gamma=log_gamma, ess=ess,
                                  use_pallas=use_pallas)[:S]
             for i in range(n)]
@@ -210,7 +265,7 @@ def build_score_table(data: np.ndarray, *, q: int, s: int,
 
 
 def score_single(data: np.ndarray, node: int, parent_nodes: list[int], *,
-                 q: int, s: int, gamma: float = 0.1, ess: float = 1.0) -> float:
+                 q, s: int, gamma: float = 0.1, ess: float = 1.0) -> float:
     """Scalar oracle for tests: ls(node, parents as *node ids*)."""
     from .combinatorics import nodes_to_candidates
     data = np.asarray(data, np.int32)
@@ -220,6 +275,7 @@ def score_single(data: np.ndarray, node: int, parent_nodes: list[int], *,
     row[0, : len(cands)] = cands
     data_ext = jnp.asarray(np.concatenate([data, np.zeros((m, 1), np.int32)], 1))
     ls = local_scores_chunk(data_ext, jnp.int32(node), jnp.asarray(row),
-                            jnp.asarray([len(cands)], jnp.int32), q=q, s=s,
+                            jnp.asarray([len(cands)], jnp.int32),
+                            q=tuple(arity_vector(q, n).tolist()), s=s,
                             log_gamma=float(math.log(gamma)), ess=ess)
     return float(ls[0])

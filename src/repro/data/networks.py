@@ -2,7 +2,8 @@
 
 * STN — the 11-node signaling transduction network from human T-cells
   (Sachs et al., Science 2005; paper ref [10]); consensus edge set.
-* ALARM — the 37-node monitoring network (paper ref [17]); standard 46 edges.
+* ALARM — the 37-node monitoring network (paper ref [17]); standard 46 edges,
+  and its published states per variable (2 to 4; 509 free parameters).
 * synthetic — random sparse DAGs at arbitrary n for the paper's n > 60 scale
   claim (§VI uses networks the benchmark suite ships; past ALARM size we
   generate ALARM-like ground truth instead).
@@ -52,6 +53,37 @@ ALARM_EDGES = [
     ("SAO2", "CATECHOL"), ("TPR", "CATECHOL"), ("CATECHOL", "HR"),
     ("HR", "CO"), ("STROKEVOLUME", "CO"), ("CO", "BP"), ("TPR", "BP"),
 ]
+
+# states per ALARM variable as the bnlearn repository publishes them: 13
+# binary, 7 with four states, the other 17 with three
+_ALARM_BINARY = {"HISTORY", "HYPOVOLEMIA", "LVFAILURE", "ERRLOWOUTPUT",
+                 "ERRCAUTER", "INSUFFANESTH", "ANAPHYLAXIS", "KINKEDTUBE",
+                 "FIO2", "PULMEMBOLUS", "SHUNT", "DISCONNECT", "CATECHOL"}
+_ALARM_FOUR = {"EXPCO2", "MINVOL", "PRESS", "VENTMACH", "VENTTUBE",
+               "VENTLUNG", "VENTALV"}
+ALARM_ARITY = tuple(2 if v in _ALARM_BINARY else 4 if v in _ALARM_FOUR
+                    else 3 for v in ALARM_NODES)
+
+# named arity tables (``bn_learn --q alarm``)
+ARITY_TABLES = {"alarm": ALARM_ARITY}
+
+
+def parse_arity(spec):
+    """``q`` from a user: an int (one arity for every variable), a sequence
+    of ints, a comma list such as "2,3,3", or the name of a table in
+    ``ARITY_TABLES``. Returns an int or a tuple of ints."""
+    if isinstance(spec, str):
+        spec = spec.strip()
+        if spec in ARITY_TABLES:
+            return ARITY_TABLES[spec]
+        parts = [p for p in spec.split(",") if p.strip()]
+        if not parts or not all(p.strip().isdigit() for p in parts):
+            raise ValueError(f"arity {spec!r} is not an int, a comma list of "
+                             f"ints or one of {sorted(ARITY_TABLES)}")
+        spec = [int(p) for p in parts] if len(parts) > 1 else int(parts[0])
+    if isinstance(spec, (int, np.integer)):
+        return int(spec)
+    return tuple(int(v) for v in spec)
 
 
 def _adjacency(nodes: list[str], edges: list[tuple[str, str]]) -> np.ndarray:

@@ -4,8 +4,14 @@ pipeline, Fig. 2): preprocess → multi-chain order-MCMC → best-graph exchange
 Usage (also the library entry point used by examples/ and benchmarks/):
 
   python -m repro.launch.bn_learn --network alarm --iters 2000 --chains 4
+  python -m repro.launch.bn_learn --network alarm --q alarm \
+      --preprocess fused                         # ALARM's published arities
   python -m repro.launch.bn_learn --network synth --n 64 --s 3 \
       --preprocess fused --prune-delta 30        # fused pipeline + compression
+
+--q is the variables' number of states: one int for all, a comma list of
+one per variable, or a named table (``alarm``: ALARM's published 2-4
+states per variable); the data are sampled and scored at those arities.
 
 --preprocess fused routes score-table construction through preprocess/
 (count-once-per-subset + LUT scoring, ~20x the reference loop at n = 64 on
@@ -85,7 +91,7 @@ from ..core.order_scoring import (build_membership_planes,
                                   score_order_sum_cached,
                                   score_order_sum_delta)
 from ..data.bn_sampler import ancestral_sample, inject_noise
-from ..data.networks import (alarm_adjacency, stn_adjacency,
+from ..data.networks import (alarm_adjacency, parse_arity, stn_adjacency,
                              synthetic_adjacency)
 from ..preprocess import SparseScoreTable, build_score_table_fused
 from ..runtime.faults import parse_fault_plan
@@ -112,7 +118,9 @@ AUTO_PRUNE_DELTA = 20.0
 
 @dataclass
 class LearnConfig:
-    q: int = 2                    # states per variable
+    q: int | tuple = 2            # states per variable: one int for all, or
+                                  # one per variable (also "2,3,..." or a
+                                  # named table, see data.networks)
     s: int = 4                    # max parent-set size (paper uses 4)
     gamma: float = 0.1            # structure penalty
     ess: float = 1.0              # BDeu equivalent sample size
@@ -173,6 +181,9 @@ class LearnConfig:
                                   # "corrupt@1:bitflip;crash@1:after"
     heal_patience: int = 1        # consecutive unhealthy checks before a
                                   # chain is healed (1 = next boundary)
+
+    def __post_init__(self):
+        self.q = parse_arity(self.q)
 
 
 def _padded(st, block: int):
@@ -765,7 +776,7 @@ def learn_structure(data: np.ndarray, cfg: LearnConfig, *,
                    best_pos=best_pos)
 
 
-def _network_data(name: str, m: int, q: int, seed: int, n_synth: int = 64):
+def _network_data(name: str, m: int, q, seed: int, n_synth: int = 64):
     rng = np.random.default_rng(seed)
     if name == "synth":
         # synthetic scale-benchmark network (n defaults to 64 — past the
@@ -789,7 +800,10 @@ def main(argv=None) -> dict:
     ap.add_argument("--samples", type=int, default=1000)
     ap.add_argument("--iters", type=int, default=1000)
     ap.add_argument("--chains", type=int, default=1)
-    ap.add_argument("--q", type=int, default=2)
+    ap.add_argument("--q", type=parse_arity, default=2,
+                    help="states per variable: an int for all, a comma "
+                         "list of one per variable, or 'alarm' (ALARM's "
+                         "published arities)")
     ap.add_argument("--s", type=int, default=4)
     ap.add_argument("--noise", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)

@@ -1,8 +1,9 @@
 """Preprocessing disk cache: skip score-table construction on repeat runs.
 
-Keyed on everything the table depends on — a SHA-256 over the data bytes and
-the scoring hyperparameters (q, s, ess, gamma, prior matrix INCLUDING its
-shape/dtype) — so a second `bn_learn` invocation with identical inputs
+Keyed on everything the table depends on — a SHA-256 over the data bytes,
+the per-variable arity vector and the scoring hyperparameters (s, ess,
+gamma, prior matrix INCLUDING its shape/dtype) — so a second `bn_learn`
+invocation with identical inputs
 restores the table instead of recomputing it. Storage rides
 checkpoint/checkpointer: atomic publish (write-to-temp + rename) means a
 killed run can never leave a readable-but-corrupt cache entry, and entries
@@ -23,7 +24,7 @@ died with the streaming assembly — at n = 100, s = 4 the dense table is the
   build.
 
 Restores are **verified against the request**: every entry stores a manifest
-(q, s, m, n, gamma, ess, kind, ...) and ``load_cached_*`` takes an
+(arity, s, m, n, gamma, ess, kind, ...) and ``load_cached_*`` takes an
 ``expect`` mapping — any mismatch (stale format, hand-mixed cache dirs,
 truncated copies) is treated as a logged miss instead of being served as a
 silently wrong-shape table. The checkpointer additionally digests every
@@ -41,20 +42,24 @@ import os
 import numpy as np
 
 from ..checkpoint import latest_step, restore_checkpoint, save_checkpoint
+from ..core.scores import arity_vector
 
 __all__ = ["cache_key", "load_cached_table", "store_cached_table",
            "load_cached_sparse", "store_cached_sparse"]
 
-_FORMAT = "preprocess-v2"     # bump to invalidate every cached table
+_FORMAT = "preprocess-v3"     # bump to invalidate every cached table
 
 logger = logging.getLogger(__name__)
 
 
-def cache_key(data: np.ndarray, *, q: int, s: int, gamma: float, ess: float,
+def cache_key(data: np.ndarray, *, q, s: int, gamma: float, ess: float,
               prior_matrix: np.ndarray | None = None,
               prune_delta: float | None = None,
               max_keep: int | None = None) -> str:
-    """Hex digest identifying one preprocessing problem instance.
+    """Hex digest identifying one preprocessing problem instance. ``q`` is
+    one arity or one per variable; the digest holds the arity vector, so an
+    int q and the same arity for every variable share a key, and two
+    different vectors never do.
 
     ``prune_delta``/``max_keep`` enter the digest only when set — they key
     the PRUNED (sparse) entries, whose kept set depends on both; dense
@@ -62,7 +67,8 @@ def cache_key(data: np.ndarray, *, q: int, s: int, gamma: float, ess: float,
     h = hashlib.sha256()
     h.update(_FORMAT.encode())
     arr = np.ascontiguousarray(np.asarray(data, np.int32))
-    h.update(repr((arr.shape, q, s, float(gamma), float(ess))).encode())
+    h.update(repr((arr.shape, s, float(gamma), float(ess))).encode())
+    h.update(arity_vector(q, arr.shape[1]).tobytes())
     h.update(arr.tobytes())
     if prior_matrix is not None:
         R = np.ascontiguousarray(np.asarray(prior_matrix, np.float32))
@@ -99,7 +105,7 @@ def load_cached_table(cache_dir: str, key: str,
                       expect: dict | None = None):
     """(table, pst, psizes) numpy arrays, or None on miss.
 
-    ``expect`` maps manifest fields (q, s, m, n, gamma, ess, ...) to the
+    ``expect`` maps manifest fields (arity, s, m, n, gamma, ess, ...) to the
     values the caller is requesting; a stored manifest that disagrees is a
     logged miss (satellite bugfix: never serve a wrong-shape table)."""
     entry = _entry_dir(cache_dir, key)

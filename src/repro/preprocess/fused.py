@@ -1,32 +1,41 @@
 """Fused count+score for one column-subset chunk (paper §III-A on-device).
 
 The reference preprocessing (core/scores.build_score_table) materialises a
-(C, q^s, q) contingency tensor per (node, chunk) unit and re-builds the
-parent-config one-hot for every node. The fused formulation exploits two
-identities:
+(C, Q, r_max) contingency tensor per (node, chunk) unit and re-builds the
+parent-config one-hot for every node. The fused formulation exploits one
+identity:
 
 * **Count once per column subset, against every child at once.** The
   contingency counts for parent set pi of node i depend only on the *column
   set* sigma = columns(pi, i) and the child column i. Counting sigma jointly
-  against the one-hot of ALL n columns — one (Q x m) @ (m x n*q) matmul —
-  amortises the (m, C, Q) one-hot build over all n children, an ~n-fold cut
-  in the memory traffic that dominates preprocessing.
+  against the one-hot of ALL n columns — one (Q x m) @ (m x R) matmul, R =
+  sum_i r_i — amortises the (m, C, Q) one-hot build over all n children, an
+  ~n-fold cut in the memory traffic that dominates preprocessing.
 
-* **Scores depend on counts only through small integer marginals.** With a
-  uniform arity q, Eq. 4's gammaln terms take only (s+1) x (m+1) distinct
-  values: gammaln(N + alpha) for integer N in [0, m] and alpha determined by
-  |pi|. The ref path replaces gammaln evaluation with two precomputed lookup
-  tables (:func:`score_luts`), turning the transcendental bulk of scoring into
-  gathers; the Pallas kernel evaluates gammaln (:func:`lgamma_f32`) directly
-  on the (Q, n*q) counts block it just produced in VMEM — either way the
-  (C, q^s, q) tensor never reaches HBM, only the (C, n) fused output does.
+Arities may differ per variable (core/scores.arity_vector). A subset's
+configuration code is mixed-radix (core/scores.mixed_radix), so its codes
+fill exactly the first q_sigma = prod_{j in sigma} r_j bins. A chunk is
+computed at a static bin count Q >= every q_sigma in it (its bucket,
+planner.q_buckets), and the bins past q_sigma count nothing and add +0.0.
+Child i owns columns [off_i, off_i + r_i) of the child one-hot. BDeu takes
+alpha_j = ess / q_sigma and alpha_jk = ess / (q_sigma r_i), so the per-subset
+q_sigma and each column's child arity enter the scoring; at a uniform q
+they are q^|sigma| and q, the values the scores always had.
+
+The jnp path (:func:`fused_scores_ref`) scores through two lookup tables
+(:func:`score_luts`): Eq. 4's gammaln terms depend on the counts only
+through integer N in [0, m] and one alpha per q_sigma or q_sigma * r_i, so
+the transcendental bulk of scoring becomes gathers.
 
 The per-subset output is ``TI[c, i] = sum_{k active} (term_k + term_jk)`` —
 everything of ls(i, pi) except the |pi|*ln(gamma) structure penalty, which the
-assembly (pipeline.py) adds per PST entry. The bin reduction is an explicitly
-SEQUENTIAL accumulation over the q^s bins so it reproduces the oracle's
-row-sum order: fused tables match `local_scores_chunk` bitwise on CPU (the
-property tests in tests/test_preprocess.py pin this to <= 1e-4 absolute).
+assembly (pipeline.py) adds per PST entry. Only the (C, n) output reaches
+HBM. The bin reduction is an explicitly SEQUENTIAL accumulation over the
+bins so it reproduces the oracle's row-sum order: fused tables match
+`local_scores_chunk` bitwise on CPU (the property tests in
+tests/test_preprocess.py pin this to <= 1e-4 absolute). The Pallas kernel
+evaluates gammaln (:func:`lgamma_f32`) on the counts block it just produced
+in VMEM.
 """
 from __future__ import annotations
 
@@ -39,45 +48,36 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.scipy.special import gammaln
 
-__all__ = ["score_luts", "fused_scores_ref", "fused_scores_pallas",
-           "encode_subset_codes", "lgamma_f32"]
+from ..core.scores import mixed_radix
+
+__all__ = ["child_columns", "score_luts", "fused_scores_ref",
+           "fused_scores_pallas", "encode_subset_codes", "lgamma_f32"]
 
 
-def score_luts(q: int, s: int, m: int, ess: float):
-    """(lut_k, lut_j), each (s+1, m+1) f32: the two gammaln families of Eq. 4
-    tabulated over parent-set size k (rows) and integer count N (cols).
-
-    lut_k[k, N] = gammaln(a_k) - gammaln(a_k + N),   a_k  = ess / q^k
-    lut_j[k, N] = gammaln(N + a_jk) - gammaln(a_jk), a_jk = ess / (q^k * q)
-
-    Built with the same f32 ops as the oracle (jnp.power, jax gammaln) so the
-    tabulated values are bitwise the oracle's.
-    """
-    ks = jnp.arange(s + 1, dtype=jnp.float32)
-    r = jnp.power(float(q), ks)
-    a_k = (ess / r)[:, None]
-    a_jk = (ess / (r * q))[:, None]
-    counts = jnp.arange(m + 1, dtype=jnp.float32)[None, :]
-    lut_k = gammaln(a_k) - gammaln(a_k + counts)
-    lut_j = gammaln(counts + a_jk) - gammaln(a_jk)
-    return lut_k, lut_j
+def child_columns(arity: jnp.ndarray, col_child: jnp.ndarray):
+    """(state (R,), child arity (R,) float32) of every child one-hot column,
+    given the child each column belongs to (``np.repeat(arange(n), r)``):
+    child i owns columns [off_i, off_i + r_i), off = exclusive cumsum of r."""
+    off = jnp.cumsum(arity) - arity
+    state = jnp.arange(col_child.shape[0], dtype=jnp.int32) - off[col_child]
+    return state, arity[col_child].astype(jnp.float32)
 
 
 def encode_subset_codes(data_ext: jnp.ndarray, sub_chunk: jnp.ndarray,
-                        q: int) -> jnp.ndarray:
+                        arity_ext: jnp.ndarray) -> jnp.ndarray:
     """Mixed-radix configuration codes for a chunk of column subsets.
 
     data_ext: (m, n+1) with an appended all-zeros column; sub_chunk: (C, s)
-    sorted column indices, -1 padded (padding maps to the zeros column, so
-    padded digit positions are the HIGH digits and contribute 0 — which is
-    what makes `code < q^{|subset|}` the exact active-bin test).
+    sorted column indices, -1 padded (padding maps to the zeros column, of
+    arity 1 in ``arity_ext``, so padded digit positions contribute 0 — which
+    is what makes `code < q_sigma` the exact active-bin test).
     Returns (m, C) int32.
     """
     n = data_ext.shape[1] - 1
     cols = jnp.where(sub_chunk < 0, n, sub_chunk)        # (C, s)
+    strides, _ = mixed_radix(arity_ext, cols)            # (C, s)
     dcols = data_ext[:, cols]                            # (m, C, s)
-    pw = q ** jnp.arange(sub_chunk.shape[1], dtype=jnp.int32)
-    return jnp.sum(dcols * pw, axis=-1).astype(jnp.int32)
+    return jnp.sum(dcols * strides, axis=-1).astype(jnp.int32)
 
 
 def _sequential_bin_sum(masked: jnp.ndarray) -> jnp.ndarray:
@@ -94,30 +94,63 @@ def _sequential_bin_sum(masked: jnp.ndarray) -> jnp.ndarray:
     return acc
 
 
-@functools.partial(jax.jit, static_argnames=("q", "s", "n"))
-def fused_scores_ref(data_ext: jnp.ndarray, child_oh: jnp.ndarray,
-                     sub_chunk: jnp.ndarray, ssz_chunk: jnp.ndarray,
-                     lut_k: jnp.ndarray, lut_j: jnp.ndarray, *,
-                     q: int, s: int, n: int) -> jnp.ndarray:
+def score_luts(qsig: np.ndarray, r: np.ndarray, m: int, ess: float):
+    """(vk, lut_k, vj, lut_j): the two gammaln families of Eq. 4 tabulated
+    over integer counts N in [0, m], one row per value their alpha takes.
+
+    vk: the distinct q_sigma of ``qsig``; lut_k[i, N] = gammaln(a) -
+    gammaln(a + N), a = ess / vk[i].
+    vj: the distinct q_sigma * r_i; lut_j[i, N] = gammaln(N + a) -
+    gammaln(a), a = ess / vj[i].
+
+    At a uniform q the rows are |sigma| = 0..s, (s+1) x (m+1) values each.
+    Built with the same f32 ops as the oracle (jax gammaln of ess over the
+    exact integer q_sigma), so the tabulated values are bitwise the
+    oracle's; gathered inside the jitted chunk, they keep the scores free of
+    how XLA fuses a transcendental into its consumers."""
+    vk = np.unique(qsig).astype(np.int64)
+    vj = np.unique(vk[:, None] * np.unique(r)[None, :])
+    counts = jnp.arange(m + 1, dtype=jnp.float32)[None, :]
+    a_k = (ess / jnp.asarray(vk, jnp.float32))[:, None]
+    a_jk = (ess / jnp.asarray(vj, jnp.float32))[:, None]
+    lut_k = gammaln(a_k) - gammaln(a_k + counts)
+    lut_j = gammaln(counts + a_jk) - gammaln(a_jk)
+    return (jnp.asarray(vk, jnp.int32), lut_k, jnp.asarray(vj, jnp.int32),
+            lut_j)
+
+
+@functools.partial(jax.jit, static_argnames=("Q", "r_max"))
+def fused_scores_ref(data_ext: jnp.ndarray, arity: jnp.ndarray,
+                     sub_chunk: jnp.ndarray, qsig_chunk: jnp.ndarray, luts,
+                     *, Q: int, r_max: int) -> jnp.ndarray:
     """Pure-jnp fused chunk: (C, n) TI for one chunk of column subsets.
 
-    child_oh: (m, n*q) one-hot of every column (built once per table).
-    Counts are produced by one MXU-shaped contraction, immediately consumed
-    by LUT gathers, and discarded — the only chunk output is (C, n).
+    arity: (n,) int32 states per column, the largest r_max; qsig_chunk:
+    (C,) each subset's q_sigma <= Q; luts: :func:`score_luts`. Every child
+    gets r_max one-hot columns (states past its arity count 0 and score 0),
+    so the per-child sums are one reshape. Counts are produced by one
+    MXU-shaped contraction, immediately consumed by LUT gathers, and
+    discarded — the only chunk output is (C, n).
     """
+    vk, lut_k, vj, lut_j = luts
     C = sub_chunk.shape[0]
-    Q = q ** s
-    code = encode_subset_codes(data_ext, sub_chunk, q)               # (m, C)
+    n = arity.shape[0]
+    arity_ext = jnp.concatenate([arity, jnp.ones((1,), arity.dtype)])
+    col_child = jnp.repeat(jnp.arange(n, dtype=jnp.int32), r_max)
+    state = jnp.tile(jnp.arange(r_max, dtype=jnp.int32), n)
+    child_oh = (data_ext[:, col_child] == state[None, :]
+                ).astype(jnp.float32)                          # (m, n*r_max)
+    code = encode_subset_codes(data_ext, sub_chunk, arity_ext)       # (m, C)
     oh = jax.nn.one_hot(code, Q, dtype=jnp.float32)                  # (m, C, Q)
     counts = jnp.round(jnp.einsum("mcQ,mJ->cQJ", oh, child_oh)
-                       ).astype(jnp.int32)                           # (C, Q, n*q)
-    sz = ssz_chunk
-    Nk = counts[:, :, 0:q].sum(-1)                                   # (C, Q)
-    bins = jnp.arange(Q, dtype=jnp.float32)[None, :]
-    active = bins + 0.5 < jnp.power(float(q), sz.astype(jnp.float32))[:, None]
-    term_k = lut_k[sz[:, None], Nk]                                  # (C, Q)
-    term_j = lut_j[sz[:, None, None], counts]                        # (C, Q, n*q)
-    tj = term_j.reshape(C, Q, n, q).sum(-1)                          # (C, Q, n)
+                       ).astype(jnp.int32)                # (C, Q, n*r_max)
+    Nk = counts[:, :, 0:r_max].sum(-1)                               # (C, Q)
+    ik = jnp.searchsorted(vk, qsig_chunk)                            # (C,)
+    ij = jnp.searchsorted(vj, qsig_chunk[:, None] * arity[col_child])
+    term_k = lut_k[ik[:, None], Nk]                                  # (C, Q)
+    term_j = lut_j[ij[:, None, :], counts]                # (C, Q, n*r_max)
+    tj = term_j.reshape(C, Q, n, r_max).sum(-1)                # (C, Q, n)
+    active = jnp.arange(Q)[None, :] < qsig_chunk[:, None]            # (C, Q)
     masked = active[:, :, None] * (tj + term_k[:, :, None])
     return _sequential_bin_sum(masked)                               # (C, n)
 
@@ -158,9 +191,9 @@ def lgamma_f32(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(jnp.abs(x) == jnp.inf, jnp.inf, out)
 
 
-def _fused_kernel(sizes_ref, codes_ref, child_oh_ref, out_ref, counts_ref, *,
-                  Q: int, q: int, n: int, block_m: int, ess: float):
-    """Per (subset, m-block) program: accumulate the (Q, n*q) counts block in
+def _fused_kernel(qsig_ref, codes_ref, child_oh_ref, col_r_ref, sum_mat_ref,
+                  out_ref, counts_ref, *, Q: int, block_m: int, ess: float):
+    """Per (subset, m-block) program: accumulate the (Q, R) counts block in
     VMEM, and on the last m-block collapse it straight to the (n,) fused
     scores — the counts never leave VMEM (the fusion the paper leaves as
     future work, §VII)."""
@@ -178,25 +211,29 @@ def _fused_kernel(sizes_ref, codes_ref, child_oh_ref, out_ref, counts_ref, *,
     bins = jax.lax.broadcasted_iota(jnp.int32, (Q, block_m), 0)
     oh_t = (bins == codes).astype(jnp.float32)           # (Q, BM)
     counts_ref[...] += jnp.dot(oh_t, child_oh_ref[...],
-                               preferred_element_type=jnp.float32)  # (Q, n*q)
+                               preferred_element_type=jnp.float32)  # (Q, R)
 
     @pl.when(mb == nmb - 1)
     def _score():
         counts = counts_ref[...]
-        szf = jnp.full((1, 1), sizes_ref[c], jnp.int32).astype(jnp.float32)
-        r = jnp.power(float(q), szf)                                 # (1, 1)
-        a_k = ess / r
-        a_jk = ess / (r * q)
-        Nk = jnp.sum(counts[:, 0:q], axis=-1, keepdims=True)         # (Q, 1)
+        col_r = col_r_ref[...]                           # (1, R) child arity
+        # q_sigma as an exact integer: Mosaic's powf is inexact (3.0 ** 2
+        # reads 9.0000114 on a v5e), so no q ** |sigma| is formed here
+        r = jnp.full((1, 1), qsig_ref[c], jnp.int32).astype(jnp.float32)
+        a_k = ess / r                                                # (1, 1)
+        a_jk = ess / (r * col_r)                                     # (1, R)
+        # N_k: any one child's columns sum to it; take child 0's [0, r_0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, col_r.shape, 1)
+        first = lane.astype(jnp.float32) < col_r[:, 0:1]
+        Nk = jnp.sum(jnp.where(first, counts, 0.0), axis=-1,
+                     keepdims=True)                                  # (Q, 1)
         term_k = lgamma_f32(a_k) - lgamma_f32(a_k + Nk)              # (Q, 1)
-        gl = lgamma_f32(counts + a_jk) - lgamma_f32(a_jk)            # (Q, n*q)
-        # per-child j-sum as an MXU matmul with a block-diagonal 0/1 matrix
-        # (avoids an in-kernel reshape, which Mosaic restricts); fp32
-        # contraction, since gl is not representable in bf16
-        col = jax.lax.broadcasted_iota(jnp.int32, (n * q, n), 0) // q
-        tgt = jax.lax.broadcasted_iota(jnp.int32, (n * q, n), 1)
-        sum_mat = (col == tgt).astype(jnp.float32)                   # (n*q, n)
-        tj = jnp.dot(gl, sum_mat, precision=jax.lax.Precision.HIGHEST,
+        gl = lgamma_f32(counts + a_jk) - lgamma_f32(a_jk)            # (Q, R)
+        # per-child j-sum as an MXU matmul with the block-diagonal 0/1
+        # (R, n) matrix (avoids an in-kernel reshape, which Mosaic
+        # restricts); fp32 contraction, since gl is not representable in bf16
+        tj = jnp.dot(gl, sum_mat_ref[...],
+                     precision=jax.lax.Precision.HIGHEST,
                      preferred_element_type=jnp.float32)             # (Q, n)
         kbins = jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0)
         active = (kbins.astype(jnp.float32) + 0.5 < r).astype(jnp.float32)
@@ -207,15 +244,18 @@ def _fused_kernel(sizes_ref, codes_ref, child_oh_ref, out_ref, counts_ref, *,
         out_ref[...] = acc
 
 
-@functools.partial(jax.jit, static_argnames=("q", "s", "n", "ess", "block_m",
+@functools.partial(jax.jit, static_argnames=("Q", "ess", "block_m",
                                              "interpret"))
 def fused_scores_pallas(codes: jnp.ndarray, child_oh: jnp.ndarray,
-                        ssz_chunk: jnp.ndarray, *, q: int, s: int, n: int,
-                        ess: float = 1.0, block_m: int = 512,
+                        qsig_chunk: jnp.ndarray, col_r: jnp.ndarray,
+                        sum_mat: jnp.ndarray, *, Q: int, ess: float = 1.0,
+                        block_m: int = 512,
                         interpret: bool | None = None) -> jnp.ndarray:
     """Pallas fused count+score. codes: (C, m) int32 subset config codes with
-    -1 sample padding; child_oh: (m, n*q) one-hot of all columns (padded rows
-    contribute nothing); ssz_chunk: (C,) subset sizes. Returns (C, n) TI.
+    -1 sample padding, each < Q; child_oh: (m, R) one-hot of all columns
+    (padded rows contribute nothing); qsig_chunk: (C,) each subset's
+    q_sigma; col_r: (1, R) float32 arity of each column's child; sum_mat:
+    (R, n) float32, 1 where column k belongs to child i. Returns (C, n) TI.
     m must already be padded to a multiple of block_m.
 
     Codes enter as (C, 1, m) and the output leaves as (C, 1, n): a subset's
@@ -223,22 +263,25 @@ def fused_scores_pallas(codes: jnp.ndarray, child_oh: jnp.ndarray,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     C, m = codes.shape
-    Q = q ** s
+    R = sum_mat.shape[0]                  # child one-hot width, sum_i r_i
+    n = sum_mat.shape[1]
     assert m % block_m == 0, "pad m to a multiple of block_m (codes with -1)"
     grid = (C, m // block_m)
-    kernel = functools.partial(_fused_kernel, Q=Q, q=q, n=n,
-                               block_m=block_m, ess=ess)
+    kernel = functools.partial(_fused_kernel, Q=Q, block_m=block_m, ess=ess)
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),           # subset sizes
+            pl.BlockSpec(memory_space=pltpu.SMEM),           # subset q_sigma
             pl.BlockSpec((None, 1, block_m), lambda c, mb: (c, 0, mb)),
-            pl.BlockSpec((block_m, n * q), lambda c, mb: (mb, 0)),
+            pl.BlockSpec((block_m, R), lambda c, mb: (mb, 0)),
+            pl.BlockSpec((1, R), lambda c, mb: (0, 0)),
+            pl.BlockSpec((R, n), lambda c, mb: (0, 0)),
         ],
         out_specs=pl.BlockSpec((None, 1, n), lambda c, mb: (c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((C, 1, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((Q, n * q), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((Q, R), jnp.float32)],
         interpret=interpret,
-    )(ssz_chunk.astype(jnp.int32), codes[:, None, :], child_oh)
+    )(qsig_chunk.astype(jnp.int32), codes[:, None, :], child_oh, col_r,
+      sum_mat)
     return out[:, 0, :]

@@ -25,14 +25,19 @@ S/chunk chunks, one device round-trip each) with:
      table or rank map ever exists. Bitwise-equal to dense+prune
      (tests/test_streaming.py pins it).
 
-4. a disk cache (cache.py) keyed on (data, q, s, ess, gamma, prior). Dense
-   runs cache the dense table (one entry serves every delta); streaming runs
-   cache the pruned representation under a key that additionally includes
-   (prune_delta, max_keep) — "always cache the DENSE table" is no longer
+4. a disk cache (cache.py) keyed on (data, arities, s, ess, gamma, prior).
+   Dense runs cache the dense table (one entry serves every delta);
+   streaming runs cache the pruned representation under a key that
+   additionally includes (prune_delta, max_keep) — "always cache the DENSE table" is no longer
    possible at streaming scale. Pruned lookups try sparse first, then fall
    back to pruning a dense entry, then build. Every restore is
-   manifest-verified (wrong q/s/m/n/... is a logged miss, never a
+   manifest-verified (wrong arities/s/m/n/... is a logged miss, never a
    wrong-shape table).
+
+``q`` is one arity for every variable or one per variable; the table is
+the same either way for ``q = [q] * n``. Column subsets are counted in
+chunks of similar q_sigma (planner.plan_subsets), each at its bucket's bin
+count Q.
 
 The dense result is bitwise-compatible with build_score_table on CPU (the
 oracle's reduction order is reproduced deliberately; see fused.py) at a
@@ -43,8 +48,11 @@ where the dense intermediate alone is ~1.6 GB.
 
 With ``return_info=True`` the info dict has the SAME schema on cache hit and
 miss: {cache_hit, n, S, plan, preprocess_s, streaming,
-peak_assembly_bytes, stages}. ``plan`` is None on a cache hit (no sharding
-was planned), a {n_chunks, n_devices, imbalance} dict otherwise;
+peak_assembly_bytes, bins_real, bins_computed, stages}. ``plan`` is None on
+a cache hit (no sharding was planned), a {n_chunks, n_devices, imbalance,
+q_buckets} dict otherwise; ``bins_real`` (sum of q_sigma over the column
+subsets) and ``bins_computed`` (sum over chunks of chunk x Q) count the
+kernel's useful and computed bins, None on a cache hit;
 ``peak_assembly_bytes`` is None unless the streaming assembly ran.
 ``stages`` breaks ``preprocess_s`` into per-stage wall-clock seconds
 (plan_s/score_s/assemble_s on the dense path, plan_s/stream_s/finalize_s
@@ -65,13 +73,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.combinatorics import build_pst, n_parent_sets, size_offsets
-from ..core.scores import ScoreTable, validate_prior_matrix
+from ..core.scores import (ScoreTable, arity_vector, check_states,
+                           validate_prior_matrix)
 from ..telemetry.spans import span
 from .cache import (cache_key, load_cached_sparse, load_cached_table,
                     store_cached_sparse, store_cached_table)
-from .fused import (encode_subset_codes, fused_scores_pallas,
+from .fused import (child_columns, encode_subset_codes, fused_scores_pallas,
                     fused_scores_ref, score_luts)
-from .planner import plan_preprocess
+from .planner import plan_subsets
 from .sparse import SparseScoreTable, prune_table
 
 __all__ = ["build_score_table_fused", "assemble_table"]
@@ -140,41 +149,64 @@ def assemble_table(TI: jnp.ndarray, rank_map: jax.Array, psizes: np.ndarray,
     return kfac[None, :] + TI[rm, jnp.arange(n, dtype=jnp.int32)[:, None]]
 
 
-@functools.partial(jax.jit, static_argnames=("q", "s", "n", "ess",
+@functools.partial(jax.jit, static_argnames=("Q", "r_max", "ess",
                                              "use_pallas", "block_m",
                                              "interpret"))
-def _run_device(data_ext, subs, sszs, lut_k, lut_j, chunk_ids, *, q, s, n,
-                ess, use_pallas, block_m, interpret):
-    """One device's share: a single jitted scan over its chunk ids ->
-    stacked (U, C, n) TI. Module-level so the trace is compiled once per
-    problem shape, not once per build call. The streaming assembly reuses it
-    with (1,)-shaped chunk_ids (one trace serves all chunks)."""
+def _run_device(data_ext, arity, col_child, subs, qsigs, luts, chunk_ids, *,
+                Q, r_max, ess, use_pallas, block_m, interpret):
+    """One device's share of one bin-count bucket: a single jitted scan over
+    its chunk ids -> stacked (U, C, n) TI. ``arity`` (n,) holds the columns'
+    states, ``col_child`` (R,) the child of each child one-hot column,
+    ``qsigs`` each subset's q_sigma <= Q, ``luts`` the jnp path's
+    fused.score_luts. Module-level so the trace is
+    compiled once per problem shape and bucket, not once per build call. The
+    streaming assembly reuses it with (1,)-shaped chunk_ids (one trace
+    serves all chunks of a bucket)."""
     m = data_ext.shape[0]
-    child_oh = jax.nn.one_hot(data_ext[:, :n].reshape(-1), q,
-                              dtype=jnp.float32).reshape(m, n * q)
+    arity_ext = jnp.concatenate([arity, jnp.ones((1,), arity.dtype)])
     if use_pallas:
+        n = arity.shape[0]
+        state, col_r = child_columns(arity, col_child)
+        child_oh = (data_ext[:, col_child] == state[None, :]
+                    ).astype(jnp.float32)                         # (m, R)
         child_p = jnp.pad(child_oh, ((0, (-m) % block_m), (0, 0)))
+        sum_mat = (col_child[:, None] == jnp.arange(n)[None, :]
+                   ).astype(jnp.float32)                          # (R, n)
 
     def body(_, ci):
         sub_c = subs[ci]
-        ssz_c = sszs[ci]
+        qsig_c = qsigs[ci]
         if use_pallas:
-            codes = encode_subset_codes(data_ext, sub_c, q).T       # (C, m)
+            codes = encode_subset_codes(data_ext, sub_c, arity_ext).T  # (C, m)
             codes = jnp.pad(codes, ((0, 0), (0, (-m) % block_m)),
                             constant_values=-1)
-            ti = fused_scores_pallas(codes, child_p, ssz_c, q=q, s=s,
-                                     n=n, ess=ess, block_m=block_m,
+            ti = fused_scores_pallas(codes, child_p, qsig_c, col_r[None, :],
+                                     sum_mat, Q=Q, ess=ess, block_m=block_m,
                                      interpret=interpret)
         else:
-            ti = fused_scores_ref(data_ext, child_oh, sub_c, ssz_c,
-                                  lut_k, lut_j, q=q, s=s, n=n)
+            ti = fused_scores_ref(data_ext, arity, sub_c, qsig_c, luts, Q=Q,
+                                  r_max=r_max)
         return None, ti
 
     _, TI = jax.lax.scan(body, None, chunk_ids)
     return TI
 
 
-def build_score_table_fused(data: np.ndarray, *, q: int, s: int,
+def _device_inputs(data: np.ndarray, r: np.ndarray, lay, luts, device):
+    """The arrays every ``_run_device`` call of one build reads, on
+    ``device``: data with the zeros column, arities, the child of each child
+    one-hot column, the planned (n_chunks, chunk, ...) subsets and q_sigma,
+    and the score LUTs."""
+    m, n = data.shape
+    data_ext = np.concatenate([data, np.zeros((m, 1), np.int32)], axis=1)
+    col_child = np.repeat(np.arange(n, dtype=np.int32), r)
+    shape = (lay.n_chunks, lay.chunk)
+    return jax.device_put((data_ext, r, col_child,
+                           lay.sub.reshape(shape + (-1,)),
+                           lay.qsig.reshape(shape), luts), device)
+
+
+def build_score_table_fused(data: np.ndarray, *, q, s: int,
                             gamma: float = 0.1, ess: float = 1.0,
                             chunk: int = 1024,
                             prior_matrix: np.ndarray | None = None,
@@ -191,7 +223,8 @@ def build_score_table_fused(data: np.ndarray, *, q: int, s: int,
     PST ordering) via the fused pipeline. Returns a ScoreTable — or a
     SparseScoreTable when ``prune_delta`` is set — and, with
     ``return_info=True``, an info dict with a schema that is IDENTICAL on
-    cache hit and miss (see module docstring).
+    cache hit and miss (see module docstring). ``q`` is one arity for every
+    variable (an int) or a sequence of one per variable.
 
     ``streaming`` selects the assembly when ``prune_delta`` is set: None
     (default) and True stream chunks straight into the pruned table with no
@@ -208,8 +241,8 @@ def build_score_table_fused(data: np.ndarray, *, q: int, s: int,
     with span("preprocess.build") as build:
         data = np.asarray(data, dtype=np.int32)
         m, n = data.shape
-        if np.any(data < 0) or np.any(data >= q):
-            raise ValueError(f"data states must lie in [0, {q})")
+        r = arity_vector(q, n)
+        check_states(data, r)
         validate_prior_matrix(prior_matrix, n)
         if use_pallas is None:
             use_pallas = jax.default_backend() == "tpu"
@@ -222,9 +255,10 @@ def build_score_table_fused(data: np.ndarray, *, q: int, s: int,
         # the telemetry collector's stage rows (launch/bn_learn) read it
         info: dict = {"cache_hit": False, "n": n, "S": S, "plan": None,
                       "preprocess_s": None, "streaming": streaming,
-                      "peak_assembly_bytes": None, "stages": {}}
+                      "peak_assembly_bytes": None, "bins_real": None,
+                      "bins_computed": None, "stages": {}}
         log_gamma = float(np.log(gamma))
-        expect = {"q": q, "s": s, "m": m, "n": n,
+        expect = {"arity": r.tolist(), "s": s, "m": m, "n": n,
                   "gamma": float(gamma), "ess": float(ess)}
         if devices is None:
             devices = (list(np.asarray(mesh.devices).flat) if mesh is not None
@@ -263,12 +297,13 @@ def build_score_table_fused(data: np.ndarray, *, q: int, s: int,
                 delta=prune_delta, prior_matrix=prior_matrix,
                 max_keep=max_keep, devices=devices, use_pallas=use_pallas,
                 block_m=block_m, interpret=interpret)
-            info["plan"] = {k: sinfo[k] for k in
-                            ("n_chunks", "n_devices", "imbalance")}
-            info["peak_assembly_bytes"] = sinfo["peak_assembly_bytes"]
+            info["plan"] = {k: sinfo[k] for k in ("n_chunks", "n_devices",
+                                                  "imbalance", "q_buckets")}
+            for k in ("peak_assembly_bytes", "bins_real", "bins_computed"):
+                info[k] = sinfo[k]
             info["stages"].update(sinfo.get("stages", {}))
         else:
-            dense = _build_dense(data, q=q, s=s, ess=ess, chunk=chunk,
+            dense = _build_dense(data, r, s=s, ess=ess, chunk=chunk,
                                  log_gamma=log_gamma,
                                  prior_matrix=prior_matrix, devices=devices,
                                  use_pallas=use_pallas, block_m=block_m,
@@ -305,55 +340,53 @@ def build_score_table_fused(data: np.ndarray, *, q: int, s: int,
     return (st, info) if return_info else st
 
 
-def _build_dense(data: np.ndarray, *, q: int, s: int, ess: float, chunk: int,
-                 log_gamma: float, prior_matrix, devices, use_pallas: bool,
-                 block_m: int, interpret, info: dict):
+def _build_dense(data: np.ndarray, r: np.ndarray, *, s: int, ess: float,
+                 chunk: int, log_gamma: float, prior_matrix, devices,
+                 use_pallas: bool, block_m: int, interpret, info: dict):
     """(table, pst, psizes) by the dense assembly: plan the column-subset
     chunks, score them on the devices, rank-gather the (n, S) table. Fills
-    ``info["plan"]`` and the plan_s/score_s/assemble_s stages."""
+    ``info["plan"]``, the bin counts and the plan_s/score_s/assemble_s
+    stages."""
     m, n = data.shape
     with span("preprocess.plan") as plan_span:
         pst, psizes = build_pst(n - 1, s)
 
-        # plan: column subsets, chunked + cost-sharded (paper §III-B)
-        sub, ssz = build_pst(n, s)               # subsets of ALL n columns
-        Csub = sub.shape[0]
-        chunk = min(chunk, Csub)
-        pad = (-Csub) % chunk
-        sub_p = np.pad(sub, ((0, pad), (0, 0)), constant_values=-1)
-        ssz_p = np.pad(ssz, (0, pad))
-        nch = sub_p.shape[0] // chunk
-        plan = plan_preprocess(ssz_p, chunk, m, q, len(devices))
-        info["plan"] = {"n_chunks": plan.n_chunks,
-                        "n_devices": plan.n_devices,
-                        "imbalance": plan.imbalance}
+        # plan: column subsets, bucketed by q_sigma, chunked + cost-sharded
+        # (paper §III-B)
+        sub, _ = build_pst(n, s)                 # subsets of ALL n columns
+        lay = plan_subsets(sub, r, chunk, m, len(devices))
+        info.update(plan=lay.summary(), bins_real=lay.bins_real,
+                    bins_computed=lay.bins_computed)
 
     with span("preprocess.score") as score_span:
-        # execute: one jitted scan per device over its chunks
-        data_ext = np.concatenate([data, np.zeros((m, 1), np.int32)], axis=1)
-        subs3 = sub_p.reshape(nch, chunk, s)
-        sszs2 = ssz_p.reshape(nch, chunk)
-        lut_k, lut_j = score_luts(q, s, m, ess)
+        # execute: per device, one jitted scan over its chunks of a bucket
+        luts = score_luts(lay.qsig, r, m, ess)
         per_dev = []
-        for d, dev in enumerate(devices[:plan.n_devices]):
-            de = jax.device_put(jnp.asarray(data_ext), dev)
-            su = jax.device_put(jnp.asarray(subs3), dev)
-            sz = jax.device_put(jnp.asarray(sszs2), dev)
-            lk = jax.device_put(lut_k, dev)
-            lj = jax.device_put(lut_j, dev)
-            ids = jax.device_put(jnp.asarray(plan.padded_chunks[d]), dev)
-            out = _run_device(de, su, sz, lk, lj, ids, q=q, s=s, n=n,
-                              ess=ess, use_pallas=use_pallas,
-                              block_m=block_m,
-                              interpret=interpret)            # async dispatch
-            per_dev.append((plan.padded_chunks[d], out))
+        for d, dev in enumerate(devices[:lay.n_devices]):
+            ins = _device_inputs(data, r, lay, luts, dev)
+            for Q, first, plan in lay.buckets:
+                if d >= plan.n_devices:
+                    continue
+                ids = plan.padded_chunks[d] + first
+                # one program per bin-count bucket (at most MAX_BUCKETS),
+                # compiled once per problem shape and reused by every build
+                # bnlint: disable=retrace-loop-varying-static
+                out = _run_device(*ins, jax.device_put(jnp.asarray(ids), dev),
+                                  Q=Q, r_max=int(r.max()), ess=ess,
+                                  use_pallas=use_pallas, block_m=block_m,
+                                  interpret=interpret)        # async dispatch
+                per_dev.append((ids, out))
 
-        TI = np.zeros((nch * chunk, n), np.float32)
+        chunk = lay.chunk
+        TI = np.zeros((lay.n_chunks * chunk, n), np.float32)
         for ids, out in per_dev:
             out = np.asarray(out)                          # (U, C, n) sync
             for u, ci in enumerate(ids):                   # dupes: same data
                 TI[ci * chunk:(ci + 1) * chunk] = out[u]
-        TI = jnp.asarray(TI[:Csub])
+        # back to build_pst(n, s) order: the rank map's subset ranks
+        where = np.empty(len(sub), np.int32)
+        where[lay.row[lay.row >= 0]] = np.nonzero(lay.row >= 0)[0]
+        TI = jnp.asarray(TI)[jnp.asarray(where)]
 
     with span("preprocess.assemble") as assemble_span:
         # assemble: rank-gather + structure penalty (+ prior)

@@ -50,8 +50,9 @@ import numpy as np
 from ..core.combinatorics import (build_pst, n_parent_sets,
                                   rank_combinations_batch)
 from ..core.order_scoring import NEG_INF
+from ..core.scores import arity_vector
 from ..telemetry.spans import span
-from .planner import plan_preprocess
+from .planner import plan_subsets
 from .sparse import SparseScoreTable
 
 __all__ = ["build_sparse_table_streaming"]
@@ -149,7 +150,7 @@ def _prior_all_jit(R: jnp.ndarray, sub_c: jnp.ndarray) -> jnp.ndarray:
 
 
 def build_sparse_table_streaming(
-        data: np.ndarray, *, q: int, s: int, gamma: float = 0.1,
+        data: np.ndarray, *, q, s: int, gamma: float = 0.1,
         ess: float = 1.0, chunk: int = 1024, delta: float,
         prior_matrix: np.ndarray | None = None, max_keep: int | None = None,
         devices=None, use_pallas: bool = False, block_m: int = 512,
@@ -160,51 +161,43 @@ def build_sparse_table_streaming(
     lists AND hash arrays) while never allocating an (n, S)-sized array.
 
     stream_info: {"peak_assembly_bytes", "n_chunks", "n_devices",
-    "imbalance", "kept_entries", "K", "stages"} — ``stages`` breaks the
+    "imbalance", "q_buckets", "bins_real", "bins_computed", "kept_entries",
+    "K", "stages"} — the plan and the bin counts as the dense build reports
+    them; ``stages`` breaks the
     wall-clock into {plan_s, stream_s, finalize_s} for the telemetry
     collector's stage rows.
     """
     from .fused import score_luts
-    from .pipeline import _run_device
+    from .pipeline import _device_inputs, _run_device
 
     with span("preprocess.plan") as plan_span:
         data = np.asarray(data, dtype=np.int32)
         m, n = data.shape
+        r = arity_vector(q, n)
         S = n_parent_sets(n - 1, s)
         log_gamma = float(np.log(gamma))
 
-        # ---- plan: identical chunking + LPT sharding to the dense pipeline
-        sub, ssz = build_pst(n, s)                  # subsets of ALL n columns
-        Csub = sub.shape[0]
-        chunk = min(chunk, Csub)
-        pad = (-Csub) % chunk
-        sub_p = np.pad(sub, ((0, pad), (0, 0)), constant_values=-1)
-        ssz_p = np.pad(ssz, (0, pad))
-        del sub, ssz                  # keep only the padded copy on the host
-        nch = sub_p.shape[0] // chunk
+        # ---- plan: identical bucketing, chunking + LPT sharding to the
+        # dense pipeline
+        sub = build_pst(n, s)[0]                    # subsets of ALL n columns
         if devices is None:
             devices = [jax.devices()[0]]
-        plan = plan_preprocess(ssz_p, chunk, m, q, len(devices))
-
-        subs3 = sub_p.reshape(nch, chunk, s)
-        sszs2 = ssz_p.reshape(nch, chunk)
-        lut_k, lut_j = score_luts(q, s, m, ess)
-        data_ext = np.concatenate([data, np.zeros((m, 1), np.int32)], axis=1)
+        lay = plan_subsets(sub, r, chunk, m, len(devices))
+        del sub                      # keep only the planned copy on the host
+        chunk = lay.chunk
+        sub_p = lay.sub
+        ssz_p = (sub_p >= 0).sum(1, dtype=np.int32)
         R = (jnp.asarray(prior_matrix, jnp.float32)
              if prior_matrix is not None else None)
 
-        dev_in = []
-        for d, dev in enumerate(devices[:plan.n_devices]):
-            dev_in.append((jax.device_put(jnp.asarray(data_ext), dev),
-                           jax.device_put(jnp.asarray(subs3), dev),
-                           jax.device_put(jnp.asarray(sszs2), dev),
-                           jax.device_put(lut_k, dev),
-                           jax.device_put(lut_j, dev)))
+        luts = score_luts(lay.qsig, r, m, ess)
+        dev_in = [_device_inputs(data, r, lay, luts, dev)
+                  for dev in devices[:lay.n_devices]]
 
         # ---- streaming merge state
         best = np.full(n, np.float32(NEG_INF), np.float32)  # global best
         ls0 = np.full(n, np.float32(NEG_INF), np.float32)    # empty-set scores
-        partials = [_DevicePartial(s) for _ in range(plan.n_devices)]
+        partials = [_DevicePartial(s) for _ in range(lay.n_devices)]
         peak = 0
 
         def note_peak(tmp_bytes: int) -> None:
@@ -217,23 +210,21 @@ def build_sparse_table_streaming(
             nonlocal best
             sub_c = sub_p[ci * chunk:(ci + 1) * chunk]       # (C, s) node ids
             ssz_c = ssz_p[ci * chunk:(ci + 1) * chunk]
-            n_valid = int(np.clip(Csub - ci * chunk, 0, chunk))
+            row_c = lay.row[ci * chunk:(ci + 1) * chunk]     # -1: padding
             # same f32 composition as assemble_table: |σ|·ln γ + TI (+ prior)
             sc = ssz_c.astype(np.float32) * np.float32(log_gamma)
             sc = sc[:, None] + ti_c                           # (C, n)
             if R is not None:
                 sc = sc + np.asarray(_prior_all_jit(R, jnp.asarray(sub_c)))
             member = (sub_c[:, :, None] == arange_n[None, None, :]).any(1)
-            valid = np.zeros((chunk, 1), bool)
-            valid[:n_valid] = True
-            dom = valid & ~member                             # (C, n) child ok
+            dom = (row_c >= 0)[:, None] & ~member             # (C, n) child ok
             chunk_best = np.where(dom, sc, np.float32(NEG_INF)).max(0)
             best = np.maximum(best, chunk_best)
-            if ci * chunk == 0:                           # σ = ∅ lives here
-                ls0[:] = sc[0]
             keep = dom & (sc >= (best - float(delta))[None, :])
-            if ci * chunk == 0:
-                keep[0] = False          # rank 0 re-inserted at finalisation
+            empty = np.nonzero(row_c == 0)[0]
+            if len(empty):                                # σ = ∅ lives here
+                ls0[:] = sc[empty[0]]
+                keep[empty[0]] = False   # rank 0 re-inserted at finalisation
             cc, ii = np.nonzero(keep)
             if len(cc):
                 rows = sub_c[cc]                              # (L, s) node ids
@@ -250,20 +241,23 @@ def build_sparse_table_streaming(
     # ---- dispatch: round-robin over the LPT buckets, bounded in-flight
     with span("preprocess.stream") as stream_span:
         schedule = []
-        width = max(len(b) for b in plan.device_chunks)
-        for r in range(width):
-            for d, bucket in enumerate(plan.device_chunks):
-                if r < len(bucket):
-                    schedule.append((d, bucket[r]))
+        for Q, first, plan in lay.buckets:
+            width = max(len(b) for b in plan.device_chunks)
+            for t in range(width):
+                for d, bucket in enumerate(plan.device_chunks):
+                    if t < len(bucket):
+                        schedule.append((d, first + bucket[t], Q))
         pending: deque = deque()
-        for d, ci in schedule:
-            de, su, sz, lk, lj = dev_in[d]
+        for d, ci, Q in schedule:
             ids = jax.device_put(jnp.asarray([ci], jnp.int32), devices[d])
-            out = _run_device(de, su, sz, lk, lj, ids, q=q, s=s, n=n, ess=ess,
-                              use_pallas=use_pallas, block_m=block_m,
+            # one program per bin-count bucket, as in the dense build
+            # bnlint: disable=retrace-loop-varying-static
+            out = _run_device(*dev_in[d], ids, Q=Q, r_max=int(r.max()),
+                              ess=ess, use_pallas=use_pallas,
+                              block_m=block_m,
                               interpret=interpret)            # async dispatch
             pending.append((d, ci, out))
-            if len(pending) >= _INFLIGHT_PER_DEV * plan.n_devices:
+            if len(pending) >= _INFLIGHT_PER_DEV * lay.n_devices:
                 dd, cc_, fut = pending.popleft()
                 merge_chunk(dd, cc_, np.asarray(fut)[0])
         while pending:
@@ -306,8 +300,8 @@ def build_sparse_table_streaming(
 
         sp = SparseScoreTable.from_kept(kept_idx, kept_ls, kept_parents,
                                         q=q, s=s, delta=delta, S=S)
-    info = {"peak_assembly_bytes": int(peak), "n_chunks": plan.n_chunks,
-            "n_devices": plan.n_devices, "imbalance": plan.imbalance,
+    info = {"peak_assembly_bytes": int(peak), **lay.summary(),
+            "bins_real": lay.bins_real, "bins_computed": lay.bins_computed,
             "kept_entries": int(counts.sum()) + n, "K": K,
             "stages": {"plan_s": plan_span.seconds,
                        "stream_s": stream_span.seconds,

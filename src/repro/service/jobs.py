@@ -12,7 +12,7 @@ the interleaving only changes *when* each segment executes on the host,
 never the segment boundaries or any PRNG stream.
 
 Admission rides the preprocess cache's content key: two requests with
-identical (data, q, s, ess, gamma, prior, pruning) AND identical
+identical (data, arity vector, s, ess, gamma, prior, pruning) AND identical
 run-affecting config (iters, chains, seed, windows, telemetry cadence, …)
 hash to the same job id, so the second request ATTACHES to the in-flight
 or completed job instead of recomputing — the dedup layer the ROADMAP's
@@ -70,10 +70,11 @@ class DatasetSpec:
     path: str = ""           # network == "file": .npy sample matrix
 
 
-def load_dataset(spec: DatasetSpec, q: int) -> np.ndarray:
+def load_dataset(spec: DatasetSpec, q) -> np.ndarray:
     """Materialise the sample matrix for one dataset spec — the same
     generators the ``bn_learn`` CLI uses, so a service job and a standalone
-    run of the same spec see byte-identical data."""
+    run of the same spec see byte-identical data. ``q``: states per
+    variable, one int or one per variable (``LearnConfig.q``)."""
     if spec.network == "file":
         data = np.load(spec.path, allow_pickle=False)
         if data.ndim != 2:
@@ -111,8 +112,9 @@ def service_config(overrides: dict | None = None, **kw) -> LearnConfig:
 
 def admission_key(data: np.ndarray, cfg: LearnConfig,
                   prior_matrix: np.ndarray | None = None) -> str:
-    """Content-addressed job id: the preprocess cache key (data, q, s, ess,
-    gamma, prior, pruning) extended with every run-affecting config field.
+    """Content-addressed job id: the preprocess cache key (data, the
+    per-variable arity vector of ``cfg.q``, s, ess, gamma, prior, pruning)
+    extended with every run-affecting config field.
     Identical requests — however many users submit them — collapse to one
     id, which is the admission/dedup contract."""
     prune_delta = cfg.prune_delta if cfg.prune_delta > 0 else None

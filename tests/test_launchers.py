@@ -41,6 +41,23 @@ def test_bn_learn_cli():
     assert out["adjacency"].shape == (11, 11)
 
 
+def test_bn_learn_cli_published_arities():
+    """--network alarm --q alarm samples and learns ALARM at its published
+    states per variable, through the fused build; a malformed --q fails
+    fast."""
+    from repro.data.networks import ALARM_ARITY
+    from repro.launch import bn_learn
+    _, data = bn_learn._network_data("alarm", 300, ALARM_ARITY, 3)
+    assert (data.max(0) == np.asarray(ALARM_ARITY) - 1).all()
+    out = bn_learn.main(["--network", "alarm", "--q", "alarm", "--s", "2",
+                         "--iters", "40", "--samples", "300",
+                         "--preprocess", "fused", "--cache-dir", ""])
+    assert np.isfinite(out["score"])
+    assert out["adjacency"].shape == (37, 37)
+    with pytest.raises(SystemExit):
+        bn_learn.main(["--network", "stn", "--q", "2,x", "--iters", "5"])
+
+
 def test_bn_learn_cli_rejects_degenerate_windows():
     """--window 1 (no in-window move) and --window > n (would be silently
     clamped mid-trace) fail FAST with a readable argparse error."""

@@ -12,13 +12,26 @@ from repro.core.order_scoring import (score_order_blocked, score_order_pruned,
 from repro.core.scores import build_score_table
 from repro.core.sharded_scoring import pad_table
 from repro.preprocess import (SparseScoreTable, build_score_table_fused,
-                              plan_preprocess, prune_table)
-from repro.preprocess.fused import (encode_subset_codes, fused_scores_pallas,
-                                    fused_scores_ref, score_luts)
+                              plan_preprocess, plan_subsets, prune_table)
+from repro.preprocess.fused import (child_columns, encode_subset_codes,
+                                    fused_scores_pallas, fused_scores_ref,
+                                    score_luts)
 
 
 def _rand_problem(rng, n, q, m):
-    return rng.integers(0, q, size=(m, n)).astype(np.int32)
+    """(m, n) states: q is one arity, or one per column."""
+    return rng.integers(0, np.asarray(q), size=(m, n)).astype(np.int32)
+
+
+# seeded mixed-arity problems: (n, arities drawn from 2..4, s, m), m not a
+# multiple of the 64-sample kernel block
+MIXED = [(6, 2, 77, 0), (7, 3, 101, 1), (9, 2, 150, 2), (8, 3, 130, 3)]
+
+
+def _mixed_problem(n, s, m, seed):
+    rng = np.random.default_rng(seed)
+    r = rng.integers(2, 5, size=n)
+    return _rand_problem(rng, n, r, m), tuple(int(v) for v in r)
 
 
 # ------------------------------------------------------------ fused == oracle
@@ -81,30 +94,106 @@ def test_lgamma_f32_is_bitwise_xla_lgamma():
     np.testing.assert_array_equal(got, want)
 
 
-def test_fused_pallas_kernel_matches_ref():
+@pytest.mark.parametrize("q", [3, (3, 2, 4, 2, 3, 4, 2)])
+def test_fused_pallas_kernel_matches_ref(q):
     """Pallas fused count+score == jnp fused chunk (interpret mode), with the
     padded sample rows deliberately CORRUPTED in the child one-hot — the
-    in-kernel mask must neutralise them."""
+    in-kernel mask must neutralise them — at one arity and at mixed
+    arities."""
     rng = np.random.default_rng(5)
-    n, q, s, m = 7, 3, 2, 100
+    n, s, m = 7, 2, 100
     data = _rand_problem(rng, n, q, m)
+    r = np.broadcast_to(np.asarray(q), (n,)).astype(np.int32)
     data_ext = jnp.asarray(np.concatenate([data, np.zeros((m, 1), np.int32)],
                                           axis=1))
-    sub, ssz = build_pst(n, s)
-    lut_k, lut_j = score_luts(q, s, m, 1.0)
-    child_oh = jax.nn.one_hot(data_ext[:, :n].reshape(-1), q,
-                              dtype=jnp.float32).reshape(m, n * q)
-    want = fused_scores_ref(data_ext, child_oh, jnp.asarray(sub),
-                            jnp.asarray(ssz), lut_k, lut_j, q=q, s=s, n=n)
+    sub, _ = build_pst(n, s)
+    lay = plan_subsets(sub, r, len(sub), m, 1)
+    Q = max(Q for Q, _, _ in lay.buckets)
+    subs, qsig = jnp.asarray(lay.sub), jnp.asarray(lay.qsig)
+    want = fused_scores_ref(data_ext, jnp.asarray(r), subs, qsig,
+                            score_luts(lay.qsig, r, m, 1.0), Q=Q,
+                            r_max=int(r.max()))
+    col_child = jnp.asarray(np.repeat(np.arange(n, dtype=np.int32), r))
+    state, col_r = child_columns(jnp.asarray(r), col_child)
+    child_oh = (data_ext[:, col_child] == state[None, :]).astype(jnp.float32)
+    sum_mat = (col_child[:, None] == jnp.arange(n)[None, :]).astype(
+        jnp.float32)
     block_m = 64
     pad = (-m) % block_m
-    codes = encode_subset_codes(data_ext, jnp.asarray(sub), q).T
+    arity_ext = jnp.asarray(np.append(r, 1))
+    codes = encode_subset_codes(data_ext, subs, arity_ext).T
     codes_p = jnp.pad(codes, ((0, 0), (0, pad)), constant_values=-1)
     child_p = jnp.pad(child_oh, ((0, pad), (0, 0)), constant_values=1.0)
-    got = fused_scores_pallas(codes_p, child_p, jnp.asarray(ssz), q=q, s=s,
-                              n=n, ess=1.0, block_m=block_m, interpret=True)
+    got = fused_scores_pallas(codes_p, child_p, qsig, col_r[None, :], sum_mat,
+                              Q=Q, ess=1.0, block_m=block_m, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4, rtol=0)
+
+
+@pytest.mark.parametrize("n,s,m,seed", MIXED)
+def test_fused_mixed_arity_matches_oracle(n, s, m, seed):
+    """At arities drawn from 2..4, the fused table (jnp path, and the
+    Pallas kernel in interpret mode) equals the core/scores oracle: bitwise
+    on the jnp path, within the kernel gate on the Pallas one."""
+    data, r = _mixed_problem(n, s, m, seed)
+    want = np.asarray(build_score_table(data, q=r, s=s).table)
+    got = np.asarray(build_score_table_fused(data, q=r, s=s).table)
+    np.testing.assert_array_equal(got, want)
+    kern = np.asarray(build_score_table_fused(data, q=r, s=s, use_pallas=True,
+                                              block_m=64).table)
+    np.testing.assert_allclose(kern, want, atol=1e-4, rtol=0)
+
+
+def _uniform_ls_chunk(data_ext, node, pst_chunk, psize_chunk, *, q, s,
+                      log_gamma, ess):
+    """The one-arity oracle as it read before arities could differ: codes in
+    base q, bins active by their digits, alpha from q**k. Kept to pin the
+    one-arity tables bitwise to it."""
+    from jax.scipy.special import gammaln
+    n = data_ext.shape[1] - 1
+    pcols = pst_chunk + (pst_chunk >= node)
+    pcols = jnp.where(pst_chunk < 0, n, pcols)
+    code = jnp.sum(data_ext[:, pcols] * q ** jnp.arange(s), axis=-1)
+    counts = jnp.einsum("mcQ,mj->cQj", jax.nn.one_hot(code, q ** s),
+                        jax.nn.one_hot(data_ext[:, node], q))
+    k = psize_chunk.astype(jnp.float32)
+    r = jnp.power(float(q), k)
+    b = np.arange(q ** s)
+    digits = jnp.asarray(np.stack([(b // q ** j) % q for j in range(s)], -1))
+    pad_pos = jnp.arange(s)[None, :] >= psize_chunk[:, None]
+    active = jnp.all(jnp.where(pad_pos[:, None, :], digits[None] == 0, True),
+                     axis=-1)
+    a_k = (ess / r)[:, None]
+    a_jk = (ess / (r * q))[:, None, None]
+    terms = active * (gammaln(a_k) - gammaln(a_k + counts.sum(-1))
+                      + (gammaln(counts + a_jk) - gammaln(a_jk)).sum(-1))
+    acc = terms[:, 0]
+    for t in range(1, terms.shape[1]):
+        acc = acc + terms[:, t]
+    return k * log_gamma + acc
+
+
+@pytest.mark.parametrize("q,s", [(2, 3), (3, 2), (4, 2)])
+def test_uniform_arity_tables_bitwise_unchanged(q, s):
+    """One arity for every variable scores exactly as before arities could
+    differ: the oracle, the fused jnp path and q given as a full vector are
+    all bitwise the one-arity formula."""
+    rng = np.random.default_rng(10 + q)
+    n, m = 7, 111
+    data = _rand_problem(rng, n, q, m)
+    pst, psizes = build_pst(n - 1, s)
+    data_ext = jnp.asarray(np.concatenate([data, np.zeros((m, 1), np.int32)],
+                                          axis=1))
+    f = jax.jit(_uniform_ls_chunk, static_argnames=("q", "s", "log_gamma",
+                                                    "ess"))
+    want = np.stack([np.asarray(f(data_ext, jnp.int32(i), jnp.asarray(pst),
+                                  jnp.asarray(psizes), q=q, s=s,
+                                  log_gamma=float(np.log(0.1)), ess=1.0))
+                     for i in range(n)])
+    for got in (build_score_table(data, q=q, s=s).table,
+                build_score_table_fused(data, q=q, s=s).table,
+                build_score_table_fused(data, q=[q] * n, s=s).table):
+        np.testing.assert_array_equal(np.asarray(got), want)
 
 
 # ------------------------------------------------------------ sparse table
@@ -222,7 +311,7 @@ def test_planner_coverage_and_balance():
     pad = (-len(ssz)) % chunk
     ssz_p = np.pad(ssz, (0, pad))
     for ndev in (1, 2, 3, 7):
-        plan = plan_preprocess(ssz_p, chunk, m=100, q=2, n_devices=ndev)
+        plan = plan_preprocess(2 ** ssz_p, chunk, m=100, n_devices=ndev)
         seen = sorted(c for b in plan.device_chunks for c in b)
         assert seen == list(range(plan.n_chunks))
         assert plan.imbalance <= 4 / 3 + 1e-9
@@ -234,10 +323,41 @@ def test_planner_coverage_and_balance():
 
 
 def test_planner_cost_model():
-    """Costs follow the paper's q^{|pi|} * m estimate."""
+    """Costs follow the paper's q_pi * m estimate."""
     ssz = np.asarray([0, 1, 2, 2])
-    plan = plan_preprocess(ssz, chunk=2, m=10, q=3, n_devices=1)
+    plan = plan_preprocess(3 ** ssz, chunk=2, m=10, n_devices=1)
     np.testing.assert_allclose(plan.costs, [(1 + 3) * 10, (9 + 9) * 10])
+
+
+@pytest.mark.parametrize("q", [3, (2, 3, 4, 2, 3, 4, 2, 3, 3, 4, 2)])
+def test_subset_plan_buckets(q):
+    """Every column subset lands in exactly one row, in a bucket whose Q is
+    at least its q_sigma; at most MAX_BUCKETS buckets; the bin counts add
+    up; at one arity the rows keep build_pst order."""
+    from repro.preprocess.planner import MAX_BUCKETS
+    n, s, chunk = 11, 3, 32
+    r = np.broadcast_to(np.asarray(q), (n,)).astype(np.int32)
+    sub, ssz = build_pst(n, s)
+    lay = plan_subsets(sub, r, chunk, m=50, n_devices=2)
+    assert len(lay.buckets) <= MAX_BUCKETS
+    real = lay.row >= 0
+    assert sorted(lay.row[real].tolist()) == list(range(len(sub)))
+    np.testing.assert_array_equal(lay.sub[real], sub[lay.row[real]])
+    qsig = np.prod(np.where(sub < 0, 1, r[np.maximum(sub, 0)]), axis=1)
+    np.testing.assert_array_equal(lay.qsig[real], qsig[lay.row[real]])
+    covered = []
+    for Q, first, plan in lay.buckets:
+        ids = sorted(c for b in plan.device_chunks for c in b)
+        assert ids == list(range(plan.n_chunks))
+        rows = slice(first * chunk, (first + plan.n_chunks) * chunk)
+        assert lay.qsig[rows].max() <= Q
+        covered += list(range(first, first + plan.n_chunks))
+    assert covered == list(range(lay.n_chunks))
+    assert lay.bins_real == int(qsig.sum())
+    assert lay.bins_computed == sum(Q * p.n_chunks * chunk
+                                    for Q, _, p in lay.buckets)
+    if np.ndim(q) == 0:
+        assert (lay.row[real] == np.arange(len(sub))).all()
 
 
 # ------------------------------------------------------------------ cache
@@ -266,6 +386,46 @@ def test_cache_roundtrip_and_key_sensitivity(tmp_path):
     sp, i5 = build_score_table_fused(data, q=q, s=s, prune_delta=5.0,
                                      cache_dir=d, return_info=True)
     assert i5["cache_hit"] and isinstance(sp, SparseScoreTable)
+
+
+def test_cache_keys_on_arities(tmp_path):
+    """The arity vector is in the key and the manifest: one arity given as
+    an int or as a full vector is the same problem, a different vector is
+    a miss, and an entry is never served to a request with other arities
+    even under its key."""
+    from repro.preprocess.cache import cache_key, load_cached_table
+    rng = np.random.default_rng(18)
+    n, s, m = 6, 2, 80
+    data = _rand_problem(rng, n, 2, m)
+    mixed = [2] * (n - 1) + [3]
+    d = str(tmp_path)
+    kw = dict(s=s, gamma=0.1, ess=1.0)
+    assert cache_key(data, q=2, **kw) == cache_key(data, q=[2] * n, **kw)
+    assert cache_key(data, q=2, **kw) != cache_key(data, q=mixed, **kw)
+    _, i1 = build_score_table_fused(data, q=2, s=s, cache_dir=d,
+                                    return_info=True)
+    _, i2 = build_score_table_fused(data, q=[2] * n, s=s, cache_dir=d,
+                                    return_info=True)
+    st3, i3 = build_score_table_fused(data, q=mixed, s=s, cache_dir=d,
+                                      return_info=True)
+    assert (i1["cache_hit"], i2["cache_hit"], i3["cache_hit"]) == (
+        False, True, False)
+    want = build_score_table(data, q=mixed, s=s).table
+    np.testing.assert_array_equal(np.asarray(st3.table), np.asarray(want))
+    expect = {"arity": mixed, "s": s, "m": m, "n": n, "gamma": 0.1,
+              "ess": 1.0}
+    assert load_cached_table(d, cache_key(data, q=2, **kw),
+                             expect=expect) is None
+
+
+def test_state_outside_arity_rejected():
+    data = np.zeros((10, 3), np.int32)
+    data[4, 2] = 2
+    build_score_table_fused(data, q=[2, 2, 3], s=1)
+    with pytest.raises(ValueError, match="column 2"):
+        build_score_table_fused(data, q=2, s=1)
+    with pytest.raises(ValueError, match="3 arities given for 4"):
+        build_score_table(np.zeros((5, 4), np.int32), q=[2, 2, 2], s=1)
 
 
 # ------------------------------------------------- end-to-end via bn_learn

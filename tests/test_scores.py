@@ -12,34 +12,37 @@ from repro.core.scores import build_score_table, score_single
 
 
 def oracle_local_score(data, node, parents, q, gamma, ess):
-    """Pure-Python Eq. 4 (natural log), counting with dicts."""
-    m = data.shape[0]
+    """Pure-Python Eq. 4 (natural log), counting with dicts. q: one arity
+    for every variable or one per variable."""
+    import itertools
+    r = [q] * data.shape[1] if np.ndim(q) == 0 else list(q)
     k = len(parents)
-    r = q ** k
-    a_jk = ess / (r * q)
-    a_k = ess / r
+    q_pa = math.prod(r[p] for p in parents)
+    a_jk = ess / (q_pa * r[node])
+    a_k = ess / q_pa
     counts = {}
     for row in data:
         key = tuple(int(row[p]) for p in parents)
-        c = counts.setdefault(key, [0] * q)
+        c = counts.setdefault(key, [0] * r[node])
         c[int(row[node])] += 1
     ls = k * math.log(gamma)
-    # iterate over ALL r parent configurations (zero-count ones contribute 0)
-    import itertools
-    for key in itertools.product(range(q), repeat=k):
-        c = counts.get(tuple(key), [0] * q)
+    # iterate over ALL q_pa parent configurations (zero-count ones add 0)
+    for key in itertools.product(*(range(r[p]) for p in parents)):
+        c = counts.get(tuple(key), [0] * r[node])
         Nk = sum(c)
         ls += math.lgamma(a_k) - math.lgamma(a_k + Nk)
-        for j in range(q):
+        for j in range(r[node]):
             ls += math.lgamma(c[j] + a_jk) - math.lgamma(a_jk)
     return ls
 
 
-@pytest.mark.parametrize("q,s", [(2, 2), (3, 3), (2, 4)])
+@pytest.mark.parametrize("q,s", [(2, 2), (3, 3), (2, 4),
+                                 ((2, 3, 4, 3, 2, 4), 2),
+                                 ((4, 2, 2, 3, 4, 3), 3)])
 def test_table_matches_oracle(q, s):
     rng = np.random.default_rng(1)
     n, m = 6, 200
-    data = rng.integers(0, q, size=(m, n)).astype(np.int32)
+    data = rng.integers(0, np.asarray(q), size=(m, n)).astype(np.int32)
     st = build_score_table(data, q=q, s=s, gamma=0.1, ess=1.0, chunk=13)
     table = np.asarray(st.table)
     pst = np.asarray(st.pst)

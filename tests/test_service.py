@@ -53,6 +53,34 @@ def test_admission_key_separates_run_config(dataset):
                                             trace_dir="/elsewhere"))
 
 
+def test_admission_key_separates_arities(dataset):
+    """The arity vector is part of the job's identity: one arity given as an
+    int or as a full vector is the same job, another vector is not."""
+    n = dataset.shape[1]
+    a = admission_key(dataset, _cfg(q=2))
+    assert a == admission_key(dataset, _cfg(q=[2] * n))
+    assert a == admission_key(dataset, _cfg(q=",".join(["2"] * n)))
+    assert a != admission_key(dataset, _cfg(q=[2] * (n - 1) + [3]))
+
+
+def test_job_at_published_alarm_arities(tmp_path):
+    """A service job on ALARM sampled and scored at its published 2-4
+    states per variable runs to its posterior."""
+    from repro.data.networks import ALARM_ARITY
+    cfg = _cfg(q="alarm", s=2, iters=60, chains=2, check_every=20)
+    assert cfg.q == ALARM_ARITY
+    data = load_dataset(DatasetSpec(network="alarm", m=200, seed=4), cfg.q)
+    assert (data.max(0) < np.asarray(ALARM_ARITY)).all()
+    assert data.max() == 3                       # four-state variables
+    man = JobManager(run_dir=str(tmp_path))
+    sched = FleetScheduler(man, slots=4)
+    job, _ = sched.submit(data, cfg)
+    sched.run()
+    assert job.state == "done", job.error
+    arts = materialize(job)
+    assert np.asarray(arts["posterior"]["edge_probs"]).shape == (37, 37)
+
+
 def test_dedup_attaches_to_same_job(dataset, tmp_path):
     man = JobManager(run_dir=str(tmp_path))
     j1, d1 = man.submit(dataset, _cfg())

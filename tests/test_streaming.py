@@ -51,10 +51,13 @@ def test_streaming_matches_dense_prune_property(data_strategy):
     _assert_tables_bitwise(sp_dense, sp_stream)
 
 
-def test_streaming_matches_with_prior():
+@pytest.mark.parametrize("q", [2, (2, 3, 4, 3, 2, 4, 2, 3, 4)])
+def test_streaming_matches_with_prior(q):
+    """Bitwise streaming == dense + prune with a prior, at one arity and at
+    arities 2..4 (chunks then straddle several bin-count buckets)."""
     rng = np.random.default_rng(11)
-    n, q, s, m = 9, 2, 3, 120
-    data = _rand_problem(rng, n, q, m)
+    n, s, m = 9, 3, 120
+    data = rng.integers(0, np.asarray(q), size=(m, n)).astype(np.int32)
     R = np.full((n, n), 0.5, np.float32)
     R[1, 0] = 0.95
     R[4, 2] = 0.1

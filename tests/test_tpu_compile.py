@@ -1,5 +1,7 @@
 """Compile every main-path Pallas kernel for a described TPU v5e chip at the
-paper's real widths (n = 60, s = 4, w = 8, q = 3, m = 1000), without a chip.
+paper's real widths (n = 60, s = 4, w = 8, q = 3, m = 1000), without a chip,
+and the count+score kernel besides at ALARM's published arities (n = 37,
+2-4 states, sum r_i = 105) in each bin-count bucket its tables plan.
 
 The dense assembly's device rank map is compiled here too: written as an
 (n, S, s) broadcast it needs 28 GB of a 16 GB chip at this size.
@@ -18,9 +20,10 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from repro.core.combinatorics import n_parent_sets
+from repro.core.combinatorics import build_pst, n_parent_sets
 
 N, S_MAX, W, Q_ARITY, M = 60, 4, 8, 3, 1000
 CHAINS = 8                                         # the MCMC vmaps over these
@@ -114,13 +117,38 @@ def _count(one_chip):
                 Q=Q_ARITY ** S_MAX, block_m=BLOCK_M, interpret=False)
 
 
-def _fused_count_score(one_chip):
+def _count_score_at(one_chip, n: int, R: int, Q: int):
     from repro.preprocess.fused import fused_scores_pallas
     return _hlo(fused_scores_pallas, one_chip,
-                ((CHUNK, M_PAD), jnp.int32),
-                ((M_PAD, N * Q_ARITY), jnp.float32), ((CHUNK,), jnp.int32),
-                q=Q_ARITY, s=S_MAX, n=N, ess=1.0, block_m=BLOCK_M,
+                ((CHUNK, M_PAD), jnp.int32), ((M_PAD, R), jnp.float32),
+                ((CHUNK,), jnp.int32), ((1, R), jnp.float32),
+                ((R, n), jnp.float32), Q=Q, ess=1.0, block_m=BLOCK_M,
                 interpret=False)
+
+
+def _fused_count_score(one_chip):
+    return _count_score_at(one_chip, N, N * Q_ARITY, Q_ARITY ** S_MAX)
+
+
+def _arities(name: str) -> np.ndarray:
+    from repro.data.networks import ALARM_ARITY
+    return np.asarray(ALARM_ARITY if name == "alarm" else [Q_ARITY] * N)
+
+
+@pytest.mark.parametrize("name", ["paper60", "alarm"])
+def test_count_score_compiles_in_every_bucket_for_v5e(one_chip, name):
+    """The count+score kernel at the bin count of every bucket a table build
+    plans (each is a program of its own): at the paper's n = 60, q = 3 and
+    at ALARM's published arities (sum r_i = 105)."""
+    from repro.preprocess.planner import plan_subsets
+    r = _arities(name)
+    lay = plan_subsets(build_pst(len(r), S_MAX)[0], r, CHUNK, M, 1)
+    buckets = [Q for Q, _, _ in lay.buckets]
+    assert len(buckets) <= 6 and buckets[-1] == np.prod(
+        np.sort(r)[::-1][:S_MAX])
+    for Q in buckets:
+        assert "tpu_custom_call" in _count_score_at(
+            one_chip, len(r), int(r.sum()), Q), Q
 
 
 @pytest.mark.parametrize("build", [
