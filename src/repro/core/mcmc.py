@@ -38,11 +38,13 @@ bitmasks) carries its per-node packed violation-count planes in
 ceil(log2(s+1)) bit planes count, per (node, parent-set), the parents that
 do NOT precede the node — bit b of word j refers to PST rank 32j+b
 (LSB-first), and a set is consistent iff its count is zero across all
-planes. The planes are built once at :func:`init_chain` (``planes_fn``),
-patched for the ≤ window moved nodes per proposal, and adopted on accept —
-exactly mirroring the (cur_ls, cur_idx) cache discipline, so the invariant
-"mask_planes describes the CURRENT order" holds at every iteration. Paths
-that don't use the cache carry a zero-size placeholder.
+planes. The planes are built once at :func:`init_chain` (``planes_fn``); per
+proposal the delta hands back only the ≤ window patched rows, and on accept
+the sampler scatters those rows into the carried stack in place — the other
+n − w rows are unchanged by construction — so the invariant "mask_planes
+describes the CURRENT order" holds at every iteration without a second
+stack. Paths that don't use the cache carry a zero-size placeholder and
+never touch it.
 
 Adaptive move windows (freeze after burn-in)
 --------------------------------------------
@@ -107,10 +109,13 @@ DEFAULT_TARGET_ACCEPT = 0.234   # classic random-walk Metropolis optimum
 class BitmaskDelta(NamedTuple):
     """Marker wrapper for the EXTENDED delta contract — the bitmask-cached
     path needs the previous order and the cached planes, and hands back the
-    patched planes for the sampler to adopt on accept:
+    window's patched plane rows for the sampler to write back on accept:
 
         fn(new_pos, lo, prev_ls, prev_idx, old_pos, planes)
-            -> (score, best_idx, best_ls, new_planes)
+            -> (score, best_idx, best_ls, win, planes_win)
+
+    win: (w,) int32 node ids; planes_win: (w, P, W) their planes under
+    new_pos. Every other row of ``planes`` is unchanged by the move.
 
     Wrapping (instead of widening DeltaFn) keeps every existing plain delta
     closure — pruned, sharded, kernel — working unchanged."""
@@ -231,30 +236,30 @@ def _propose_and_score(state: ChainState, k_prop: jax.Array,
                        delta_fn: DeltaFn | BitmaskDelta | None, window: int):
     """One proposal + rescore under a STATIC window, dispatching between the
     full, plain-delta and bitmask-delta paths. Returns
-    (new_pos, new_score, new_idx, new_ls, new_planes)."""
+    (new_pos, new_score, new_idx, new_ls, rows) where rows is the bitmask
+    path's (win, planes_win) pair and None on the paths without the cache."""
     if window >= 2:
         new_pos, lo = _propose_move_impl(k_prop, state.pos, window=window)
     else:
         new_pos, lo = _propose_swap(k_prop, state.pos), jnp.int32(0)
     with jax.named_scope("order_score"):
         if isinstance(delta_fn, BitmaskDelta):
-            new_score, new_idx, new_ls, new_planes = delta_fn.fn(
+            new_score, new_idx, new_ls, win, planes_win = delta_fn.fn(
                 new_pos, lo, state.cur_ls, state.cur_idx, state.pos,
                 state.mask_planes)
-        elif delta_fn is not None:
+            return new_pos, new_score, new_idx, new_ls, (win, planes_win)
+        if delta_fn is not None:
             new_score, new_idx, new_ls = delta_fn(new_pos, lo, state.cur_ls,
                                                   state.cur_idx)
-            new_planes = state.mask_planes
         else:
             new_score, new_idx, new_ls = score_fn(new_pos)
-            new_planes = state.mask_planes
-    return new_pos, new_score, new_idx, new_ls, new_planes
+    return new_pos, new_score, new_idx, new_ls, None
 
 
 @jax.named_scope("accept")
 def _accept_update(state: ChainState, key, k_u, proposal) -> ChainState:
     """Shared MH accept/reject + cache/best bookkeeping."""
-    new_pos, new_score, new_idx, new_ls, new_planes = proposal
+    new_pos, new_score, new_idx, new_ls, rows = proposal
     log_u = jnp.log(jax.random.uniform(k_u, (), minval=1e-38))
     accept = log_u < (new_score - state.score)
 
@@ -262,7 +267,16 @@ def _accept_update(state: ChainState, key, k_u, proposal) -> ChainState:
     score = jnp.where(accept, new_score, state.score)
     cur_idx = jnp.where(accept, new_idx, state.cur_idx)
     cur_ls = jnp.where(accept, new_ls, state.cur_ls)
-    mask_planes = jnp.where(accept, new_planes, state.mask_planes)
+    mask_planes = state.mask_planes
+    if rows is not None:
+        # write the window's rows back only on accept: a rejected proposal
+        # aims every row at the out-of-range id n, which "drop" skips (as it
+        # does the adaptive switch's padding rows). Nothing reads the old
+        # stack afterwards, so XLA updates the scan carry in place.
+        win, planes_win = rows
+        n = mask_planes.shape[0]
+        mask_planes = mask_planes.at[jnp.where(accept, win, n)].set(
+            planes_win, mode="drop")
 
     better = accept & (new_score > state.best_score)
     return accept, ChainState(
@@ -305,12 +319,26 @@ def mcmc_step_adaptive(state: ChainState, score_fn: ScoreFn,
     iterate in index space moves win_idx toward target_accept, after that it
     is frozen (MCMC validity — adapt-then-freeze)."""
     assert len(windows) == len(delta_fns) and len(windows) >= 1
+    masked = [isinstance(f, BitmaskDelta) for f in delta_fns]
+    assert all(masked) or not any(masked), \
+        "the bitmask cache needs a BitmaskDelta for every window"
     key, k_prop, k_u = jax.random.split(state.key, 3)
+    n = state.pos.shape[0]
+    w_max = min(max(windows), n)
 
     def branch(j):
         def go(_):
-            return _propose_and_score(state, k_prop, score_fn, delta_fns[j],
-                                      windows[j])
+            *head, rows = _propose_and_score(state, k_prop, score_fn,
+                                             delta_fns[j], windows[j])
+            if rows is not None:
+                # the switch's branches must agree on shapes: pad to the
+                # widest window with id n (dropped by the scatter) and
+                # zero rows
+                win, planes_win = rows
+                pad = w_max - win.shape[0]
+                rows = (jnp.pad(win, (0, pad), constant_values=n),
+                        jnp.pad(planes_win, ((0, pad), (0, 0), (0, 0))))
+            return (*head, rows)
         return go
 
     idx = jnp.clip(state.win_idx, 0, len(windows) - 1)

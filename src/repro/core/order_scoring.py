@@ -69,9 +69,9 @@ word ops — and derives the boolean mask as ``~(V₀|V₁|…)``, replacing the
 O(w·S·s) gather+compare entirely. The masked max+argmax then runs over the
 same blocks with the same first-wins tie-break as `_score_nodes_blocked`, so
 the result is bitwise-identical to a full `score_order_blocked` rescore.
-On accept, the sampler splices the patched planes back into the chain cache
-(core/mcmc.py), preserving the invariant that ``mask_planes`` always
-describes the CURRENT order.
+It returns only the window's patched rows; on accept the sampler writes
+them back into the chain cache in place (core/mcmc.py), preserving the
+invariant that ``mask_planes`` always describes the CURRENT order.
 """
 from __future__ import annotations
 
@@ -508,8 +508,9 @@ def score_order_delta_bitmask(table: jnp.ndarray, cm: jnp.ndarray,
     window nodes' cached violation planes with word ops, score them against
     the packed mask, splice. No per-proposal (blk, s) position gathers — the
     PST is not even an argument. Returns the usual (total, best_idx (n,),
-    best_ls (n,)) contract triple PLUS the patched (n, P, S/32) planes, which
-    the sampler adopts on accept."""
+    best_ls (n,)) contract triple PLUS the window's node ids (w,) and their
+    patched (w, P, S/32) plane rows, which the sampler writes back on
+    accept."""
     n, S = table.shape
     assert S % block == 0, "pad S to a multiple of block"
     w = min(window, n)
@@ -518,7 +519,7 @@ def score_order_delta_bitmask(table: jnp.ndarray, cm: jnp.ndarray,
     words = planes_consistent_words(new_planes_win)           # (w, S/32)
     ls_w, idx_w = _score_nodes_blocked_bitmask(table[win], words, block=block)
     tot, best_idx, best_ls = splice_window(prev_ls, prev_idx, win, ls_w, idx_w)
-    return tot, best_idx, best_ls, planes.at[win].set(new_planes_win)
+    return tot, best_idx, best_ls, win, new_planes_win
 
 
 def _score_nodes_pruned(kept_ls: jnp.ndarray, kept_parents: jnp.ndarray,

@@ -157,8 +157,9 @@ def _local_bitmask_delta(table_l, cm_l, pos, lo, offset, pos_old, planes_l, *,
     """Device-local bitmask-cached window rescore: patch the local plane
     words, fold the masked max over the local shard, reduce the (w,) pair
     over ICI. planes_l: (n, P, shard/32) — this device's slice of the chain's
-    cached violation planes; the patched slice is returned for adoption on
-    accept and never leaves the device.
+    cached violation planes; the window's patched rows (w, P, shard/32) are
+    returned for the sampler to write back on accept and never leave the
+    device. Returns (win, ls_g, idx_g, planes_win).
 
     use_kernel=True routes patch+score through the ONE fused Pallas kernel
     (order_score_window_bitmask_fused_pallas); the default runs the same
@@ -185,7 +186,7 @@ def _local_bitmask_delta(table_l, cm_l, pos, lo, offset, pos_old, planes_l, *,
         ls_l, idx_l = _score_nodes_blocked_bitmask(
             rows, words, block=min(block, rows.shape[1]))
     ls_g, idx_g = _pmax_pmin(ls_l, idx_l + offset, axis)
-    return win, ls_g, idx_g, planes_l.at[win].set(new_win)
+    return win, ls_g, idx_g, new_win
 
 
 def make_sharded_planes_fn(pst, mesh, *, axis: str = "model",
@@ -271,12 +272,12 @@ def sharded_chain_step(states, table, pst, mesh, cm=None, *,
             cm_l = rest[0]
 
             def bitmask_fn(pos, lo, prev_ls, prev_idx, pos_old, planes_l):
-                win, ls_g, idx_g, new_planes = _local_bitmask_delta(
+                win, ls_g, idx_g, planes_win = _local_bitmask_delta(
                     table_l, cm_l, pos, lo, my * shard, pos_old, planes_l,
                     window=w, block=block, axis=axis, use_kernel=use_kernel)
                 tot, bi, bl = splice_window(prev_ls, prev_idx, win, ls_g,
                                             idx_g)
-                return tot, bi, bl, new_planes
+                return tot, bi, bl, win, planes_win
 
             delta_fn = BitmaskDelta(bitmask_fn)
         elif w:
@@ -349,9 +350,11 @@ def make_sharded_bitmask_fns(table, pst, mesh, *, window: int,
 
     * delta_fn: a :class:`BitmaskDelta` with the extended per-chain contract
       ``fn(new_pos, lo, prev_ls, prev_idx, old_pos, planes) -> (score,
-      best_idx, best_ls, new_planes)`` where planes is the chain's
-      (n, P, S/32) cache, S-sharded over `axis` — plane words stay on their
-      device; the collective payload is the (w,) pmax/pmin pair.
+      best_idx, best_ls, win, planes_win)`` where planes is the chain's
+      (n, P, S/32) cache and planes_win the window's (w, P, S/32) patched
+      rows, both S-sharded over `axis` (win is replicated) — plane words
+      stay on their device; the collective payload is the (w,) pmax/pmin
+      pair.
     * planes_fn: (n,) pos -> freshly-built sharded planes (init_chain's
       ``planes_fn`` contract / checkpoint-restore rebuild), built per shard
       inside shard_map.
@@ -370,17 +373,17 @@ def make_sharded_bitmask_fns(table, pst, mesh, *, window: int,
 
     in_specs = (P(None, axis), P(None, axis), P(None), P(), P(None), P(None),
                 P(None), P(None, None, axis))
-    out_specs = (P(), P(None), P(None), P(None, None, axis))
+    out_specs = (P(), P(None), P(None), P(None), P(None, None, axis))
 
     @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     def go(table_l, cm_l, pos, lo, prev_ls, prev_idx, pos_old, planes_l):
         my = jax.lax.axis_index(axis)
-        win, ls_g, idx_g, new_planes = _local_bitmask_delta(
+        win, ls_g, idx_g, planes_win = _local_bitmask_delta(
             table_l, cm_l, pos, lo, my * shard, pos_old, planes_l,
             window=w, block=block, axis=axis, use_kernel=use_kernel)
         tot, bi, bl = splice_window(prev_ls, prev_idx, win, ls_g, idx_g)
-        return tot, bi, bl, new_planes
+        return tot, bi, bl, win, planes_win
 
     def fn(pos, lo, prev_ls, prev_idx, pos_old, planes):
         return go(table, cm, pos, lo, prev_ls, prev_idx, pos_old, planes)

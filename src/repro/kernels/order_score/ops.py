@@ -98,7 +98,7 @@ def order_score_delta_bitmask(table: jnp.ndarray, cm: jnp.ndarray,
     and the PST leaves the per-iteration hot path entirely. table must
     already be padded to a block_s multiple (pad_for_kernel), with cm/planes
     built on the padded shape. Same extended contract as core's
-    score_order_delta_bitmask: (total, best_idx, best_ls, patched_planes)."""
+    score_order_delta_bitmask: (total, best_idx, best_ls, win, planes_win)."""
     from ...core.order_scoring import (_score_nodes_blocked_bitmask,
                                       planes_consistent_words, splice_window,
                                       update_window_planes, window_nodes)
@@ -124,4 +124,4 @@ def order_score_delta_bitmask(table: jnp.ndarray, cm: jnp.ndarray,
         val, idx = _score_nodes_blocked_bitmask(rows, words,
                                                 block=min(block_s, S))
     tot, best_idx, best_ls = splice_window(prev_ls, prev_idx, win, val, idx)
-    return tot, best_idx, best_ls, planes.at[win].set(new_planes_win)
+    return tot, best_idx, best_ls, win, new_planes_win

@@ -4,6 +4,7 @@ exchange_best invariants, and restore of the extended ChainState from a
 pre-tentpole checkpoint layout.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +15,9 @@ from _propcheck import given, hst, settings
 from repro.core.combinatorics import build_pst, n_parent_sets
 from repro.core.mcmc import (BitmaskDelta, ChainState, exchange_best,
                              exchange_step, init_chain, mcmc_run,
-                             mcmc_run_adaptive, mcmc_run_chains, propose_move)
+                             mcmc_run_adaptive, mcmc_run_chains,
+                             mcmc_run_chains_adaptive, mcmc_step,
+                             mcmc_step_adaptive, propose_move)
 from repro.core.order_scoring import (NEG_INF, build_membership_planes,
                                       build_violation_planes, consistent_mask,
                                       pack_mask_words,
@@ -35,6 +38,14 @@ def _problem(n=12, s=3, block=64, seed=42):
     pst = jnp.pad(jnp.asarray(pst), ((0, pad), (0, 0)), constant_values=-1)
     cm = build_membership_planes(pst, n)
     return table, pst, cm
+
+
+def _bitmask_delta(table, cm, block, window):
+    def bfn(pos, lo, prev_ls, prev_idx, pos_old, planes):
+        return score_order_delta_bitmask(table, cm, pos, prev_ls, prev_idx,
+                                         lo, pos_old, planes, window=window,
+                                         block=block)
+    return BitmaskDelta(bfn)
 
 
 def test_pack_unpack_roundtrip_and_init_planes_match_masks():
@@ -75,9 +86,10 @@ def test_bitmask_cache_equals_recomputed_masks(seed):
         key, k_mv = jax.random.split(key)
         w = int(rng.integers(2, 7))
         new_pos, lo = propose_move(k_mv, pos, window=w)
-        tot, gidx, gls, new_planes = score_order_delta_bitmask(
+        tot, gidx, gls, win, rows = score_order_delta_bitmask(
             table, cm, new_pos, ls, idx, lo, pos, planes, window=w,
             block=block)
+        new_planes = planes.at[win].set(rows)
         want = score_order_blocked(table, pst, new_pos, block=block)
         assert float(tot) == float(want[0])
         np.testing.assert_array_equal(np.asarray(gidx), np.asarray(want[1]))
@@ -98,14 +110,9 @@ def test_mcmc_bitmask_chain_is_bitwise_identical(padded_random_table):
     fn = functools.partial(score_order_blocked, table, pst, block=block)
     planes_fn = functools.partial(build_violation_planes, pst)
 
-    def bfn(pos, lo, prev_ls, prev_idx, pos_old, planes):
-        return score_order_delta_bitmask(table, cm, pos, prev_ls, prev_idx,
-                                         lo, pos_old, planes, window=4,
-                                         block=block)
-
     a, _ = mcmc_run(jax.random.key(3), n, fn, 300, window=4)
     b, _ = mcmc_run(jax.random.key(3), n, fn, 300,
-                    delta_fn=BitmaskDelta(bfn), window=4,
+                    delta_fn=_bitmask_delta(table, cm, block, 4), window=4,
                     planes_fn=planes_fn)
     assert float(a.score) == float(b.score)
     assert float(a.best_score) == float(b.best_score)
@@ -116,6 +123,85 @@ def test_mcmc_bitmask_chain_is_bitwise_identical(padded_random_table):
     np.testing.assert_array_equal(np.asarray(a.cur_ls), np.asarray(b.cur_ls))
     np.testing.assert_array_equal(np.asarray(b.mask_planes),
                                   np.asarray(planes_fn(b.pos)))
+
+
+def test_accept_writes_window_rows_in_place(small_problem):
+    """The accept step writes back only the window's rows: the lowered
+    vmapped step holds no select over the whole (C, n, P, W) plane stack,
+    the compiled scan copies no such stack per iteration, and the carried
+    planes still equal a from-scratch build after hundreds of steps with
+    both accepts and rejects."""
+    table, pst, cm, block, fn = small_problem
+    n, C, iters = 12, 2, 300
+    planes_fn = functools.partial(build_violation_planes, pst)
+    delta = _bitmask_delta(table, cm, block, 4)
+    states = jax.vmap(lambda k: init_chain(k, n, fn, planes_fn=planes_fn))(
+        jax.random.split(jax.random.key(11), C))
+    step = jax.vmap(lambda s: mcmc_step(s, fn, delta, 4))
+    stack = states.mask_planes.shape
+    assert stack[:2] == (C, n)
+
+    shlo = jax.jit(step).lower(states).as_text()
+    tensor = "tensor<" + "x".join(map(str, stack)) + "xui32>"
+    assert not [l for l in shlo.splitlines()
+                if "stablehlo.select" in l and tensor in l]
+
+    def run(st):
+        return jax.lax.scan(lambda c, _: (step(c), None), st, None,
+                            length=iters)[0]
+
+    hlo = jax.jit(run, donate_argnums=0).lower(states).compile().as_text()
+    result = re.compile(r"%(\S+) = u32\[" + ",".join(map(str, stack))
+                        + r"\]\{[^}]*\} (\w+)\(")
+    ops = [m.groups() for m in map(result.search, hlo.splitlines()) if m]
+    assert ops, "the plane stack should appear in the compiled scan"
+    for name, opcode in ops:
+        assert opcode not in ("copy", "select") and "select" not in name, \
+            (name, opcode)
+
+    out = jax.jit(run)(states)
+    accepts = np.asarray(out.accepts)
+    assert ((0 < accepts) & (accepts < iters)).all(), accepts
+    np.testing.assert_array_equal(np.asarray(out.mask_planes),
+                                  np.asarray(jax.vmap(planes_fn)(out.pos)))
+
+
+def test_adaptive_bitmask_switch_pads_window_rows(small_problem):
+    """mcmc_step_adaptive over bitmask deltas of different widths pads each
+    branch's rows to the widest window with the dropped id n: the carried
+    planes equal a from-scratch build at EVERY iteration, and the walk is
+    bitwise the full-rescore adaptive walk."""
+    table, pst, cm, block, fn = small_problem
+    n, C, iters, windows = 12, 2, 200, (2, 4, 6)
+    planes_fn = functools.partial(build_violation_planes, pst)
+    deltas = tuple(_bitmask_delta(table, cm, block, w) for w in windows)
+    states = jax.vmap(lambda k: init_chain(
+        k, n, fn, planes_fn=planes_fn, win_idx=len(windows) // 2))(
+        jax.random.split(jax.random.key(13), C))
+
+    def body(st, _):
+        st = jax.vmap(lambda s: mcmc_step_adaptive(
+            s, fn, deltas, windows, burn_in=iters // 2))(st)
+        ok = jnp.all(st.mask_planes == jax.vmap(planes_fn)(st.pos))
+        return st, (ok, st.win_idx)
+
+    out, (ok, win_idx) = jax.jit(
+        lambda st: jax.lax.scan(body, st, None, length=iters))(states)
+    assert np.asarray(ok).all(), "carried planes drifted from rebuild"
+    assert len(set(np.asarray(win_idx).ravel().tolist())) > 1, \
+        "the run should visit more than one window"
+
+    full = mcmc_run_chains_adaptive(jax.random.key(13), C, n, fn, iters,
+                                    windows=windows, burn_in=iters // 2)
+    got = mcmc_run_chains_adaptive(jax.random.key(13), C, n, fn, iters,
+                                   windows=windows, delta_fns=deltas,
+                                   planes_fn=planes_fn, burn_in=iters // 2)
+    for name in ("pos", "score", "cur_idx", "cur_ls", "best_score",
+                 "best_idx", "accepts", "win_idx", "adapt_err"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, name)),
+                                      np.asarray(getattr(full, name)), name)
+    np.testing.assert_array_equal(np.asarray(got.mask_planes),
+                                  np.asarray(jax.vmap(planes_fn)(got.pos)))
 
 
 def test_kernel_bitmask_variant_matches_core(padded_random_table):
@@ -143,10 +229,11 @@ def test_kernel_bitmask_variant_matches_core(padded_random_table):
             np.testing.assert_array_equal(np.asarray(got[2]),
                                           np.asarray(want[2]))
             # the fused kernel's patched plane words == from-scratch build
+            patched = planes.at[got[3]].set(got[4])
             np.testing.assert_array_equal(
-                np.asarray(got[3]),
+                np.asarray(patched),
                 np.asarray(build_violation_planes(pst, new_pos)))
-        pos, planes = new_pos, got[3]
+        pos, planes = new_pos, patched
         idx, ls = want[1], want[2]
 
 
@@ -195,7 +282,7 @@ def test_padded_pst_rows_are_structurally_inconsistent():
     cm = build_membership_planes(ppad, n)
     _, idx0, ls0 = score_order_blocked(tzero, ppad, pos, block=block)
     new_pos, lo = propose_move(jax.random.key(0), pos, window=4)
-    tot, gidx, _, _ = score_order_delta_bitmask(
+    tot, gidx, _, _, _ = score_order_delta_bitmask(
         tzero, cm, new_pos, ls0, idx0, lo, pos, planes, window=4,
         block=block)
     assert int(np.max(np.asarray(gidx))) < S
@@ -254,13 +341,9 @@ def test_mcmc_run_chains_in_scan_exchange_invariants(small_problem):
     n = 12
     planes_fn = functools.partial(build_violation_planes, pst)
 
-    def bfn(pos, lo, prev_ls, prev_idx, pos_old, planes):
-        return score_order_delta_bitmask(table, cm, pos, prev_ls, prev_idx,
-                                         lo, pos_old, planes, window=4,
-                                         block=block)
-
     states = mcmc_run_chains(jax.random.key(5), 4, n, fn, 120,
-                             delta_fn=BitmaskDelta(bfn), window=4,
+                             delta_fn=_bitmask_delta(table, cm, block, 4),
+                             window=4,
                              exchange_every=25, planes_fn=planes_fn)
     for c in range(4):
         sc, idx, ls = fn(states.pos[c])
@@ -333,7 +416,6 @@ def test_adaptive_chains_with_exchange_keep_per_slot_windows(small_problem):
     exchange leaves the run bitwise-identical to exchange_every=0."""
     _, _, _, _, fn = small_problem
     n = 12
-    from repro.core.mcmc import mcmc_run_chains_adaptive
     sts = mcmc_run_chains_adaptive(jax.random.key(3), 4, n, fn, 60,
                                    windows=(2, 4), delta_fns=(None, None),
                                    burn_in=20, exchange_every=15)
@@ -436,14 +518,8 @@ def test_restore_extended_chainstate_from_pre_tentpole_checkpoint(
     # derived cache: rebuild planes from the restored positions and resume
     st2 = st2._replace(mask_planes=jax.vmap(planes_fn)(st2.pos))
 
-    def bfn(pos, lo, prev_ls, prev_idx, pos_old, planes):
-        return score_order_delta_bitmask(table, cm, pos, prev_ls, prev_idx,
-                                         lo, pos_old, planes, window=4,
-                                         block=block)
-
-    from repro.core.mcmc import mcmc_step
-    step = jax.jit(jax.vmap(
-        lambda s: mcmc_step(s, fn, BitmaskDelta(bfn), 4)))
+    delta = _bitmask_delta(table, cm, block, 4)
+    step = jax.jit(jax.vmap(lambda s: mcmc_step(s, fn, delta, 4)))
     for _ in range(5):
         st2 = step(st2)
     for c in range(2):
@@ -463,7 +539,6 @@ def test_restore_across_engine_variants_reconciles_planes(tmp_path,
     restored positions / resets the placeholder, and the chain continues
     bitwise-correctly."""
     from repro.checkpoint import restore_checkpoint, save_checkpoint
-    from repro.core.mcmc import mcmc_step
     from repro.launch.bn_learn import reconcile_mask_planes
 
     table, pst, cm, block, fn = small_problem
@@ -489,13 +564,8 @@ def test_restore_across_engine_variants_reconciles_planes(tmp_path,
     np.testing.assert_array_equal(np.asarray(st.mask_planes),
                                   np.asarray(jax.vmap(planes_fn)(st.pos)))
 
-    def bfn(pos, lo, prev_ls, prev_idx, pos_old, planes):
-        return score_order_delta_bitmask(table, cm, pos, prev_ls, prev_idx,
-                                         lo, pos_old, planes, window=4,
-                                         block=block)
-
-    step = jax.jit(jax.vmap(
-        lambda s: mcmc_step(s, fn, BitmaskDelta(bfn), 4)))
+    delta = _bitmask_delta(table, cm, block, 4)
+    step = jax.jit(jax.vmap(lambda s: mcmc_step(s, fn, delta, 4)))
     for _ in range(5):
         st = step(st)
     for c in range(2):

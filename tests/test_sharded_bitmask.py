@@ -66,9 +66,12 @@ SCRIPT = textwrap.dedent("""
     def one_move(carry, key):
         pos, planes, ls, idx = carry
         new_pos, lo = propose_move(key, pos, window=w)
-        tot_s, idx_s, ls_s, pl_s = bfn.fn(new_pos, lo, ls, idx, pos, planes)
-        tot_1, idx_1, ls_1, pl_1 = score_order_delta_bitmask(
+        tot_s, idx_s, ls_s, win_s, rows_s = bfn.fn(new_pos, lo, ls, idx, pos,
+                                                   planes)
+        tot_1, idx_1, ls_1, win_1, rows_1 = score_order_delta_bitmask(
             tpad, cm, new_pos, ls, idx, lo, pos, planes, window=w, block=blk)
+        pl_s = planes.at[win_s].set(rows_s)
+        pl_1 = planes.at[win_1].set(rows_1)
         tot_f, idx_f, ls_f = fn(new_pos)
         out = (tot_s, tot_1, tot_f, idx_s, idx_1, idx_f, ls_s, ls_1, ls_f,
                jnp.all(pl_s == pl_1))
@@ -126,6 +129,9 @@ SCRIPT = textwrap.dedent("""
         want = jax.jit(fn)(new_pos)
         assert float(got[0]) == float(want[0])
         np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+        np.testing.assert_array_equal(
+            np.asarray(planes2.at[got[3]].set(got[4])),
+            np.asarray(planes_fn(new_pos)))
 
         # sharded_chain_step: cached-planes path == mask-recompute path ==
         # vmapped local steps, bitwise; planes always describe current order
