@@ -16,7 +16,7 @@ import numpy as np
 
 from repro.core import random_cpts, random_dag, roc_point
 from repro.data.bn_sampler import ancestral_sample, inject_noise
-from repro.launch.bn_learn import LearnConfig, learn_structure
+from repro.learner import LearnConfig, learn_structure
 
 try:
     from .common import emit

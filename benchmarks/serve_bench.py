@@ -32,7 +32,7 @@ except ImportError:                      # run as a plain script
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     from common import emit
 
-from repro.launch.bn_learn import learn_structure
+from repro.learner import learn_structure
 from repro.service import (DatasetSpec, FleetScheduler, JobManager,
                            load_dataset, service_config)
 

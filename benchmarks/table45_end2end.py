@@ -13,7 +13,7 @@ import numpy as np
 from repro.core import random_cpts, roc_point
 from repro.data.bn_sampler import ancestral_sample
 from repro.data.networks import alarm_adjacency, stn_adjacency
-from repro.launch.bn_learn import LearnConfig, learn_structure
+from repro.learner import LearnConfig, learn_structure
 
 from .common import emit
 

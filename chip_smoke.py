@@ -93,7 +93,7 @@ def _mosaic(fn, *args, **static) -> bool:
 
 
 def _learn_config(cell: Cell, work: str, run_name: str, **kw):
-    from repro.launch.bn_learn import LearnConfig
+    from repro.learner import LearnConfig
     return LearnConfig(q=cell.q, s=cell.s, iters=cell.iters,
                        chains=cell.chains, window=cell.window, seed=cell.seed,
                        preprocess="fused", telemetry=True,
@@ -145,7 +145,7 @@ def learn_dense(cell: Cell, data, truth, st, cache: str, work: str,
     """(b) XLA and fused-kernel bitmask engines on the dense table: equal
     best score and best parent sets, finite, with true edges found."""
     from repro.core import roc_point
-    from repro.launch.bn_learn import learn_structure
+    from repro.learner import learn_structure
 
     outs = {}
     for name, kernel in (("xla", False), ("kernel", True)):
@@ -204,8 +204,8 @@ def learn_pruned(cell: Cell, data, st, dense_score: float, work: str):
     builds it on the chip, checked bitwise against the pruned dense table
     (as the streaming tests do), then learned on end to end from the cache
     that build filled."""
-    from repro.launch.bn_learn import (AUTO_PRUNE_DELTA, AUTO_PRUNE_S,
-                                       learn_structure)
+    from repro.learner import (AUTO_PRUNE_DELTA, AUTO_PRUNE_S,
+                               learn_structure)
     from repro.preprocess import build_score_table_fused, prune_table
 
     _require(st.S >= AUTO_PRUNE_S, (st.S, AUTO_PRUNE_S))
@@ -270,7 +270,7 @@ def one_chip(cell: Cell, truth, data, work: str,
 def four_chips(cell: Cell, data, work: str, tp: int = 4):
     """bn_learn --sharded (table and planes S-sharded over `tp` chips),
     without and with the kernel, against a single-device run: bitwise."""
-    from repro.launch.bn_learn import learn_structure
+    from repro.learner import learn_structure
     from repro.preprocess import build_score_table_fused
 
     cache = os.path.join(work, "dense")
@@ -310,7 +310,7 @@ def main(argv=None) -> int:
 
     import jax
 
-    from repro.launch.bn_learn import _network_data
+    from repro.data.networks import network_data
 
     devices = jax.devices()
     need = 4 if args.four_chips else 1
@@ -321,8 +321,8 @@ def main(argv=None) -> int:
         return 1
     shutil.rmtree(WORK, ignore_errors=True)
     cell = PAPER
-    truth, data = _network_data("synth", cell.m, cell.q, cell.seed,
-                                n_synth=cell.n)
+    truth, data = network_data("synth", cell.m, cell.q, cell.seed,
+                               n_synth=cell.n)
     if args.four_chips:
         four_chips(cell, data, WORK)
     else:

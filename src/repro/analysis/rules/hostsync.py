@@ -15,8 +15,8 @@ roots
   * closures handed to ``core.mcmc.make_traced_segment_runner`` (``step``,
     ``tap``, ``exchange``) at any call site;
   * closures RETURNED by ``make_*`` factory functions — the repo's
-    convention for building traced callables (make_tap, make_score_fn,
-    make_delta_fn, ...).
+    convention for building traced callables (make_tap,
+    make_sharded_score_fn, make_sharded_planes_fn, ...).
 
 edges
   direct calls by (possibly imported) name, so helpers called from scan

@@ -27,7 +27,8 @@ Two proposal regimes:
   test stays the pure score ratio.
 
 Everything is a `lax.scan` over iterations; chains are vmapped (and sharded
-over the `data`/`pod` mesh axes by launch/bn_learn.py).
+over the `data`/`pod` mesh axes by the learner's sharded engine,
+repro/learner.py).
 
 Cached consistency bitmasks (ChainState.mask_planes)
 ----------------------------------------------------
@@ -64,9 +65,10 @@ come from one fixed Metropolis kernel — the standard warm-up contract.
 Convergence telemetry (segmented runs)
 --------------------------------------
 
-:func:`make_traced_segment_runner` is the segmented counterpart of the
-one-shot run loops above: the same scan, cut into host-visible segments,
-optionally carrying a telemetry ``TraceState`` (repro.telemetry.taps)
+:func:`make_traced_segment_runner` is the multi-chain run loop (the
+single-chain :func:`mcmc_run` loops stay as the walk's reference): the
+scan over vmapped chains, cut into host-visible segments, optionally
+carrying a telemetry ``TraceState`` (repro.telemetry.taps)
 beside the chain stack and calling an in-scan tap each iteration. The host
 drains the trace between segments to compute split-R̂ / edge-marginal R̂
 and may stop the run early (bn_learn ``--stop-on-converge``) — runs then
@@ -100,8 +102,8 @@ DeltaFn = Callable[[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray],
 # dense core.scores.ScoreTable (score_order_blocked / the Pallas kernel /
 # the sharded scorer) or a preprocess.SparseScoreTable (score_order_pruned,
 # O(n*K)); best_idx is a global PST rank in every case, so best-graph
-# tracking and adjacency recovery are identical. launch/bn_learn.make_score_fn
-# and make_delta_fn do the dispatch.
+# tracking and adjacency recovery are identical. repro/learner.build_engine
+# does the dispatch.
 
 DEFAULT_TARGET_ACCEPT = 0.234   # classic random-walk Metropolis optimum
 
@@ -174,7 +176,7 @@ def _propose_move_impl(key: jax.Array, pos: jax.Array, *, window: int):
     """Bounded-window move mixture. Returns (new_pos, lo) where every changed
     position lies in [lo, lo+window-1]. Requires window ≥ 2 (and n ≥ 2);
     window > n is clamped to n (callers that should refuse instead — the CLI
-    — validate before tracing, launch/bn_learn.main).
+    — validate before tracing, the bn_learn CLI's main).
 
     Already-traced callers (the scan bodies) use this raw impl so the move
     inlines into the engine computation; the public `propose_move` below is
@@ -453,26 +455,13 @@ def exchange_step(states: ChainState) -> ChainState:
     return jax.lax.cond(b == w, lambda st: st, copy, states)
 
 
-def _run_chain_rounds(states, step, iters: int, exchange_every: int,
-                      n_chains: int):
-    """Shared chain-scan skeleton: vmapped `step` for `iters` iterations,
-    with the in-scan exchange spliced in every `exchange_every` (plus a
-    trailing partial round)."""
-    def sweep(states, length):
-        def body(st, _):
-            return jax.vmap(step)(st), None
-        states, _ = jax.lax.scan(body, states, None, length=length)
-        return states
-
-    if exchange_every <= 0 or n_chains < 2:
-        return sweep(states, iters)
-    rounds, rem = divmod(iters, exchange_every)
-
-    def round_body(st, _):
-        return exchange_step(sweep(st, exchange_every)), None
-
-    states, _ = jax.lax.scan(round_body, states, None, length=rounds)
-    return sweep(states, rem)
+def _one_segment(states, step, iters: int, exchange_every: int):
+    """The whole walk as ONE ``iters``-long segment of
+    :func:`make_traced_segment_runner`: vmapped `step`, with the in-scan
+    exchange every `exchange_every` iterations (none for a single chain)."""
+    run = make_traced_segment_runner(
+        step, exchange_every=exchange_every if states.pos.shape[0] > 1 else 0)
+    return run(states, None, jnp.int32(0), length=iters)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("n_chains", "n", "score_fn",
@@ -484,16 +473,16 @@ def mcmc_run_chains(key: jax.Array, n_chains: int, n: int, score_fn: ScoreFn,
     """vmapped independent chains (DP axis). Returns stacked final states.
 
     exchange_every > 0 runs the periodic in-scan :func:`exchange_step` every
-    that many iterations (plus a trailing partial round), instead of only
-    reducing at the end: slow chains inherit the current best basin while
-    the walk is still running — the paper's end-only best-graph exchange
-    promoted to a restart heuristic. 0 keeps fully independent chains."""
+    that many iterations, instead of only reducing at the end: slow chains
+    inherit the current best basin while the walk is still running — the
+    paper's end-only best-graph exchange promoted to a restart heuristic.
+    0 keeps fully independent chains."""
     keys = jax.random.split(key, n_chains)
     states = jax.vmap(
         lambda k: init_chain(k, n, score_fn, planes_fn=planes_fn))(keys)
-    return _run_chain_rounds(
+    return _one_segment(
         states, lambda s: mcmc_step(s, score_fn, delta_fn, window), iters,
-        exchange_every, n_chains)
+        exchange_every)
 
 
 @functools.partial(jax.jit, static_argnames=("n_chains", "n", "score_fn",
@@ -521,14 +510,14 @@ def mcmc_run_chains_adaptive(key: jax.Array, n_chains: int, n: int,
     step = lambda s: mcmc_step_adaptive(s, score_fn, delta_fns, windows,
                                         target_accept=target_accept,
                                         burn_in=burn_in)
-    return _run_chain_rounds(states, step, iters, exchange_every, n_chains)
+    return _one_segment(states, step, iters, exchange_every)
 
 
 def make_traced_segment_runner(step, *, tap=None, exchange=None,
                                exchange_every: int = 0,
                                stacked_step: bool = False):
-    """The SEGMENTED run loop shared by every telemetry-aware path (the
-    single-device, checkpointed and sharded drivers in launch/bn_learn, and
+    """The SEGMENTED run loop every walk shares (the single-device and
+    sharded engines of repro/learner.py, :func:`mcmc_run_chains` and
     benchmarks/telemetry_bench): a jitted
 
         run_segment(states, trace, start, *, length) -> (states, trace)

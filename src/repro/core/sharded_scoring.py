@@ -299,7 +299,8 @@ def sharded_chain_step(states, table, pst, mesh, cm=None, *,
 def make_sharded_score_fn(table, pst, mesh, *, axis: str = "model",
                           block: int = 4096):
     """Closure with the (n,)-contract used by core.mcmc — the drop-in
-    multi-device replacement for make_score_fn."""
+    multi-device replacement for the learner's score closure
+    (repro/learner.build_engine)."""
     tp = mesh.shape[axis]
     block = _shard_block(table.shape[1], tp, block)
     table, pst = pad_table(table, pst, tp * block)

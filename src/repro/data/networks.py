@@ -12,7 +12,8 @@ from __future__ import annotations
 
 import numpy as np
 
-from ..core.graph import random_dag
+from ..core.graph import random_cpts, random_dag
+from .bn_sampler import ancestral_sample
 
 STN_NODES = ["Raf", "Mek", "Plcg", "PIP2", "PIP3", "Erk", "Akt", "PKA",
              "PKC", "P38", "Jnk"]
@@ -108,3 +109,17 @@ def synthetic_adjacency(rng: np.random.Generator, n: int = 64, *,
     """ALARM-like synthetic ground truth at scale n (~1.2 parents/node at the
     defaults — the n = 64 scale-benchmark network of bn_learn/preprocess)."""
     return random_dag(rng, n, max_parents, edge_prob)
+
+
+def network_data(name: str, m: int, q, seed: int, n_synth: int = 64):
+    """(ground-truth adjacency, (m, n) samples) for a named network —
+    ``alarm``, ``stn`` or ``synth`` (a synthetic DAG of n_synth nodes) —
+    with seeded random CPTs at q states per variable (one int, or one per
+    variable)."""
+    rng = np.random.default_rng(seed)
+    if name == "synth":
+        adj = synthetic_adjacency(rng, n_synth)
+    else:
+        adj = {"alarm": alarm_adjacency, "stn": stn_adjacency}[name]()
+    cpts = random_cpts(rng, adj, q)
+    return adj, ancestral_sample(rng, adj, cpts, m, q)

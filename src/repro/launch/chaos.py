@@ -38,7 +38,7 @@ import sys
 
 
 def _build_cfg(workdir: str, name: str, **overrides):
-    from .bn_learn import LearnConfig
+    from ..learner import LearnConfig
     base = dict(q=2, s=2, iters=96, chains=4, seed=3, window=4,
                 exchange_every=16, trace_every=4, check_every=32,
                 telemetry=True, supervise=True, checkpoint_every=32,
@@ -75,7 +75,7 @@ def _validate_traces(workdir: str) -> int:
 def _crash_resume_leg(workdir: str, *, sharded: bool = False) -> None:
     """Crash + corrupt mid-run, auto-resume, compare bitwise to clean."""
     from ..runtime.faults import InjectedCrash
-    from .bn_learn import learn_structure
+    from ..learner import learn_structure
     tag = "sharded" if sharded else "single"
     data = _data()
     ref = learn_structure(data, _build_cfg(
@@ -106,7 +106,7 @@ def _crash_resume_leg(workdir: str, *, sharded: bool = False) -> None:
 def _heal_leg(workdir: str) -> None:
     """Poisoned + stalled chains healed within one supervision interval."""
     import numpy as np
-    from .bn_learn import learn_structure
+    from ..learner import learn_structure
     data = _data()
     # exchange_every=0: with the in-scan exchange on, the poisoned chain is
     # re-seeded INSIDE the scan (the NaN-safe exchange always makes it the

@@ -131,7 +131,7 @@ def shutdown(base: str, thread: threading.Thread) -> None:
 
 
 def main(argv=None) -> int:
-    from ..launch.bn_learn import learn_structure
+    from ..learner import learn_structure
     from ..launch.bn_query import load_result
     from ..service import load_dataset, service_config
     from ..service.jobs import DatasetSpec

@@ -57,7 +57,7 @@ kernel's useful and computed bins, None on a cache hit;
 ``stages`` breaks ``preprocess_s`` into per-stage wall-clock seconds
 (plan_s/score_s/assemble_s on the dense path, plan_s/stream_s/finalize_s
 streaming, cache_load_s/cache_store_s around the disk cache) — the
-telemetry collector (launch/bn_learn --telemetry) emits them as stage rows.
+telemetry collector (repro/learner.prepare_run) emits them as stage rows.
 Each is the ``.seconds`` of a telemetry span (``preprocess.build`` gives
 ``preprocess_s``; ``preprocess.plan``/``score``/``assemble``, the last split
 into ``preprocess.rank_map`` and ``preprocess.gather``), so a profiler trace
@@ -252,7 +252,7 @@ def build_score_table_fused(data: np.ndarray, *, q, s: int,
 
         S = n_parent_sets(n - 1, s)
         # "stages" is the per-stage wall-clock breakdown of preprocess_s —
-        # the telemetry collector's stage rows (launch/bn_learn) read it
+        # the telemetry collector's stage rows (repro/learner) read it
         info: dict = {"cache_hit": False, "n": n, "S": S, "plan": None,
                       "preprocess_s": None, "streaming": streaming,
                       "peak_assembly_bytes": None, "bins_real": None,

@@ -15,8 +15,8 @@ each chunk only to its bucket's Q.
 
 The planner is pure (no device state): it maps a cost vector to per-device
 chunk lists, so it is unit-testable at any simulated device count and is
-reused by launch/bn_learn through pipeline.build_score_table_fused with the
-devices of a launch/mesh mesh.
+reused by the learner (repro/learner.py) through
+pipeline.build_score_table_fused with the devices of a launch/mesh mesh.
 """
 from __future__ import annotations
 

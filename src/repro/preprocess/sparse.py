@@ -49,7 +49,7 @@ class SparseScoreTable:
 
     Duck-types the parts of core.scores.ScoreTable the driver uses (`n`, `S`,
     `q`, `s`, and a `table` property materialising the exact dense fallback),
-    so core/order_scoring, core/mcmc and launch/bn_learn accept either
+    so core/order_scoring, core/mcmc and repro/learner accept either
     representation. Deliberately does NOT keep the (S, s) PST or (S,) psizes:
     every stored array is O(n·K) — adjacency recovery decodes the winning
     ranks arithmetically instead (core.graph.adjacency_from_ranks), which is

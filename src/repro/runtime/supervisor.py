@@ -1,9 +1,9 @@
 """Fault-tolerant run supervisor: the segmented BN run loop, hardened.
 
-Every telemetry-aware driver in launch/bn_learn already cuts the walk into
-jitted segments (core/mcmc.make_traced_segment_runner) with the host in
-between. :class:`RunSupervisor` owns that host loop for the single-device,
-adaptive AND sharded engines, and layers four things onto it:
+Every walk of the learner (repro/learner.py) is cut into jitted segments
+(core/mcmc.make_traced_segment_runner) with the host in between.
+:class:`RunSupervisor` owns that host loop for the single-device, adaptive
+AND sharded engines, and layers four things onto it:
 
 * **verified auto-resume** — restore goes through
   checkpoint.restore_latest_verified: per-leaf digests are re-hashed, a
@@ -53,31 +53,55 @@ from .faults import FaultPlan
 from .straggler import StragglerPolicy, best_finite_chain, rebalance_chains
 
 __all__ = ["RunSupervisor", "SupervisedResult", "pack_tree", "unpack_tree",
-           "N_STATE_LEAVES"]
+           "reconcile_planes", "N_STATE_LEAVES"]
 
 logger = logging.getLogger(__name__)
 
 N_STATE_LEAVES = len(ChainState._fields)
 
 
-def pack_tree(pack, states, trace):
-    """Checkpoint layout with telemetry: the ChainState leaves first (EXACTLY
-    the pre-telemetry tuple when trace is None), TraceState leaves appended
+def reconcile_planes(states: ChainState, planes_fn) -> ChainState:
+    """Derived-cache interop across engine variants: the ``mask_planes``
+    leaf is a cache of the positions, and snapshots written by different
+    engines disagree about its shape — sharded runs snapshot the zero-size
+    placeholder, single-device bitmask runs may carry full (n, P, S/32)
+    planes built under another padding, and pre-bitmask layouts have no
+    leaf at all (backfilled by the checkpointer's ``allow_missing``, which
+    covers MISSING leaves only, never wrong-shaped ones). So the leaf is
+    ALWAYS rebuilt from the positions when the engine uses the cache
+    (``planes_fn``: stacked (C, n) pos -> (C, n, P, W) planes) and reset to
+    the placeholder when it does not — restored OR healed positions always
+    get planes built under this engine's own padding."""
+    if planes_fn is not None:
+        return states._replace(mask_planes=planes_fn(states.pos))
+    return states._replace(
+        mask_planes=jnp.zeros((states.pos.shape[0], 0), jnp.uint32))
+
+
+def pack_tree(states: ChainState, trace):
+    """Checkpoint layout: the ChainState leaves first (EXACTLY the
+    pre-telemetry tuple when trace is None), TraceState leaves appended
     after them — so pre-telemetry snapshots restore through the
     checkpointer's ``allow_missing`` backfill (the trace leaves come back
     from the fresh template), the same schema-evolution path the pre-bitmask
-    9-leaf snapshots use."""
-    tree = tuple(pack(states))
+    9-leaf snapshots use. Typed PRNG keys are not numpy-serializable, so the
+    key leaf is snapshot as key data; the consistency planes are a
+    pos-derived cache, snapshot as a zero-size stand-in and rebuilt after
+    restore (:func:`reconcile_planes`)."""
+    states = reconcile_planes(states, None)
+    states = states._replace(key=jax.random.key_data(states.key))
+    tree = tuple(jax.tree.map(np.asarray, states))
     if trace is not None:
         tree = tree + tuple(np.asarray(leaf) for leaf in trace)
     return tree
 
 
-def unpack_tree(unpack, restored, trace):
+def unpack_tree(restored, trace):
     """Inverse of :func:`pack_tree`: split the restored tuple back into
     (ChainState, TraceState | None)."""
     restored = tuple(jnp.asarray(leaf) for leaf in restored)
-    states = unpack(restored[:N_STATE_LEAVES])
+    states = ChainState(*restored[:N_STATE_LEAVES])
+    states = states._replace(key=jax.random.wrap_key_data(states.key))
     if trace is not None:
         from ..telemetry import TraceState
         trace = TraceState(*restored[N_STATE_LEAVES:])
@@ -144,7 +168,7 @@ class RunSupervisor:
         used both after restore (derived-cache reconcile across engine
         variants) and after healing (cloned positions need cloned planes
         REBUILT under this engine's padding).
-    pack/unpack: the driver's checkpoint (de)serialisation closures.
+    Checkpoints use the :func:`pack_tree` layout.
     """
 
     def __init__(self, *, iters: int, seg: int, chains: int,
@@ -152,8 +176,7 @@ class RunSupervisor:
                  collector=None, stop_on_converge: bool = False,
                  faults: FaultPlan | None = None, heal: bool = False,
                  heal_patience: int = 1, seed: int = 0,
-                 planes_fn=None, cache_dir: str = "",
-                 pack=None, unpack=None):
+                 planes_fn=None, cache_dir: str = ""):
         self.iters = int(iters)
         self.seg = max(int(seg), 1)
         self.chains = int(chains)
@@ -166,8 +189,6 @@ class RunSupervisor:
         self.policy = StragglerPolicy(patience=max(int(heal_patience), 1))
         self.planes_fn = planes_fn
         self.cache_dir = cache_dir
-        self.pack = pack
-        self.unpack = unpack
         # healing keys: decorrelated from the chain keys, derived from the
         # GLOBAL iteration so resumed runs draw identical clone keys
         self._heal_key = jax.random.fold_in(jax.random.key(int(seed)), 0x5E9)
@@ -216,7 +237,7 @@ class RunSupervisor:
         """(states, trace, done, resumed_from): verified auto-resume."""
         if not self.checkpointed or latest_step(self.checkpoint_dir) is None:
             return states, trace, 0, None
-        template = pack_tree(self.pack, states, trace)
+        template = pack_tree(states, trace)
         try:
             restored, metadata, step = restore_latest_verified(
                 self.checkpoint_dir, template, allow_missing=True)
@@ -224,21 +245,10 @@ class RunSupervisor:
             logger.warning("no checkpoint step verified in %s — starting "
                            "from scratch", self.checkpoint_dir)
             return states, trace, 0, None
-        states, trace = unpack_tree(self.unpack, restored, trace)
-        states = self._reconcile_planes(states)
+        states, trace = unpack_tree(restored, trace)
+        states = reconcile_planes(states, self.planes_fn)
         self._load_meta(metadata, states)
         return states, trace, step, step
-
-    def _reconcile_planes(self, states: ChainState) -> ChainState:
-        """Derived-cache interop (mirrors launch/bn_learn
-        reconcile_mask_planes): the planes leaf is rebuilt from positions
-        when this engine uses the bitmask cache and reset to the zero-size
-        placeholder when it does not — restored OR healed positions always
-        get planes built under this engine's own padding."""
-        if self.planes_fn is not None:
-            return states._replace(mask_planes=self.planes_fn(states.pos))
-        return states._replace(
-            mask_planes=jnp.zeros((states.pos.shape[0], 0), jnp.uint32))
 
     # -------------------------------------------------------------- faults
     def _fire_pre_segment(self, states: ChainState) -> ChainState:
@@ -300,7 +310,7 @@ class RunSupervisor:
             return_mask=True)
         if healed.any():
             donor = best_finite_chain(best_before)
-            states = self._reconcile_planes(states)
+            states = reconcile_planes(states, self.planes_fn)
             if trace is not None:
                 trace = _reseed_trace(trace, healed, donor)
             for c in np.nonzero(healed)[0]:
@@ -394,7 +404,7 @@ class RunSupervisor:
             self.faults.crash(f"before checkpoint write at iter {done}")
         if self.checkpointed:
             save_checkpoint(self.checkpoint_dir, done,
-                            pack_tree(self.pack, states, trace),
+                            pack_tree(states, trace),
                             metadata=self._state_meta())
         for event in corrupts:
             self.faults.corrupt_checkpoint(self.checkpoint_dir, event)

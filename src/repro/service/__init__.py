@@ -4,9 +4,9 @@ posteriors.
 The package splits the server into the classic three layers:
 
 * :mod:`repro.service.jobs`      — admission, content-addressed dedup, and
-  the per-job engine (the same ``prepare_run``/``make_engine_closures``/
-  ``_build_segmented`` path standalone ``bn_learn`` uses, so service
-  answers are bitwise-comparable to one-shot runs).
+  the per-job engine (the same ``prepare_run``/``build_engine``/
+  ``start_run`` path of repro/learner.py that standalone ``bn_learn``
+  uses, so service answers are bitwise-comparable to one-shot runs).
 * :mod:`repro.service.scheduler` — packs jobs onto a chain-slot budget,
   advancing each active job one supervised segment per tick with optional
   elastic fleet cloning into idle slots.

@@ -2,10 +2,10 @@
 
 A JOB is one dataset-learning request — (data, LearnConfig) — run as a
 vmapped fleet of chains through the SAME code path a standalone
-``bn_learn`` run takes: ``prepare_run`` (preprocess + disk cache +
-collector), ``make_engine_closures`` (scorer/delta/plane closures) and
-``_build_segmented`` (vmapped init, jitted traced segment runner, armed
-RunSupervisor). Because the engine construction is shared, a job advanced
+``learn_structure`` run takes (repro/learner.py): ``prepare_run``
+(preprocess + disk cache + collector), ``build_engine`` (scorer/delta/plane
+closures) and ``start_run`` (vmapped init, jitted traced segment runner,
+armed RunSupervisor). Because the engine construction is shared, a job advanced
 segment-by-segment by the multi-job scheduler produces BITWISE-identical
 posterior artifacts to a one-shot run of the same (data, config, seed):
 the interleaving only changes *when* each segment executes on the host,
@@ -36,9 +36,8 @@ from dataclasses import dataclass, replace
 
 import numpy as np
 
-from ..core.mcmc import exchange_best
-from ..launch.bn_learn import (LearnConfig, _build_segmented, _finish,
-                               make_engine_closures, prepare_run)
+from ..learner import (LearnConfig, build_engine, finish_run, prepare_run,
+                       start_run)
 from ..preprocess.cache import cache_key
 from .query import job_response, materialize
 
@@ -82,9 +81,9 @@ def load_dataset(spec: DatasetSpec, q) -> np.ndarray:
                              f"(samples, nodes) matrix, got {data.shape}")
         return np.asarray(data, np.int8)
     from ..data.bn_sampler import inject_noise
-    from ..launch.bn_learn import _network_data
-    _, data = _network_data(spec.network, spec.m, q, spec.seed,
-                            n_synth=spec.n)
+    from ..data.networks import network_data
+    _, data = network_data(spec.network, spec.m, q, spec.seed,
+                           n_synth=spec.n)
     if spec.noise:
         data = inject_noise(np.random.default_rng(spec.seed + 1), data,
                             spec.noise, q)
@@ -144,8 +143,7 @@ class Job:
         self.sup = None               # armed RunSupervisor once running
         self.extra_chains = 0         # elastic expansion beyond cfg.chains
         self.submitted_at = time.time()
-        self._st = self._collector = self._pre = None
-        self._closures = None
+        self._st = self._collector = self._pre = self._engine = None
         self._t0 = 0.0
 
     @property
@@ -165,14 +163,10 @@ class Job:
         cfg = self.cfg
         self._st, self._collector, self._pre = prepare_run(
             self.data, cfg, prior_matrix=self.prior_matrix)
-        self._closures = make_engine_closures(self._st, cfg, self.n)
-        (score_fn, window, delta_fn, planes_fn, adaptive_ws, delta_fns,
-         burn_in, _mask_on) = self._closures
-        key = jax.random.key(cfg.seed)
+        self._engine = build_engine(self._st, cfg)
         self._t0 = time.time()
-        self.sup = _build_segmented(self._st, cfg, key, self.n, score_fn,
-                                    window, delta_fn, planes_fn, adaptive_ws,
-                                    delta_fns, burn_in, self._collector)
+        self.sup = start_run(self._st, cfg, self._engine, self._collector,
+                             jax.random.key(cfg.seed))
         self.state = "running"
 
     def advance(self) -> bool:
@@ -189,21 +183,12 @@ class Job:
         """Materialise the result dict (identical to what a standalone
         ``learn_structure`` call returns, artifacts included), persist the
         query artifacts for ``bn_query``, and retire the job."""
-        res = self.sup.result()
-        best_score, best_idx, best_pos = exchange_best(res.states)
-        (_score_fn, window, delta_fn, _planes_fn, adaptive_ws, _delta_fns,
-         _burn_in, mask_on) = self._closures
-        self.result = _finish(
-            self.cfg, self._st, res.states, best_score, best_idx,
-            window=window, adaptive_ws=adaptive_ws, mask_on=mask_on,
-            sharded=False, t_pre=self._pre["t_pre"],
-            cache_hit=self._pre["cache_hit"],
-            auto_pruned=self._pre["auto_pruned"],
-            t_iter=time.time() - self._t0, iters_run=res.iters_run,
-            stopped=res.stopped, collector=self._collector, heals=res.heals,
-            trace=res.trace, best_pos=best_pos)
+        self.result = finish_run(
+            self.cfg, self._st, self._engine, self.sup.result(),
+            pre=self._pre, t_iter=time.time() - self._t0,
+            collector=self._collector)
         self.state = "done"
-        self._st = self._closures = None      # free the table
+        self._st = self._engine = None        # free the table
         if self.run_dir:
             self._persist()
         return self.result

@@ -7,7 +7,7 @@ from repro.core import random_cpts, random_dag, roc_point
 from repro.core.order_scoring import score_order_ref, score_order_sum
 from repro.data.bn_sampler import ancestral_sample
 from repro.data.discretize import discretize
-from repro.launch.bn_learn import LearnConfig, learn_structure
+from repro.learner import LearnConfig, learn_structure
 
 
 def test_sum_score_upper_bounds_max_score():

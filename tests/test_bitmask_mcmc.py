@@ -535,11 +535,11 @@ def test_restore_across_engine_variants_reconciles_planes(tmp_path,
     mask_planes placeholder) restored into the bitmask engine, and a
     full-planes snapshot restored into a placeholder engine, previously left
     a wrong-shaped planes leaf (allow_missing only backfills MISSING
-    leaves). reconcile_mask_planes rebuilds the derived cache from the
+    leaves). reconcile_planes rebuilds the derived cache from the
     restored positions / resets the placeholder, and the chain continues
     bitwise-correctly."""
     from repro.checkpoint import restore_checkpoint, save_checkpoint
-    from repro.launch.bn_learn import reconcile_mask_planes
+    from repro.runtime.supervisor import reconcile_planes
 
     table, pst, cm, block, fn = small_problem
     n = 12
@@ -559,7 +559,7 @@ def test_restore_across_engine_variants_reconciles_planes(tmp_path,
                                      step=1, allow_missing=True)
     st = unpack(restored)
     assert st.mask_planes.shape == (2, 0)          # the wrong-shaped leaf
-    st = reconcile_mask_planes(st, lambda p: jax.vmap(planes_fn)(p))
+    st = reconcile_planes(st, lambda p: jax.vmap(planes_fn)(p))
     assert st.mask_planes.shape == with_planes.mask_planes.shape
     np.testing.assert_array_equal(np.asarray(st.mask_planes),
                                   np.asarray(jax.vmap(planes_fn)(st.pos)))
@@ -582,7 +582,7 @@ def test_restore_across_engine_variants_reconciles_planes(tmp_path,
                                      step=1, allow_missing=True)
     st = unpack(restored)
     assert st.mask_planes.ndim == 4                # the wrong-shaped leaf
-    st = reconcile_mask_planes(st, None)
+    st = reconcile_planes(st, None)
     assert st.mask_planes.shape == (2, 0)
     step = jax.jit(jax.vmap(lambda s: mcmc_step(s, fn, None, 4)))
     for _ in range(3):

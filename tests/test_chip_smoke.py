@@ -7,7 +7,8 @@ import sys
 
 import pytest
 
-from repro.launch import bn_learn
+from repro import learner
+from repro.data.networks import network_data
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
@@ -31,10 +32,10 @@ def test_chip_smoke_refuses_without_tpu(chip_smoke, capsys):
 
 def test_chip_smoke_one_chip_phases_tiny(chip_smoke, monkeypatch, tmp_path):
     # the streaming-pruned default engine only engages above AUTO_PRUNE_S
-    monkeypatch.setattr(bn_learn, "AUTO_PRUNE_S", 10)
+    monkeypatch.setattr(learner, "AUTO_PRUNE_S", 10)
     cell = chip_smoke.Cell(n=10, q=3, s=2, m=200, chains=2, window=4,
                            iters=100, serve_m=150, serve_iters=100)
-    truth, data = bn_learn._network_data("synth", cell.m, cell.q, cell.seed,
-                                         n_synth=cell.n)
+    truth, data = network_data("synth", cell.m, cell.q, cell.seed,
+                               n_synth=cell.n)
     chip_smoke.one_chip(cell, truth, data, str(tmp_path),
                         require_mosaic=False)

@@ -45,9 +45,9 @@ def test_bn_learn_cli_published_arities():
     """--network alarm --q alarm samples and learns ALARM at its published
     states per variable, through the fused build; a malformed --q fails
     fast."""
-    from repro.data.networks import ALARM_ARITY
+    from repro.data.networks import ALARM_ARITY, network_data
     from repro.launch import bn_learn
-    _, data = bn_learn._network_data("alarm", 300, ALARM_ARITY, 3)
+    _, data = network_data("alarm", 300, ALARM_ARITY, 3)
     assert (data.max(0) == np.asarray(ALARM_ARITY) - 1).all()
     out = bn_learn.main(["--network", "alarm", "--q", "alarm", "--s", "2",
                          "--iters", "40", "--samples", "300",

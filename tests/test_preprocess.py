@@ -432,7 +432,7 @@ def test_state_outside_arity_rejected():
 def test_learn_structure_fused_sparse_end_to_end(tmp_path):
     """preprocess -> MCMC -> adjacency through the driver, fused + pruned +
     cached; the second run must hit the preprocessing cache."""
-    from repro.launch.bn_learn import LearnConfig, learn_structure
+    from repro.learner import LearnConfig, learn_structure
 
     rng = np.random.default_rng(19)
     from repro.core import random_cpts, random_dag

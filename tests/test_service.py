@@ -12,7 +12,7 @@ import os
 import numpy as np
 import pytest
 
-from repro.launch.bn_learn import learn_structure
+from repro.learner import learn_structure
 from repro.service import (DatasetSpec, FleetScheduler, JobManager,
                            admission_key, error_response, job_response,
                            load_dataset, materialize, service_config,

@@ -164,7 +164,7 @@ LEARN_SCRIPT = textwrap.dedent("""
     from repro.core import random_cpts
     from repro.data.bn_sampler import ancestral_sample
     from repro.data.networks import synthetic_adjacency
-    from repro.launch.bn_learn import LearnConfig, learn_structure
+    from repro.learner import LearnConfig, learn_structure
 
     rng = np.random.default_rng(0)
     adj = synthetic_adjacency(rng, 10)

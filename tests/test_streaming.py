@@ -238,7 +238,7 @@ def test_poisoned_cache_manifest_is_logged_miss(tmp_path, caplog):
 def test_bn_learn_auto_prune_switch(monkeypatch):
     """Above the size threshold the fused driver defaults to the streaming
     pruned engine; --no-auto-prune (auto_prune=False) keeps it dense."""
-    from repro.launch import bn_learn as bl
+    from repro import learner as bl
 
     rng = np.random.default_rng(41)
     n, q, s, m = 10, 2, 2, 120
@@ -260,7 +260,7 @@ def test_bn_learn_auto_prune_switch(monkeypatch):
 def test_streaming_n100_s4_end_to_end():
     """The ISSUE 6 acceptance gate: synthetic n = 100, s = 4 learned
     end-to-end through the streaming pruned path in bounded memory."""
-    from repro.launch.bn_learn import LearnConfig, learn_structure
+    from repro.learner import LearnConfig, learn_structure
 
     rng = np.random.default_rng(43)
     n, q, s = 100, 2, 4

@@ -245,7 +245,7 @@ def _bn_data(n=10, m=160):
 
 
 def _cfg(tmp_path, name, **over):
-    from repro.launch.bn_learn import LearnConfig
+    from repro.learner import LearnConfig
     base = dict(q=2, s=2, iters=64, chains=4, seed=5, window=4,
                 exchange_every=8, check_every=32,
                 trace_dir=str(tmp_path / "traces"), run_name=name)
@@ -254,7 +254,7 @@ def _cfg(tmp_path, name, **over):
 
 
 def test_supervised_run_matches_plain_bitwise(tmp_path):
-    from repro.launch.bn_learn import learn_structure
+    from repro.learner import learn_structure
     data = _bn_data()
     o1 = learn_structure(data, _cfg(tmp_path, "plain", telemetry=True))
     o2 = learn_structure(data, _cfg(tmp_path, "sup", telemetry=True,
@@ -266,7 +266,7 @@ def test_supervised_run_matches_plain_bitwise(tmp_path):
 
 
 def test_crash_resume_is_bitwise_identical(tmp_path):
-    from repro.launch.bn_learn import learn_structure
+    from repro.learner import learn_structure
     data = _bn_data()
     ref = learn_structure(data, _cfg(
         tmp_path, "ref", supervise=True, checkpoint_every=32,
@@ -289,7 +289,7 @@ def test_crash_resume_is_bitwise_identical(tmp_path):
 
 
 def test_poison_healed_within_one_interval(tmp_path):
-    from repro.launch.bn_learn import learn_structure
+    from repro.learner import learn_structure
     data = _bn_data()
     out = learn_structure(data, _cfg(
         tmp_path, "heal", telemetry=True, supervise=True, exchange_every=0,
@@ -305,7 +305,7 @@ def test_poison_healed_within_one_interval(tmp_path):
 
 
 def test_stall_healed_by_progress_guard(tmp_path):
-    from repro.launch.bn_learn import learn_structure
+    from repro.learner import learn_structure
     data = _bn_data()
     out = learn_structure(data, _cfg(
         tmp_path, "stall", supervise=True, iters=96,
@@ -318,7 +318,7 @@ def test_stall_healed_by_progress_guard(tmp_path):
 def test_graceful_degradation_without_heal(tmp_path):
     # poisoned chain, NO --supervise: the run must still complete with a
     # finite best score (NaN-safe exchange + finite-guarded accumulators)
-    from repro.launch.bn_learn import learn_structure
+    from repro.learner import learn_structure
     data = _bn_data()
     out = learn_structure(data, _cfg(
         tmp_path, "degrade", telemetry=True,

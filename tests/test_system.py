@@ -7,7 +7,7 @@ import pytest
 from repro.core import random_cpts, random_dag, roc_point
 from repro.core.priors import make_prior_matrix
 from repro.data.bn_sampler import ancestral_sample, inject_noise
-from repro.launch.bn_learn import LearnConfig, learn_structure
+from repro.learner import LearnConfig, learn_structure
 
 
 @pytest.fixture(scope="module")

@@ -267,7 +267,7 @@ def test_old_13_leaf_checkpoint_backfills_trace_leaves(tmp_path):
     upgrade."""
     from repro.checkpoint import restore_checkpoint, save_checkpoint
     from repro.core.mcmc import ChainState
-    from repro.launch.bn_learn import _pack_tree, _unpack_tree
+    from repro.runtime.supervisor import pack_tree, unpack_tree
 
     n, C = 6, 2
     fn = lambda pos: (jnp.float32(-1.0), jnp.zeros(n, jnp.int32),
@@ -275,25 +275,21 @@ def test_old_13_leaf_checkpoint_backfills_trace_leaves(tmp_path):
     from repro.core.mcmc import init_chain
     states = jax.vmap(lambda k: init_chain(k, n, fn))(
         jax.random.split(jax.random.key(5), C))
-    pack = lambda s: jax.tree.map(np.asarray,
-                                  s._replace(key=jax.random.key_data(s.key)))
-    unpack = lambda t: ChainState(*t)._replace(
-        key=jax.random.wrap_key_data(jnp.asarray(t[0])))
 
     # pre-telemetry snapshot: trace=None -> exactly the 13-leaf layout
-    old = _pack_tree(pack, states, None)
+    old = pack_tree(states, None)
     assert len(old) == len(ChainState._fields) == 13
     save_checkpoint(str(tmp_path), 3, old)
 
     trace = init_trace(C, n)
-    template = _pack_tree(pack, states, trace)
+    template = pack_tree(states, trace)
     assert len(template) == 13 + len(TraceState._fields)
     with pytest.raises(ValueError, match="mismatch"):
         restore_checkpoint(str(tmp_path), template, step=3)
     restored, meta = restore_checkpoint(str(tmp_path), template, step=3,
                                         allow_missing=True)
     assert len(meta["missing_leaves"]) == len(TraceState._fields)
-    st2, tr2 = _unpack_tree(unpack, restored, trace)
+    st2, tr2 = unpack_tree(restored, trace)
     np.testing.assert_array_equal(np.asarray(st2.pos), np.asarray(states.pos))
     assert int(tr2.taps) == 0               # backfilled fresh trace
     assert tr2.edge_counts.shape == (C, n, n)
@@ -301,25 +297,21 @@ def test_old_13_leaf_checkpoint_backfills_trace_leaves(tmp_path):
 
 def test_checkpoint_roundtrip_with_trace(tmp_path):
     from repro.checkpoint import restore_checkpoint, save_checkpoint
-    from repro.core.mcmc import ChainState, init_chain
-    from repro.launch.bn_learn import _pack_tree, _unpack_tree
+    from repro.core.mcmc import init_chain
+    from repro.runtime.supervisor import pack_tree, unpack_tree
 
     n, C = 6, 2
     fn = lambda pos: (jnp.float32(-1.0), jnp.zeros(n, jnp.int32),
                       jnp.zeros(n, jnp.float32))
     states = jax.vmap(lambda k: init_chain(k, n, fn))(
         jax.random.split(jax.random.key(6), C))
-    pack = lambda s: jax.tree.map(np.asarray,
-                                  s._replace(key=jax.random.key_data(s.key)))
-    unpack = lambda t: ChainState(*t)._replace(
-        key=jax.random.wrap_key_data(jnp.asarray(t[0])))
     trace = init_trace(C, n)._replace(taps=jnp.int32(5),
                                       reseeds=jnp.asarray([1, 2], jnp.int32))
-    save_checkpoint(str(tmp_path), 9, _pack_tree(pack, states, trace))
+    save_checkpoint(str(tmp_path), 9, pack_tree(states, trace))
     restored, _ = restore_checkpoint(
-        str(tmp_path), _pack_tree(pack, states, init_trace(C, n)), step=9,
+        str(tmp_path), pack_tree(states, init_trace(C, n)), step=9,
         allow_missing=True)
-    _, tr2 = _unpack_tree(unpack, restored, init_trace(C, n))
+    _, tr2 = unpack_tree(restored, init_trace(C, n))
     assert int(tr2.taps) == 5
     np.testing.assert_array_equal(np.asarray(tr2.reseeds), [1, 2])
 
@@ -331,7 +323,7 @@ def _synth_data(m=300, n=8, seed=0):
 
 
 def test_learn_structure_telemetry_end_to_end(tmp_path):
-    from repro.launch.bn_learn import LearnConfig, learn_structure
+    from repro.learner import LearnConfig, learn_structure
 
     cfg = LearnConfig(q=2, s=2, iters=300, chains=3, seed=0, window=4,
                       telemetry=True, trace_every=4, check_every=100,
@@ -353,7 +345,7 @@ def test_learn_structure_telemetry_end_to_end(tmp_path):
 
 
 def test_learn_structure_stop_on_converge(tmp_path):
-    from repro.launch.bn_learn import LearnConfig, learn_structure
+    from repro.learner import LearnConfig, learn_structure
 
     cfg = LearnConfig(q=2, s=2, iters=2000, chains=4, seed=0, window=4,
                       stop_on_converge=True, trace_every=4, check_every=100,
@@ -373,7 +365,7 @@ def test_learn_structure_telemetry_resumes_from_plain_checkpoint(tmp_path):
     """Driver-level schema evolution: a checkpointed run WITHOUT telemetry
     leaves 13-leaf snapshots; re-running the same config WITH telemetry
     resumes from them (trace leaves backfilled) and completes."""
-    from repro.launch.bn_learn import LearnConfig, learn_structure
+    from repro.learner import LearnConfig, learn_structure
 
     ck = str(tmp_path / "ck")
     data = _synth_data()
@@ -390,14 +382,19 @@ def test_learn_structure_telemetry_resumes_from_plain_checkpoint(tmp_path):
     assert info["kinds"]["final"] == 1
 
 
-def test_telemetry_does_not_change_the_walk():
+@pytest.mark.parametrize("chains,adapt", [(1, False), (1, True),
+                                           (2, False), (2, True)])
+def test_telemetry_does_not_change_the_walk(chains, adapt):
     """The taps are observers: the same config with and without telemetry
-    must land on the identical best score and adjacency."""
-    from repro.launch.bn_learn import LearnConfig, learn_structure
+    must land on the identical best score, adjacency and accept rates —
+    one chain or several, fixed or adaptive window. STN samples, not flat
+    random data, so that walks from different keys tell apart."""
+    from repro.data.networks import network_data
+    from repro.learner import LearnConfig, learn_structure
 
-    data = _synth_data()
-    base = dict(q=2, s=2, iters=150, chains=2, seed=0, window=4,
-                exchange_every=30)
+    _, data = network_data("stn", 300, 2, 1)
+    base = dict(q=2, s=2, iters=150, chains=chains, seed=0, window=4,
+                exchange_every=30, adapt_window=adapt)
     plain = learn_structure(data, LearnConfig(**base))
     import tempfile
     with tempfile.TemporaryDirectory() as td:
@@ -406,3 +403,35 @@ def test_telemetry_does_not_change_the_walk():
                               check_every=50, trace_dir=td))
     assert plain["score"] == tapped["score"]
     np.testing.assert_array_equal(plain["adjacency"], tapped["adjacency"])
+    assert plain["chain_accept_rates"] == tapped["chain_accept_rates"]
+
+
+def test_learn_structure_walk_is_the_core_chain_loop():
+    """The library's run loop is pinned to the core multi-chain loop: three
+    exchanging chains through learn_structure land on the best score and
+    graph of a direct mcmc_run_chains call over the same engine."""
+    from repro.core import adjacency_from_ranks
+    from repro.core.mcmc import exchange_best, mcmc_run_chains
+    from repro.data.networks import network_data
+    from repro.learner import (LearnConfig, build_engine, learn_structure,
+                               prepare_run)
+
+    _, data = network_data("stn", 300, 2, 1)
+    cfg = LearnConfig(q=2, s=2, iters=120, chains=3, seed=1, window=4,
+                      exchange_every=25)
+    out = learn_structure(data, cfg)
+    st, _, _ = prepare_run(data, cfg)
+    eng = build_engine(st, cfg)
+    assert eng.mask_on and eng.window == 4
+    states = mcmc_run_chains(jax.random.key(cfg.seed), cfg.chains, st.n,
+                             eng.score_fn, cfg.iters, delta_fn=eng.delta_fn,
+                             window=eng.window,
+                             exchange_every=cfg.exchange_every,
+                             planes_fn=eng.planes_fn)
+    best_score, best_idx, _ = exchange_best(states)
+    assert out["score"] == float(best_score)
+    assert out["chain_accept_rates"] == [
+        int(a) / cfg.iters for a in np.asarray(states.accepts)]
+    np.testing.assert_array_equal(
+        out["adjacency"], adjacency_from_ranks(np.asarray(best_idx), s=cfg.s))
+    assert out["exchange_count"] == 4
