@@ -144,34 +144,24 @@ def build_pst(n_candidates: int, s: int) -> tuple[np.ndarray, np.ndarray]:
 
     Order: size-ascending blocks (empty set first), lexicographic within a block.
     (The paper lists size-4-first; only the block order differs — see DESIGN.md §8.)
+    A size with no subsets (``n_candidates < k``) gives an empty block.
+
+    Built by suffix concatenation, with no per-row loop: the k-subsets whose
+    first element is ``a`` are ``a`` prepended to the (k-1)-subsets of
+    ``{a+1..n_candidates-1}``, which are the last C(n_candidates-1-a, k-1)
+    rows of the (k-1) block. The Python loop runs over sizes x first elements.
     """
-    rows = []
-    sizes = []
-    for k in range(s + 1):
-        if k == 0:
-            rows.append(np.full((1, s), -1, dtype=np.int32))
-            sizes.append(np.zeros(1, dtype=np.int32))
-            continue
-        block = np.empty((math.comb(n_candidates, k), s), dtype=np.int32)
-        block[:] = -1
-        # enumerate lexicographically without per-row unranking (O(S) total)
-        c = np.arange(k, dtype=np.int64)
-        idx = 0
-        while True:
-            block[idx, :k] = c
-            idx += 1
-            # next lexicographic combination
-            j = k - 1
-            while j >= 0 and c[j] == n_candidates - k + j:
-                j -= 1
-            if j < 0:
-                break
-            c[j] += 1
-            for jj in range(j + 1, k):
-                c[jj] = c[jj - 1] + 1
-        rows.append(block)
-        sizes.append(np.full(idx, k, dtype=np.int32))
-    return np.concatenate(rows, axis=0), np.concatenate(sizes)
+    off = [int(o) for o in size_offsets(n_candidates, s)]
+    pst = np.full((off[-1], s), -1, dtype=np.int32)
+    for k in range(1, s + 1):
+        prev_end = row = off[k]          # the (k-1) block ends where k starts
+        for a in range(n_candidates - k + 1):
+            t = math.comb(n_candidates - 1 - a, k - 1)
+            pst[row:row + t, 0] = a
+            pst[row:row + t, 1:k] = pst[prev_end - t:prev_end, :k - 1]
+            row += t
+    sizes = np.repeat(np.arange(s + 1, dtype=np.int32), np.diff(off))
+    return pst, sizes
 
 
 def rank_parent_set(n_candidates: int, s: int, parents: np.ndarray) -> int:

@@ -349,11 +349,14 @@ def _build_dense(data: np.ndarray, r: np.ndarray, *, s: int, ess: float,
     stages."""
     m, n = data.shape
     with span("preprocess.plan") as plan_span:
-        pst, psizes = build_pst(n - 1, s)
+        sub, ssz = build_pst(n, s)               # subsets of ALL n columns
+        # the candidate PST, build_pst(n - 1, s): the subsets without column
+        # n - 1, whose restriction keeps the size-then-lexicographic order
+        keep = (sub != n - 1).all(1)
+        pst, psizes = sub[keep], ssz[keep]
 
         # plan: column subsets, bucketed by q_sigma, chunked + cost-sharded
         # (paper §III-B)
-        sub, _ = build_pst(n, s)                 # subsets of ALL n columns
         lay = plan_subsets(sub, r, chunk, m, len(devices))
         info.update(plan=lay.summary(), bins_real=lay.bins_real,
                     bins_computed=lay.bins_computed)

@@ -76,6 +76,78 @@ def test_pst_complete_and_ordered(nc, s):
         assert off[k + 1] - off[k] == math.comb(nc, k)
 
 
+def _build_pst_rowloop(n_candidates, s):
+    """The per-row enumeration build_pst replaced: steps through every
+    combination of a size block in lexicographic order, one row at a time.
+    Kept as the oracle for the vectorised build (raises where C(N, k) = 0)."""
+    rows = []
+    sizes = []
+    for k in range(s + 1):
+        if k == 0:
+            rows.append(np.full((1, s), -1, dtype=np.int32))
+            sizes.append(np.zeros(1, dtype=np.int32))
+            continue
+        block = np.empty((math.comb(n_candidates, k), s), dtype=np.int32)
+        block[:] = -1
+        c = np.arange(k, dtype=np.int64)
+        idx = 0
+        while True:
+            block[idx, :k] = c
+            idx += 1
+            j = k - 1
+            while j >= 0 and c[j] == n_candidates - k + j:
+                j -= 1
+            if j < 0:
+                break
+            c[j] += 1
+            for jj in range(j + 1, k):
+                c[jj] = c[jj - 1] + 1
+        rows.append(block)
+        sizes.append(np.full(idx, k, dtype=np.int32))
+    return np.concatenate(rows, axis=0), np.concatenate(sizes)
+
+
+PST_CASES = [(1, 1), (5, 2), (6, 4), (12, 4), (36, 4), (37, 4), (59, 4),
+             (60, 4), (20, 6)]
+
+
+def _assert_bitwise(got, want):
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype == np.int32
+        assert g.shape == w.shape
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("nc,s", PST_CASES)
+def test_build_pst_matches_rowloop(nc, s):
+    """The suffix-concatenation build is bitwise the per-row enumeration:
+    rows, padding, sizes, dtypes and order."""
+    _assert_bitwise(build_pst(nc, s), _build_pst_rowloop(nc, s))
+
+
+def test_build_pst_fewer_candidates_than_s():
+    """Where C(N, k) = 0 the size-k block is empty (the row loop raised):
+    build_pst(3, 4) lists the 8 subsets of {0, 1, 2} in size order."""
+    pst, sizes = build_pst(3, 4)
+    want = [c for k in range(5) for c in itertools.combinations(range(3), k)]
+    assert len(want) == 8 and pst.shape == (8, 4)
+    assert [tuple(row[row >= 0].tolist()) for row in pst] == want
+    np.testing.assert_array_equal(sizes, [len(c) for c in want])
+    assert np.all(pst[np.arange(4)[None, :] >= sizes[:, None]] == -1)
+    pst0, sizes0 = build_pst(0, 2)
+    np.testing.assert_array_equal(pst0, [[-1, -1]])
+    np.testing.assert_array_equal(sizes0, [0])
+
+
+@pytest.mark.parametrize("n,s", PST_CASES)
+def test_pst_without_last_column_is_candidate_pst(n, s):
+    """The rows of build_pst(n, s) without column n - 1 are build_pst(n - 1,
+    s), bitwise and in order: what lets the dense build enumerate once."""
+    sub, ssz = build_pst(n, s)
+    keep = (sub != n - 1).all(1)
+    _assert_bitwise((sub[keep], ssz[keep]), build_pst(n - 1, s))
+
+
 @given(hst.integers(2, 20), hst.data())
 @settings(max_examples=100, deadline=None)
 def test_candidate_node_mapping_bijection(n, data):
