@@ -6,7 +6,7 @@ import numpy as np
 from .combinatorics import candidates_to_nodes, unrank_parent_set
 from .scores import arity_vector
 
-__all__ = ["random_dag", "random_cpts", "adjacency_from_best",
+__all__ = ["random_dag", "random_dag_arcs", "random_cpts", "adjacency_from_best",
            "adjacency_from_ranks", "parents_list_from_adjacency",
            "topological_order"]
 
@@ -24,6 +24,31 @@ def random_dag(rng: np.random.Generator, n: int, max_parents: int,
         if npar:
             for m in rng.choice(preds, size=npar, replace=False):
                 adj[m, i] = 1
+    return adj
+
+
+def random_dag_arcs(rng: np.random.Generator, n: int, arcs: int,
+                    max_parents: int = 4) -> np.ndarray:
+    """Random DAG with exactly ``arcs`` edges and at most ``max_parents``
+    parents per node: a random order, then the forward pairs of that order
+    in random sequence, each kept while its head has room."""
+    order = rng.permutation(n)
+    room = np.minimum(np.arange(n), max_parents).sum()
+    if not 0 <= arcs <= room:
+        raise ValueError(f"{arcs} arcs do not fit {n} nodes with at most "
+                         f"{max_parents} parents (at most {room})")
+    tail, head = np.triu_indices(n, 1)             # positions in the order
+    adj = np.zeros((n, n), dtype=np.int8)
+    indeg = np.zeros(n, np.int64)
+    kept = 0
+    for e in rng.permutation(len(tail)):
+        if kept == arcs:
+            break
+        a, b = order[tail[e]], order[head[e]]
+        if indeg[b] < max_parents:
+            adj[a, b] = 1
+            indeg[b] += 1
+            kept += 1
     return adj
 
 

@@ -3,7 +3,8 @@
 Ancestral (forward) sampling from Dirichlet CPTs — the paper assumes complete
 multinomial data (§II). Noise injection (paper §VI, Fig. 11): each entry flips
 state with probability p (for q=2 a bit flip; for q>2 a uniform re-draw among
-the other states). ``q`` is one arity for every variable or one per
+the other states). Continuous data (the BGe score's) come from a
+linear-Gaussian structural equation model instead. ``q`` is one arity for every variable or one per
 variable; a parent configuration is the mixed-radix code of the parents'
 states, the first parent its lowest digit.
 """
@@ -14,7 +15,7 @@ import numpy as np
 from ..core.graph import parents_list_from_adjacency, topological_order
 from ..core.scores import arity_vector
 
-__all__ = ["ancestral_sample", "inject_noise"]
+__all__ = ["ancestral_sample", "inject_noise", "linear_gaussian_sample"]
 
 
 def ancestral_sample(rng: np.random.Generator, adj: np.ndarray,
@@ -56,3 +57,22 @@ def inject_noise(rng: np.random.Generator, data: np.ndarray, p: float,
     shift = rng.integers(1, np.maximum(r, 2), size=data.shape)
     return np.where(flip & (r > 1), (data + shift) % r,
                     data).astype(data.dtype)
+
+
+def linear_gaussian_sample(rng: np.random.Generator, adj: np.ndarray,
+                           m: int) -> np.ndarray:
+    """m samples (m, n) float64 of a linear-Gaussian SEM on ``adj``:
+    x_i = sum_p w_pi x_p + e_i, e_i ~ N(0, 1), each arc's weight drawn
+    uniformly from +-[0.5, 2] (the protocol of Zheng et al. 2018,
+    arXiv:1803.01422, Sec. 5); columns then standardized to mean 0 and
+    variance 1, as users do with expression panels. Draws: the arcs'
+    magnitudes and signs in ``np.nonzero(adj)`` order, then the noise."""
+    n = adj.shape[0]
+    tails, heads = np.nonzero(adj)
+    w = np.zeros((n, n))
+    w[tails, heads] = (rng.uniform(0.5, 2.0, len(tails))
+                       * rng.choice([-1.0, 1.0], len(tails)))
+    x = rng.standard_normal((m, n))
+    for i in topological_order(adj):
+        x[:, i] += x @ w[:, i]
+    return (x - x.mean(0)) / x.std(0)

@@ -6,14 +6,17 @@
   and its published states per variable (2 to 4; 509 free parameters).
 * synthetic — random sparse DAGs at arbitrary n for the paper's n > 60 scale
   claim (§VI uses networks the benchmark suite ships; past ALARM size we
-  generate ALARM-like ground truth instead).
+  generate ALARM-like ground truth instead), or with an exact arc count.
+
+For the BGe score the samples are continuous: a linear-Gaussian SEM on the
+same DAG (bn_sampler.linear_gaussian_sample) in place of the CPTs.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from ..core.graph import random_cpts, random_dag
-from .bn_sampler import ancestral_sample
+from ..core.graph import random_cpts, random_dag, random_dag_arcs
+from .bn_sampler import ancestral_sample, linear_gaussian_sample
 
 STN_NODES = ["Raf", "Mek", "Plcg", "PIP2", "PIP3", "Erk", "Akt", "PKA",
              "PKC", "P38", "Jnk"]
@@ -111,15 +114,23 @@ def synthetic_adjacency(rng: np.random.Generator, n: int = 64, *,
     return random_dag(rng, n, max_parents, edge_prob)
 
 
-def network_data(name: str, m: int, q, seed: int, n_synth: int = 64):
+def network_data(name: str, m: int, q, seed: int, n_synth: int = 64, *,
+                 arcs: int = 0, score: str = "bdeu"):
     """(ground-truth adjacency, (m, n) samples) for a named network —
-    ``alarm``, ``stn`` or ``synth`` (a synthetic DAG of n_synth nodes) —
+    ``alarm``, ``stn`` or ``synth`` (a synthetic DAG of n_synth nodes; with
+    ``arcs`` > 0, exactly that many arcs and at most 4 parents a node) —
     with seeded random CPTs at q states per variable (one int, or one per
-    variable)."""
+    variable), or for ``score="bge"`` standardized linear-Gaussian samples
+    (float64; q unused)."""
     rng = np.random.default_rng(seed)
+    if arcs and name != "synth":
+        raise ValueError(f"an arc count is drawn for synth, not {name!r}")
     if name == "synth":
-        adj = synthetic_adjacency(rng, n_synth)
+        adj = (random_dag_arcs(rng, n_synth, arcs) if arcs
+               else synthetic_adjacency(rng, n_synth))
     else:
         adj = {"alarm": alarm_adjacency, "stn": stn_adjacency}[name]()
+    if score == "bge":
+        return adj, linear_gaussian_sample(rng, adj, m)
     cpts = random_cpts(rng, adj, q)
     return adj, ancestral_sample(rng, adj, cpts, m, q)

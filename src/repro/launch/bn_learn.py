@@ -8,10 +8,15 @@ ground truth and the run's mode.
       --preprocess fused                         # ALARM's published arities
   python -m repro.launch.bn_learn --network synth --n 64 --s 3 \
       --preprocess fused --prune-delta 30        # fused pipeline + compression
+  python -m repro.launch.bn_learn --network synth --n 64 --arcs 102 \
+      --score bge --preprocess fused             # continuous data, BGe
 
 --q is the variables' number of states: one int for all, a comma list of
 one per variable, or a named table (``alarm``: ALARM's published 2-4
 states per variable); the data are sampled and scored at those arities.
+--score bge samples continuous data instead (a linear-Gaussian SEM on the
+network's DAG, standardized columns) and scores them with the Gaussian BGe
+score; --arcs gives the synthetic DAG an exact arc count.
 --telemetry writes the run's convergence trace as JSONL under --trace-dir;
 --supervise and --fault-plan (grammar in runtime/faults.py) turn on chain
 healing and deterministic chaos between segments.
@@ -67,6 +72,13 @@ def main(argv=None) -> dict:
                          "list of one per variable, or 'alarm' (ALARM's "
                          "published arities)")
     ap.add_argument("--s", type=int, default=4)
+    ap.add_argument("--score", default="bdeu", choices=["bdeu", "bge"],
+                    help="bdeu: discrete states at --q; bge: continuous "
+                         "linear-Gaussian samples, Gaussian BGe score")
+    ap.add_argument("--arcs", type=int, default=0,
+                    help="--network synth: exactly this many arcs, at most "
+                         "4 parents a node (0: edge probability 0.45, at "
+                         "most 3 parents)")
     ap.add_argument("--noise", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--use-kernel", action="store_true")
@@ -158,7 +170,8 @@ def main(argv=None) -> dict:
     args = ap.parse_args(argv)
 
     truth, data = network_data(args.network, args.samples, args.q, args.seed,
-                               n_synth=args.n)
+                               n_synth=args.n, arcs=args.arcs,
+                               score=args.score)
     n_nodes = truth.shape[0]
     # reject degenerate windows HERE, with a readable message, instead of
     # letting propose_move silently clamp (window > n) or trace garbage
@@ -172,9 +185,12 @@ def main(argv=None) -> dict:
                  f"{n_nodes} nodes; pick 2 <= window <= {n_nodes} (or 0) — "
                  "oversized windows would only be silently clamped")
     if args.noise:
+        if args.score == "bge":
+            ap.error("--noise flips discrete states; it has no meaning for "
+                     "--score bge")
         data = inject_noise(np.random.default_rng(args.seed + 1), data,
                             args.noise, args.q)
-    cfg = LearnConfig(q=args.q, s=args.s, iters=args.iters,
+    cfg = LearnConfig(q=args.q, s=args.s, score=args.score, iters=args.iters,
                       chains=args.chains, seed=args.seed,
                       use_kernel=args.use_kernel, window=args.window,
                       mask_cache=not args.no_mask_cache,
@@ -218,7 +234,7 @@ def main(argv=None) -> dict:
         mode += f"+exch({out['exchange_every']})"
     pre = f"pre={out['preprocess_s']:.2f}s"
     if args.preprocess == "fused":
-        tags = ["fused"]
+        tags = ["fused"] + (["bge"] if args.score == "bge" else [])
         if out.get("auto_pruned"):
             tags.append("auto-pruned")
         if out["preprocess_cache_hit"]:

@@ -96,7 +96,7 @@ class BNServer(ThreadingHTTPServer):
     def submit(self, payload: dict) -> dict:
         spec = DatasetSpec(**payload.get("dataset", {}))
         cfg = service_config(payload.get("config", {}))
-        data = load_dataset(spec, cfg.q)
+        data = load_dataset(spec, cfg.q, score=cfg.score)
         with self.lock:
             job, deduped = self.scheduler.submit(data, cfg)
             return job_response(job, deduped=deduped)

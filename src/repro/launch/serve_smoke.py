@@ -158,7 +158,7 @@ def main(argv=None) -> int:
         art = fetch_artifacts(base, jid)
         post, mapr, cons = art["posterior"], art["map"], art["consensus"]
         cfg = service_config(config)
-        data = load_dataset(DatasetSpec(**spec), cfg.q)
+        data = load_dataset(DatasetSpec(**spec), cfg.q, score=cfg.score)
         ref = learn_structure(data, cfg)
         same = {
             "posterior": np.array_equal(np.asarray(post["edge_probs"]),

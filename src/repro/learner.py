@@ -47,6 +47,7 @@ from .core.order_scoring import (build_membership_planes,
                                  score_order_delta_bitmask,
                                  score_order_pruned, score_order_pruned_delta,
                                  score_order_sum_cached, score_order_sum_delta)
+from .core.scores import check_score
 from .data.networks import parse_arity
 from .preprocess import SparseScoreTable, build_score_table_fused
 from .runtime.faults import parse_fault_plan
@@ -77,6 +78,12 @@ class LearnConfig:
     s: int = 4                    # max parent-set size (paper uses 4)
     gamma: float = 0.1            # structure penalty
     ess: float = 1.0              # BDeu equivalent sample size
+    score: str = "bdeu"           # "bdeu" (discrete states) | "bge"
+                                  # (continuous data, Gaussian BGe score;
+                                  # q and ess unused)
+    bge_alpha_mu: float = 1.0     # BGe prior mean's equivalent sample size
+    bge_alpha_w: float | None = None  # BGe Wishart degrees of freedom
+                                      # (None: n + 2)
     iters: int = 1000
     chains: int = 1
     seed: int = 0
@@ -137,6 +144,7 @@ class LearnConfig:
 
     def __post_init__(self):
         self.q = parse_arity(self.q)
+        check_score(self.score)
 
 
 class Engine(NamedTuple):
@@ -290,7 +298,9 @@ def prepare_run(data: np.ndarray, cfg: LearnConfig, *,
     """The preprocess + telemetry half of the pipeline, shared by
     :func:`learn_structure` and the posterior service's job manager
     (service/jobs.py): builds the score table (reference or fused pipeline,
-    auto-prune switch, disk cache) and the telemetry collector.
+    auto-prune switch, disk cache) and the telemetry collector. ``data``
+    holds integer states under ``cfg.score == "bdeu"`` and continuous
+    measurements under ``"bge"``.
 
     Returns (st, collector, pre) with pre = {"t_pre", "cache_hit",
     "auto_pruned"}."""
@@ -316,15 +326,18 @@ def prepare_run(data: np.ndarray, cfg: LearnConfig, *,
         # O(n*K) pruned scorers — the dense (n, S) build is the memory wall
         prune_delta = AUTO_PRUNE_DELTA
         auto_pruned = True
+    scoring = dict(score=cfg.score, bge_alpha_mu=cfg.bge_alpha_mu,
+                   bge_alpha_w=cfg.bge_alpha_w)
     if cfg.preprocess == "fused":
         st, pre_info = build_score_table_fused(
             data, q=cfg.q, s=cfg.s, gamma=cfg.gamma, ess=cfg.ess,
             prior_matrix=prior_matrix, prune_delta=prune_delta,
-            cache_dir=cfg.cache_dir or None, return_info=True)
+            cache_dir=cfg.cache_dir or None, return_info=True, **scoring)
         cache_hit = pre_info["cache_hit"]
     else:
         st = build_score_table(data, q=cfg.q, s=cfg.s, gamma=cfg.gamma,
-                               ess=cfg.ess, prior_matrix=prior_matrix)
+                               ess=cfg.ess, prior_matrix=prior_matrix,
+                               **scoring)
     jax.block_until_ready(st.kept_ls if isinstance(st, SparseScoreTable)
                           else st.table)
     t_pre = time.time() - t0

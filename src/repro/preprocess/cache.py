@@ -2,8 +2,9 @@
 
 Keyed on everything the table depends on — a SHA-256 over the data bytes,
 the per-variable arity vector and the scoring hyperparameters (s, ess,
-gamma, prior matrix INCLUDING its shape/dtype) — so a second `bn_learn`
-invocation with identical inputs
+gamma, prior matrix INCLUDING its shape/dtype), and for BGe the score kind,
+the float64 data bytes and alpha_mu, alpha_w in place of the states and
+arities — so a second `bn_learn` invocation with identical inputs
 restores the table instead of recomputing it. Storage rides
 checkpoint/checkpointer: atomic publish (write-to-temp + rename) means a
 killed run can never leave a readable-but-corrupt cache entry, and entries
@@ -42,7 +43,8 @@ import os
 import numpy as np
 
 from ..checkpoint import latest_step, restore_checkpoint, save_checkpoint
-from ..core.scores import arity_vector
+from ..core.scores import (arity_vector, as_states, bge_data, bge_prior,
+                           check_score)
 
 __all__ = ["cache_key", "load_cached_table", "store_cached_table",
            "load_cached_sparse", "store_cached_sparse"]
@@ -55,20 +57,31 @@ logger = logging.getLogger(__name__)
 def cache_key(data: np.ndarray, *, q, s: int, gamma: float, ess: float,
               prior_matrix: np.ndarray | None = None,
               prune_delta: float | None = None,
-              max_keep: int | None = None) -> str:
+              max_keep: int | None = None, score: str = "bdeu",
+              bge_alpha_mu: float = 1.0,
+              bge_alpha_w: float | None = None) -> str:
     """Hex digest identifying one preprocessing problem instance. ``q`` is
     one arity or one per variable; the digest holds the arity vector, so an
     int q and the same arity for every variable share a key, and two
-    different vectors never do.
+    different vectors never do. BDeu data must be integer states (as the
+    build refuses anything else); BGe entries are keyed on the float64
+    data bytes, the score kind and alpha_mu, alpha_w (``q`` and ``ess``
+    unused), so no BDeu entry and no other continuous dataset shares one.
 
     ``prune_delta``/``max_keep`` enter the digest only when set — they key
     the PRUNED (sparse) entries, whose kept set depends on both; dense
     entries are delta-independent and keep the delta-free key."""
     h = hashlib.sha256()
     h.update(_FORMAT.encode())
-    arr = np.ascontiguousarray(np.asarray(data, np.int32))
-    h.update(repr((arr.shape, s, float(gamma), float(ess))).encode())
-    h.update(arity_vector(q, arr.shape[1]).tobytes())
+    if check_score(score) == "bge":
+        arr = np.ascontiguousarray(bge_data(data))
+        alpha_w = bge_prior(bge_alpha_mu, bge_alpha_w, arr.shape[1])[0]
+        h.update(repr(("bge", arr.shape, s, float(gamma),
+                       float(bge_alpha_mu), alpha_w)).encode())
+    else:
+        arr = np.ascontiguousarray(as_states(data))
+        h.update(repr((arr.shape, s, float(gamma), float(ess))).encode())
+        h.update(arity_vector(q, arr.shape[1]).tobytes())
     h.update(arr.tobytes())
     if prior_matrix is not None:
         R = np.ascontiguousarray(np.asarray(prior_matrix, np.float32))

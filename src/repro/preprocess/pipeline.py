@@ -39,6 +39,14 @@ the same either way for ``q = [q] * n``. Column subsets are counted in
 chunks of similar q_sigma (planner.plan_subsets), each at its bucket's bin
 count Q.
 
+``score`` is a choice about the data: ``"bdeu"`` scores integer states
+(non-integer data is refused), ``"bge"`` scores continuous data with the
+Gaussian BGe score (bge.py; ``q`` and ``ess`` unused). Both assemblies meet
+the score at one seam, :func:`chunk_runner`: a function of a planned chunk
+that returns its (chunk, n) ``TI``. BGe has no q_sigma buckets, so its
+chunks come out in ``build_pst(n, s)`` order and the dense assembly keeps
+``TI`` on the device.
+
 The dense result is bitwise-compatible with build_score_table on CPU (the
 oracle's reduction order is reproduced deliberately; see fused.py) at a
 fraction of the wall clock — benchmarks/preprocess_bench.py measures >= 3x
@@ -47,16 +55,18 @@ practical; the streaming path extends reach to n = 100, s = 4 (S ~ 3.9M)
 where the dense intermediate alone is ~1.6 GB.
 
 With ``return_info=True`` the info dict has the SAME schema on cache hit and
-miss: {cache_hit, n, S, plan, preprocess_s, streaming,
-peak_assembly_bytes, bins_real, bins_computed, stages}. ``plan`` is None on
+miss: {cache_hit, n, S, score, plan, preprocess_s, streaming,
+peak_assembly_bytes, bins_real, bins_computed, stages}; ``score`` is the
+score kind. ``plan`` is None on
 a cache hit (no sharding was planned), a {n_chunks, n_devices, imbalance,
 q_buckets} dict otherwise; ``bins_real`` (sum of q_sigma over the column
 subsets) and ``bins_computed`` (sum over chunks of chunk x Q) count the
-kernel's useful and computed bins, None on a cache hit;
+kernel's useful and computed bins, None on a cache hit and for BGe;
 ``peak_assembly_bytes`` is None unless the streaming assembly ran.
 ``stages`` breaks ``preprocess_s`` into per-stage wall-clock seconds
 (plan_s/score_s/assemble_s on the dense path, plan_s/stream_s/finalize_s
-streaming, cache_load_s/cache_store_s around the disk cache) — the
+streaming, cache_load_s/cache_store_s around the disk cache; BGe's
+``preprocess.moments`` lies inside score_s or plan_s) — the
 telemetry collector (repro/learner.prepare_run) emits them as stage rows.
 Each is the ``.seconds`` of a telemetry span (``preprocess.build`` gives
 ``preprocess_s``; ``preprocess.plan``/``score``/``assemble``, the last split
@@ -73,7 +83,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.combinatorics import build_pst, n_parent_sets, size_offsets
-from ..core.scores import (ScoreTable, arity_vector, check_states,
+from ..core.scores import (ScoreTable, arity_vector, as_states, bge_data,
+                           bge_prior, check_score, check_states,
                            validate_prior_matrix)
 from ..telemetry.spans import span
 from .cache import (cache_key, load_cached_sparse, load_cached_table,
@@ -83,7 +94,7 @@ from .fused import (child_columns, encode_subset_codes, fused_scores_pallas,
 from .planner import plan_subsets
 from .sparse import SparseScoreTable, prune_table
 
-__all__ = ["build_score_table_fused", "assemble_table"]
+__all__ = ["build_score_table_fused", "assemble_table", "chunk_runner"]
 
 
 def _binom(x: jnp.ndarray, r: jnp.ndarray, s: int) -> jnp.ndarray:
@@ -206,6 +217,34 @@ def _device_inputs(data: np.ndarray, r: np.ndarray, lay, luts, device):
                            lay.qsig.reshape(shape), luts), device)
 
 
+def chunk_runner(data: np.ndarray, r: np.ndarray, lay, devices, *,
+                 score: str, ess: float, bge_alpha_mu: float,
+                 bge_alpha_w: float | None, use_pallas: bool, block_m: int,
+                 interpret):
+    """The seam both assemblies share: ``run(d, ids, Q)`` -> (len(ids),
+    chunk, n) ``TI`` of the planned chunks ``ids`` (an int32 array on device
+    d) of bin-count bucket Q, after the inputs every call of the build
+    reads are on the devices. BDeu counts and scores (fused.py), BGe
+    factors the moments (bge.py)."""
+    if score == "bge":
+        from .bge import bge_chunk_runner
+        return bge_chunk_runner(data, lay, devices, alpha_mu=bge_alpha_mu,
+                                alpha_w=bge_alpha_w, use_pallas=use_pallas,
+                                interpret=interpret)
+    luts = score_luts(lay.qsig, r, data.shape[0], ess)
+    ins = [_device_inputs(data, r, lay, luts, dev)
+           for dev in devices[:lay.n_devices]]
+    r_max = int(r.max())
+
+    def run(d, ids, Q):
+        # one program per bin-count bucket (at most MAX_BUCKETS), compiled
+        # once per problem shape and reused by every build
+        return _run_device(*ins[d], ids, Q=Q, r_max=r_max, ess=ess,
+                           use_pallas=use_pallas, block_m=block_m,
+                           interpret=interpret)
+    return run
+
+
 def build_score_table_fused(data: np.ndarray, *, q, s: int,
                             gamma: float = 0.1, ess: float = 1.0,
                             chunk: int = 1024,
@@ -218,7 +257,9 @@ def build_score_table_fused(data: np.ndarray, *, q, s: int,
                             use_pallas: bool | None = None,
                             block_m: int = 512,
                             interpret: bool | None = None,
-                            return_info: bool = False):
+                            return_info: bool = False, score: str = "bdeu",
+                            bge_alpha_mu: float = 1.0,
+                            bge_alpha_w: float | None = None):
     """Drop-in replacement for core/scores.build_score_table (same table, same
     PST ordering) via the fused pipeline. Returns a ScoreTable — or a
     SparseScoreTable when ``prune_delta`` is set — and, with
@@ -237,12 +278,27 @@ def build_score_table_fused(data: np.ndarray, *, q, s: int,
     (launch/mesh meshes work directly); default is the first local device.
     ``use_pallas`` defaults to True on TPU, False elsewhere (the jnp fused
     path is the fast CPU path; the kernel is the fast TPU path).
+
+    ``score="bge"`` scores continuous ``data`` with the BGe score, its
+    hyperparameters ``bge_alpha_mu`` and ``bge_alpha_w`` (None: n + 2);
+    ``q`` and ``ess`` are then unused. The BDeu default refuses data that
+    is not integer.
     """
     with span("preprocess.build") as build:
-        data = np.asarray(data, dtype=np.int32)
-        m, n = data.shape
-        r = arity_vector(q, n)
-        check_states(data, r)
+        if check_score(score) == "bge":
+            data = bge_data(data)
+            m, n = data.shape
+            r = np.ones(n, np.int32)          # no bins: one bucket, in order
+            bge_alpha_w = bge_prior(bge_alpha_mu, bge_alpha_w, n)[0]
+            q = None
+            kind = {"score": "bge", "alpha_mu": float(bge_alpha_mu),
+                    "alpha_w": bge_alpha_w}
+        else:
+            data = as_states(data)
+            m, n = data.shape
+            r = arity_vector(q, n)
+            check_states(data, r)
+            kind = {"score": "bdeu", "arity": r.tolist(), "ess": float(ess)}
         validate_prior_matrix(prior_matrix, n)
         if use_pallas is None:
             use_pallas = jax.default_backend() == "tpu"
@@ -253,13 +309,15 @@ def build_score_table_fused(data: np.ndarray, *, q, s: int,
         S = n_parent_sets(n - 1, s)
         # "stages" is the per-stage wall-clock breakdown of preprocess_s —
         # the telemetry collector's stage rows (repro/learner) read it
-        info: dict = {"cache_hit": False, "n": n, "S": S, "plan": None,
+        info: dict = {"cache_hit": False, "n": n, "S": S, "score": score,
+                      "plan": None,
                       "preprocess_s": None, "streaming": streaming,
                       "peak_assembly_bytes": None, "bins_real": None,
                       "bins_computed": None, "stages": {}}
         log_gamma = float(np.log(gamma))
-        expect = {"arity": r.tolist(), "s": s, "m": m, "n": n,
-                  "gamma": float(gamma), "ess": float(ess)}
+        expect = {**kind, "s": s, "m": m, "n": n, "gamma": float(gamma)}
+        scoring = dict(score=score, ess=ess, bge_alpha_mu=bge_alpha_mu,
+                       bge_alpha_w=bge_alpha_w)
         if devices is None:
             devices = (list(np.asarray(mesh.devices).flat) if mesh is not None
                        else [jax.devices()[0]])
@@ -268,12 +326,13 @@ def build_score_table_fused(data: np.ndarray, *, q, s: int,
         key = skey = None
         sp = dense = None
         if cache_dir:
-            key = cache_key(data, q=q, s=s, gamma=gamma, ess=ess,
-                            prior_matrix=prior_matrix)
+            key = cache_key(data, q=q, s=s, gamma=gamma,
+                            prior_matrix=prior_matrix, **scoring)
             if prune_delta is not None:
-                skey = cache_key(data, q=q, s=s, gamma=gamma, ess=ess,
+                skey = cache_key(data, q=q, s=s, gamma=gamma,
                                  prior_matrix=prior_matrix,
-                                 prune_delta=prune_delta, max_keep=max_keep)
+                                 prune_delta=prune_delta, max_keep=max_keep,
+                                 **scoring)
                 hit = load_cached_sparse(cache_dir, skey, expect=expect)
                 if hit is not None:
                     kept_idx, kept_ls, kept_parents, _ = hit
@@ -293,21 +352,21 @@ def build_score_table_fused(data: np.ndarray, *, q, s: int,
             # intermediate
             from .streaming import build_sparse_table_streaming
             sp, sinfo = build_sparse_table_streaming(
-                data, q=q, s=s, gamma=gamma, ess=ess, chunk=chunk,
+                data, q=q, s=s, gamma=gamma, chunk=chunk,
                 delta=prune_delta, prior_matrix=prior_matrix,
                 max_keep=max_keep, devices=devices, use_pallas=use_pallas,
-                block_m=block_m, interpret=interpret)
+                block_m=block_m, interpret=interpret, **scoring)
             info["plan"] = {k: sinfo[k] for k in ("n_chunks", "n_devices",
                                                   "imbalance", "q_buckets")}
             for k in ("peak_assembly_bytes", "bins_real", "bins_computed"):
                 info[k] = sinfo[k]
             info["stages"].update(sinfo.get("stages", {}))
         else:
-            dense = _build_dense(data, r, s=s, ess=ess, chunk=chunk,
+            dense = _build_dense(data, r, s=s, chunk=chunk,
                                  log_gamma=log_gamma,
                                  prior_matrix=prior_matrix, devices=devices,
                                  use_pallas=use_pallas, block_m=block_m,
-                                 interpret=interpret, info=info)
+                                 interpret=interpret, info=info, **scoring)
     info["preprocess_s"] = build.seconds
 
     if info["cache_hit"]:
@@ -340,9 +399,9 @@ def build_score_table_fused(data: np.ndarray, *, q, s: int,
     return (st, info) if return_info else st
 
 
-def _build_dense(data: np.ndarray, r: np.ndarray, *, s: int, ess: float,
-                 chunk: int, log_gamma: float, prior_matrix, devices,
-                 use_pallas: bool, block_m: int, interpret, info: dict):
+def _build_dense(data: np.ndarray, r: np.ndarray, *, s: int, chunk: int,
+                 log_gamma: float, prior_matrix, devices, use_pallas: bool,
+                 block_m: int, interpret, info: dict, **scoring):
     """(table, pst, psizes) by the dense assembly: plan the column-subset
     chunks, score them on the devices, rank-gather the (n, S) table. Fills
     ``info["plan"]``, the bin counts and the plan_s/score_s/assemble_s
@@ -358,38 +417,39 @@ def _build_dense(data: np.ndarray, r: np.ndarray, *, s: int, ess: float,
         # plan: column subsets, bucketed by q_sigma, chunked + cost-sharded
         # (paper §III-B)
         lay = plan_subsets(sub, r, chunk, m, len(devices))
-        info.update(plan=lay.summary(), bins_real=lay.bins_real,
-                    bins_computed=lay.bins_computed)
+        info["plan"] = lay.summary()
+        if scoring["score"] == "bdeu":
+            info.update(bins_real=lay.bins_real,
+                        bins_computed=lay.bins_computed)
 
     with span("preprocess.score") as score_span:
         # execute: per device, one jitted scan over its chunks of a bucket
-        luts = score_luts(lay.qsig, r, m, ess)
+        run = chunk_runner(data, r, lay, devices, use_pallas=use_pallas,
+                           block_m=block_m, interpret=interpret, **scoring)
         per_dev = []
         for d, dev in enumerate(devices[:lay.n_devices]):
-            ins = _device_inputs(data, r, lay, luts, dev)
             for Q, first, plan in lay.buckets:
                 if d >= plan.n_devices:
                     continue
                 ids = plan.padded_chunks[d] + first
-                # one program per bin-count bucket (at most MAX_BUCKETS),
-                # compiled once per problem shape and reused by every build
-                # bnlint: disable=retrace-loop-varying-static
-                out = _run_device(*ins, jax.device_put(jnp.asarray(ids), dev),
-                                  Q=Q, r_max=int(r.max()), ess=ess,
-                                  use_pallas=use_pallas, block_m=block_m,
-                                  interpret=interpret)        # async dispatch
-                per_dev.append((ids, out))
+                out = run(d, jax.device_put(jnp.asarray(ids), dev), Q)
+                per_dev.append((ids, out))                # async dispatch
 
-        chunk = lay.chunk
-        TI = np.zeros((lay.n_chunks * chunk, n), np.float32)
-        for ids, out in per_dev:
-            out = np.asarray(out)                          # (U, C, n) sync
-            for u, ci in enumerate(ids):                   # dupes: same data
-                TI[ci * chunk:(ci + 1) * chunk] = out[u]
-        # back to build_pst(n, s) order: the rank map's subset ranks
-        where = np.empty(len(sub), np.int32)
-        where[lay.row[lay.row >= 0]] = np.nonzero(lay.row >= 0)[0]
-        TI = jnp.asarray(TI)[jnp.asarray(where)]
+        if len(per_dev) == 1:
+            # one bucket on one device: the chunks are in build_pst(n, s)
+            # order already, so TI stays on the device
+            TI = per_dev[0][1].reshape(-1, n)[:len(sub)]
+        else:
+            chunk = lay.chunk
+            TI = np.zeros((lay.n_chunks * chunk, n), np.float32)
+            for ids, out in per_dev:
+                out = np.asarray(out)                      # (U, C, n) sync
+                for u, ci in enumerate(ids):               # dupes: same data
+                    TI[ci * chunk:(ci + 1) * chunk] = out[u]
+            # back to build_pst(n, s) order: the rank map's subset ranks
+            where = np.empty(len(sub), np.int32)
+            where[lay.row[lay.row >= 0]] = np.nonzero(lay.row >= 0)[0]
+            TI = jnp.asarray(TI)[jnp.asarray(where)]
 
     with span("preprocess.assemble") as assemble_span:
         # assemble: rank-gather + structure penalty (+ prior)

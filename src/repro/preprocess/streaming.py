@@ -50,7 +50,7 @@ import numpy as np
 from ..core.combinatorics import (build_pst, n_parent_sets,
                                   rank_combinations_batch)
 from ..core.order_scoring import NEG_INF
-from ..core.scores import arity_vector
+from ..core.scores import arity_vector, as_states, bge_data
 from ..telemetry.spans import span
 from .planner import plan_subsets
 from .sparse import SparseScoreTable
@@ -154,7 +154,8 @@ def build_sparse_table_streaming(
         ess: float = 1.0, chunk: int = 1024, delta: float,
         prior_matrix: np.ndarray | None = None, max_keep: int | None = None,
         devices=None, use_pallas: bool = False, block_m: int = 512,
-        interpret: bool | None = None):
+        interpret: bool | None = None, score: str = "bdeu",
+        bge_alpha_mu: float = 1.0, bge_alpha_w: float | None = None):
     """(SparseScoreTable, stream_info): the fused pipeline streamed straight
     into the pruned representation. Bitwise-equal to
     ``prune_table(build_score_table_fused(...), delta)`` (kept sets, packed
@@ -165,15 +166,21 @@ def build_sparse_table_streaming(
     "K", "stages"} — the plan and the bin counts as the dense build reports
     them; ``stages`` breaks the
     wall-clock into {plan_s, stream_s, finalize_s} for the telemetry
-    collector's stage rows.
+    collector's stage rows. ``score`` and the BGe hyperparameters are
+    build_score_table_fused's; the chunks are scored through the same
+    ``pipeline.chunk_runner``.
     """
-    from .fused import score_luts
-    from .pipeline import _device_inputs, _run_device
+    from .pipeline import chunk_runner
 
     with span("preprocess.plan") as plan_span:
-        data = np.asarray(data, dtype=np.int32)
-        m, n = data.shape
-        r = arity_vector(q, n)
+        if score == "bge":
+            data = bge_data(data)
+            m, n = data.shape
+            r = np.ones(n, np.int32)
+        else:
+            data = as_states(data)
+            m, n = data.shape
+            r = arity_vector(q, n)
         S = n_parent_sets(n - 1, s)
         log_gamma = float(np.log(gamma))
 
@@ -190,9 +197,10 @@ def build_sparse_table_streaming(
         R = (jnp.asarray(prior_matrix, jnp.float32)
              if prior_matrix is not None else None)
 
-        luts = score_luts(lay.qsig, r, m, ess)
-        dev_in = [_device_inputs(data, r, lay, luts, dev)
-                  for dev in devices[:lay.n_devices]]
+        run = chunk_runner(data, r, lay, devices, score=score, ess=ess,
+                           bge_alpha_mu=bge_alpha_mu,
+                           bge_alpha_w=bge_alpha_w, use_pallas=use_pallas,
+                           block_m=block_m, interpret=interpret)
 
         # ---- streaming merge state
         best = np.full(n, np.float32(NEG_INF), np.float32)  # global best
@@ -250,13 +258,7 @@ def build_sparse_table_streaming(
         pending: deque = deque()
         for d, ci, Q in schedule:
             ids = jax.device_put(jnp.asarray([ci], jnp.int32), devices[d])
-            # one program per bin-count bucket, as in the dense build
-            # bnlint: disable=retrace-loop-varying-static
-            out = _run_device(*dev_in[d], ids, Q=Q, r_max=int(r.max()),
-                              ess=ess, use_pallas=use_pallas,
-                              block_m=block_m,
-                              interpret=interpret)            # async dispatch
-            pending.append((d, ci, out))
+            pending.append((d, ci, run(d, ids, Q)))       # async dispatch
             if len(pending) >= _INFLIGHT_PER_DEV * lay.n_devices:
                 dd, cc_, fut = pending.popleft()
                 merge_chunk(dd, cc_, np.asarray(fut)[0])
@@ -300,8 +302,10 @@ def build_sparse_table_streaming(
 
         sp = SparseScoreTable.from_kept(kept_idx, kept_ls, kept_parents,
                                         q=q, s=s, delta=delta, S=S)
+    bge = score == "bge"
     info = {"peak_assembly_bytes": int(peak), **lay.summary(),
-            "bins_real": lay.bins_real, "bins_computed": lay.bins_computed,
+            "bins_real": None if bge else lay.bins_real,
+            "bins_computed": None if bge else lay.bins_computed,
             "kept_entries": int(counts.sum()) + n, "K": K,
             "stages": {"plan_s": plan_span.seconds,
                        "stream_s": stream_span.seconds,

@@ -12,7 +12,8 @@ the interleaving only changes *when* each segment executes on the host,
 never the segment boundaries or any PRNG stream.
 
 Admission rides the preprocess cache's content key: two requests with
-identical (data, arity vector, s, ess, gamma, prior, pruning) AND identical
+identical (data, score kind, arity vector or BGe hyperparameters, s, ess,
+gamma, prior, pruning) AND identical
 run-affecting config (iters, chains, seed, windows, telemetry cadence, …)
 hash to the same job id, so the second request ATTACHES to the in-flight
 or completed job instead of recomputing — the dedup layer the ROADMAP's
@@ -36,6 +37,7 @@ from dataclasses import dataclass, replace
 
 import numpy as np
 
+from ..core.scores import as_states, bge_data
 from ..learner import (LearnConfig, build_engine, finish_run, prepare_run,
                        start_run)
 from ..preprocess.cache import cache_key
@@ -60,31 +62,38 @@ _RUN_FIELDS = ("iters", "chains", "seed", "window", "mask_cache",
 @dataclass(frozen=True)
 class DatasetSpec:
     """What to learn on: a named generator network, a synthetic DAG, or a
-    file-backed sample matrix (``.npy`` int array, rows = samples)."""
+    file-backed sample matrix (``.npy``, rows = samples: integer states
+    for BDeu, measurements for BGe)."""
     network: str = "stn"     # alarm | stn | synth | file
     n: int = 16              # node count (network == "synth")
+    arcs: int = 0            # exact arc count (network == "synth"; 0 = off)
     m: int = 300             # samples to draw (generator networks)
     seed: int = 0            # data-generation seed
     noise: float = 0.0       # label-noise fraction (generator networks)
     path: str = ""           # network == "file": .npy sample matrix
 
 
-def load_dataset(spec: DatasetSpec, q) -> np.ndarray:
+def load_dataset(spec: DatasetSpec, q, score: str = "bdeu") -> np.ndarray:
     """Materialise the sample matrix for one dataset spec — the same
     generators the ``bn_learn`` CLI uses, so a service job and a standalone
     run of the same spec see byte-identical data. ``q``: states per
-    variable, one int or one per variable (``LearnConfig.q``)."""
+    variable, one int or one per variable (``LearnConfig.q``); ``score``
+    (``LearnConfig.score``): "bge" draws or loads continuous data, "bdeu"
+    integer states, refusing a file whose values are not integers."""
     if spec.network == "file":
         data = np.load(spec.path, allow_pickle=False)
         if data.ndim != 2:
             raise ValueError(f"dataset file {spec.path} must hold a 2-D "
                              f"(samples, nodes) matrix, got {data.shape}")
-        return np.asarray(data, np.int8)
+        return bge_data(data) if score == "bge" else as_states(data)
     from ..data.bn_sampler import inject_noise
     from ..data.networks import network_data
     _, data = network_data(spec.network, spec.m, q, spec.seed,
-                           n_synth=spec.n)
+                           n_synth=spec.n, arcs=spec.arcs, score=score)
     if spec.noise:
+        if score == "bge":
+            raise ValueError("noise flips discrete states; a BGe dataset "
+                             "takes none")
         data = inject_noise(np.random.default_rng(spec.seed + 1), data,
                             spec.noise, q)
     return data
@@ -111,14 +120,17 @@ def service_config(overrides: dict | None = None, **kw) -> LearnConfig:
 
 def admission_key(data: np.ndarray, cfg: LearnConfig,
                   prior_matrix: np.ndarray | None = None) -> str:
-    """Content-addressed job id: the preprocess cache key (data, the
-    per-variable arity vector of ``cfg.q``, s, ess, gamma, prior, pruning)
+    """Content-addressed job id: the preprocess cache key (data, the score
+    kind, the per-variable arity vector of ``cfg.q`` or the BGe
+    hyperparameters, s, ess, gamma, prior, pruning)
     extended with every run-affecting config field.
     Identical requests — however many users submit them — collapse to one
     id, which is the admission/dedup contract."""
     prune_delta = cfg.prune_delta if cfg.prune_delta > 0 else None
     base = cache_key(data, q=cfg.q, s=cfg.s, gamma=cfg.gamma, ess=cfg.ess,
-                     prior_matrix=prior_matrix, prune_delta=prune_delta)
+                     prior_matrix=prior_matrix, prune_delta=prune_delta,
+                     score=cfg.score, bge_alpha_mu=cfg.bge_alpha_mu,
+                     bge_alpha_w=cfg.bge_alpha_w)
     run = repr(tuple(getattr(cfg, f) for f in _RUN_FIELDS))
     h = hashlib.sha256((base + run).encode()).hexdigest()[:16]
     return f"job-{h}"

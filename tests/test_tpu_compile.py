@@ -179,3 +179,23 @@ def test_rank_map_compiles_for_v5e_in_one_pass(one_chip):
     mem = _rank_map.lower(N, S_MAX, *args).compile().memory_analysis()
     assert mem.temp_size_in_bytes < mem.output_size_in_bytes, (
         mem.temp_size_in_bytes, mem.output_size_in_bytes)
+
+
+def test_bge_scorer_compiles_for_v5e(one_chip):
+    """The BGe scorer of a dense n = 64, s = 4 table build (the magicirri64
+    configuration: 679,121 column subsets in chunks of 1024, moments padded
+    to 128 lanes), as the build dispatches it: the Pallas kernel under the
+    chunk gather, in one program."""
+    from repro.core.combinatorics import n_parent_sets as subsets
+    from repro.preprocess.bge import _run_chunks
+    n, s = 64, S_MAX
+    n_chunks = -(-subsets(n, s) // CHUNK)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in (((128, 128), jnp.float32),
+                                 ((2 * (s + 1),), jnp.float32),
+                                 ((n_chunks, CHUNK, s), jnp.int32),
+                                 ((n_chunks,), jnp.int32))]
+    compiled = _run_chunks.lower(*args, n=n, use_pallas=True,
+                                 interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert "bge_scores" in compiled.as_text()
