@@ -38,6 +38,10 @@ cache.py            preprocessing disk cache keyed on (data, arities, s, ess,
 pipeline.py         the driver: cache -> plan -> fused pass -> dense
                     rank-gather assembly (the rank IS the hash address) or
                     streaming-pruned assembly -> cache store.
+compact.py          the dense assembly on the TPU: each node's row is its
+                    column of the per-subset output with the subsets that
+                    hold the node dropped, compacted in VMEM by log-step
+                    passes (bitwise the rank-gather).
 ==================  =========================================================
 
 core/scores.build_score_table remains the oracle; tests/test_preprocess.py

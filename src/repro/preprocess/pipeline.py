@@ -10,13 +10,19 @@ S/chunk chunks, one device round-trip each) with:
 3. one of two assemblies:
 
    * **dense** (``prune_delta=None``, or ``streaming=False``): a single
-     jitted scan per device, then a gather
-     ls(i, pi) = |pi|*ln(gamma) + TI[rank(columns(pi, i)), i] using the
-     vectorized combination ranking (core/combinatorics) — the rank IS the
-     hash (paper §III-A). The (n, S) rank map is built on the device from
-     the (S, s) PST by one jitted int32 computation, so only the PST and
-     its sizes cross from the host. Materialises the (n, S) table (plus
-     the (n, S) device rank map), which is the memory wall at n >= 100;
+     jitted scan per device, then
+     ls(i, pi) = |pi|*ln(gamma) + TI[rank(columns(pi, i)), i], the rank
+     being the vectorized combination ranking (core/combinatorics) — the
+     rank IS the hash (paper §III-A). Row i is column i of ``TI`` with the
+     subsets that contain column i dropped, in order, so on the TPU it is
+     assembled by an order-preserving compaction (compact.py: K = bits of
+     C - S full-row passes in VMEM, the (n, C) int32 drop shifts built on
+     the device from the (C, s) subsets). Elsewhere it is a point gather
+     from the (n, S) rank map, built on the device from the (S, s) PST by
+     one jitted int32 computation. Either way only subsets and sizes cross
+     from the host, and the table is the same bitwise. Materialises the
+     (n, S) table (plus the (n, C) shifts or (n, S) rank map), which is the
+     memory wall at n >= 100;
    * **streaming** (``prune_delta`` set — the default engine for pruned
      tables, streaming.py): per-chunk dispatch whose (chunk, n) output is
      rank-gathered chunk-locally and merged into per-node within-delta
@@ -56,13 +62,16 @@ where the dense intermediate alone is ~1.6 GB.
 
 With ``return_info=True`` the info dict has the SAME schema on cache hit and
 miss: {cache_hit, n, S, score, plan, preprocess_s, streaming,
-peak_assembly_bytes, bins_real, bins_computed, stages}; ``score`` is the
-score kind. ``plan`` is None on
+peak_assembly_bytes, bins_real, bins_computed, assembly, compact_passes,
+stages}; ``score`` is the score kind. ``plan`` is None on
 a cache hit (no sharding was planned), a {n_chunks, n_devices, imbalance,
 q_buckets} dict otherwise; ``bins_real`` (sum of q_sigma over the column
 subsets) and ``bins_computed`` (sum over chunks of chunk x Q) count the
 kernel's useful and computed bins, None on a cache hit and for BGe;
-``peak_assembly_bytes`` is None unless the streaming assembly ran.
+``peak_assembly_bytes`` is None unless the streaming assembly ran;
+``assembly`` is ``"compact"`` or ``"gather"`` where the dense assembly
+ran (None otherwise), ``compact_passes`` the compaction's pass count K
+(None unless it ran).
 ``stages`` breaks ``preprocess_s`` into per-stage wall-clock seconds
 (plan_s/score_s/assemble_s on the dense path, plan_s/stream_s/finalize_s
 streaming, cache_load_s/cache_store_s around the disk cache; BGe's
@@ -70,8 +79,9 @@ streaming, cache_load_s/cache_store_s around the disk cache; BGe's
 telemetry collector (repro/learner.prepare_run) emits them as stage rows.
 Each is the ``.seconds`` of a telemetry span (``preprocess.build`` gives
 ``preprocess_s``; ``preprocess.plan``/``score``/``assemble``, the last split
-into ``preprocess.rank_map`` and ``preprocess.gather``), so a profiler trace
-shows the same stretches on the device's clock.
+into ``preprocess.rank_map`` (the assembly's index: drop shifts or rank
+map) and ``preprocess.gather`` (compaction or gather, and the prior)), so a
+profiler trace shows the same stretches on the device's clock.
 """
 from __future__ import annotations
 
@@ -89,6 +99,8 @@ from ..core.scores import (ScoreTable, arity_vector, as_states, bge_data,
 from ..telemetry.spans import span
 from .cache import (cache_key, load_cached_sparse, load_cached_table,
                     store_cached_sparse, store_cached_table)
+from .compact import (compact_fits, compact_passes, compact_table,
+                      drop_shifts)
 from .fused import (child_columns, encode_subset_codes, fused_scores_pallas,
                     fused_scores_ref, score_luts)
 from .planner import plan_subsets
@@ -158,6 +170,14 @@ def assemble_table(TI: jnp.ndarray, rank_map: jax.Array, psizes: np.ndarray,
     kfac = jnp.asarray(np.asarray(psizes, np.float32)) * jnp.float32(log_gamma)
     rm = jnp.asarray(rank_map)
     return kfac[None, :] + TI[rm, jnp.arange(n, dtype=jnp.int32)[:, None]]
+
+
+def _compacts(devices, C: int, S: int) -> bool:
+    """Whether the dense build assembles by compaction (compact.py): on the
+    TPU, where the point gather reads at a small share of HBM bandwidth and
+    the compaction's passes run in VMEM, while eight rows of C subsets fit
+    there; elsewhere the gather is the fast path."""
+    return devices[0].platform == "tpu" and compact_fits(C, S)
 
 
 @functools.partial(jax.jit, static_argnames=("Q", "r_max", "ess",
@@ -313,7 +333,8 @@ def build_score_table_fused(data: np.ndarray, *, q, s: int,
                       "plan": None,
                       "preprocess_s": None, "streaming": streaming,
                       "peak_assembly_bytes": None, "bins_real": None,
-                      "bins_computed": None, "stages": {}}
+                      "bins_computed": None, "assembly": None,
+                      "compact_passes": None, "stages": {}}
         log_gamma = float(np.log(gamma))
         expect = {**kind, "s": s, "m": m, "n": n, "gamma": float(gamma)}
         scoring = dict(score=score, ess=ess, bge_alpha_mu=bge_alpha_mu,
@@ -403,9 +424,9 @@ def _build_dense(data: np.ndarray, r: np.ndarray, *, s: int, chunk: int,
                  log_gamma: float, prior_matrix, devices, use_pallas: bool,
                  block_m: int, interpret, info: dict, **scoring):
     """(table, pst, psizes) by the dense assembly: plan the column-subset
-    chunks, score them on the devices, rank-gather the (n, S) table. Fills
-    ``info["plan"]``, the bin counts and the plan_s/score_s/assemble_s
-    stages."""
+    chunks, score them on the devices, assemble the (n, S) table. Fills
+    ``info["plan"]``, the bin counts, ``assembly``, ``compact_passes`` and
+    the plan_s/score_s/assemble_s stages."""
     m, n = data.shape
     with span("preprocess.plan") as plan_span:
         sub, ssz = build_pst(n, s)               # subsets of ALL n columns
@@ -452,11 +473,21 @@ def _build_dense(data: np.ndarray, r: np.ndarray, *, s: int, chunk: int,
             TI = jnp.asarray(TI)[jnp.asarray(where)]
 
     with span("preprocess.assemble") as assemble_span:
-        # assemble: rank-gather + structure penalty (+ prior)
+        # assemble: compaction (TPU) or rank-gather, + structure penalty
+        # (+ prior)
+        compacts = _compacts(devices, len(sub), len(pst))
+        info.update(assembly="compact" if compacts else "gather",
+                    compact_passes=(compact_passes(len(sub), len(pst))
+                                    if compacts else None))
         with span("preprocess.rank_map"):
-            rmap = _rank_map(n, s, pst, psizes)
+            index = (drop_shifts(n, sub) if compacts
+                     else _rank_map(n, s, pst, psizes))
         with span("preprocess.gather"):
-            table = assemble_table(TI, rmap, psizes, log_gamma)
+            if compacts:
+                table = compact_table(TI, index, psizes, log_gamma,
+                                      interpret=interpret)
+            else:
+                table = assemble_table(TI, index, psizes, log_gamma)
             if prior_matrix is not None:
                 from ..core.priors import prior_table
                 table = table + prior_table(

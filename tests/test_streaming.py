@@ -100,7 +100,8 @@ def test_streaming_max_keep_cap():
 # ------------------------------------------------ no dense intermediate
 def test_streaming_never_materialises_dense(monkeypatch):
     """The streaming path must not touch the dense assembly machinery at all
-    and must keep peak host allocation well under the (n, S) table bytes."""
+    (neither the rank-map gather nor the compaction) and must keep peak
+    host allocation well under the (n, S) table bytes."""
     from repro.preprocess import pipeline as pl
 
     def _boom(*a, **k):
@@ -108,6 +109,8 @@ def test_streaming_never_materialises_dense(monkeypatch):
 
     monkeypatch.setattr(pl, "_rank_map", _boom)
     monkeypatch.setattr(pl, "assemble_table", _boom)
+    monkeypatch.setattr(pl, "drop_shifts", _boom)
+    monkeypatch.setattr(pl, "compact_table", _boom)
 
     rng = np.random.default_rng(17)
     n, q, s, m, chunk, delta = 64, 2, 3, 60, 512, 6.0

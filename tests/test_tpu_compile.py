@@ -4,7 +4,8 @@ and the count+score kernel besides at ALARM's published arities (n = 37,
 2-4 states, sum r_i = 105) in each bin-count bucket its tables plan.
 
 The dense assembly's device rank map is compiled here too: written as an
-(n, S, s) broadcast it needs 28 GB of a 16 GB chip at this size.
+(n, S, s) broadcast it needs 28 GB of a 16 GB chip at this size; and the
+compaction that replaces its gather on the TPU, at n = 64.
 
 Interpret mode checks numerics but not the TPU compiler's rules (block
 tiling, VMEM, unsupported primitives); these compiles do. Each test asserts
@@ -178,6 +179,31 @@ def test_rank_map_compiles_for_v5e_in_one_pass(one_chip):
             for shape in ((S_SETS, S_MAX), (S_SETS,))]
     mem = _rank_map.lower(N, S_MAX, *args).compile().memory_analysis()
     assert mem.temp_size_in_bytes < mem.output_size_in_bytes, (
+        mem.temp_size_in_bytes, mem.output_size_in_bytes)
+
+
+def test_compaction_compiles_for_v5e_at_magicirri64(one_chip):
+    """The dense assembly's compaction at n = 64, s = 4 (679,121 column
+    subsets, 16 passes): the Pallas kernel, and no temporary beyond twice
+    the (n, S) table (the transposed values are the only one)."""
+    from repro.preprocess import compact
+    n, s = 64, S_MAX
+    C, S = n_parent_sets(n, s), n_parent_sets(n - 1, s)
+    assert C == 679_121 and compact.compact_fits(C, S)
+    _, psizes = build_pst(n - 1, s)
+    firsts = tuple(int(f) for f in np.searchsorted(psizes,
+                                                   np.arange(1, s + 1)))
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in (((C, n), jnp.float32),
+                                 ((compact._groups(n), compact._cols(C)),
+                                  jnp.int32))]
+    compiled = compact._compact.lower(*args, S=S, firsts=firsts,
+                                      log_gamma=float(np.log(0.1)),
+                                      interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert "compact_rows" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * mem.output_size_in_bytes, (
         mem.temp_size_in_bytes, mem.output_size_in_bytes)
 
 
